@@ -11,7 +11,10 @@ shape) and on its PK-join eager-aggregation variant:
           of the pre-shuffle subtree), reported as `reduction_factor`;
     wire ratio on 8 forced host devices
         — actual all_to_all buffer slots (`distributed.shuffle_stats`),
-          measured in a subprocess so the forced device count cannot leak;
+          measured in a subprocess pinned to the CPU platform so the forced
+          device count cannot leak and the child never contends for an
+          accelerator the parent holds.  An emulated 8-shard host mesh:
+          these are counts, not chip speed;
     pipeline_bps
         — warm compiled-pipeline batches/sec of the chosen (split) plan.
 
@@ -123,6 +126,7 @@ def _wire_rows() -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(repo, "src") + os.pathsep + repo \
         + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"  # the parent may hold the accelerator
     r = subprocess.run(
         [sys.executable, "-c", _WIRE_SCRIPT % (DOP, repo, DOP)],
         capture_output=True, text=True, timeout=600, env=env, cwd=repo)

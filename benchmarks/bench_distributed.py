@@ -1,7 +1,8 @@
 """Weak-scaling sweep of the sharded executor on an 8-way CPU host mesh.
 
-One subprocess (forced 8 host devices, same isolation as bench_aggregation)
-serves a 6-aggregate Reduce flow at a FIXED 8192 rows per shard while the
+One subprocess (forced 8 host devices pinned to the CPU platform, same
+isolation as bench_aggregation: the child never contends for an accelerator
+the parent holds) serves a 6-aggregate Reduce flow at a FIXED 8192 rows per shard while the
 mesh widens 1 -> 2 -> 4 -> 8, once with the sliced overlap wire
 (`overlap_slices=4`, the default) and once with the serial per-column wire
 (`overlap_slices=1`, the `REPRO_OVERLAP=0` path).  Reported per width:
@@ -20,8 +21,9 @@ mesh widens 1 -> 2 -> 4 -> 8, once with the sliced overlap wire
           `cost.wire_profile`.
 
 The sliced and serial wires are asserted BYTE-identical before any timing.
-On this emulated mesh every "device" is a host thread, so collective
-latency cannot genuinely hide under compute; the overlap path's measured
+On this emulated mesh every "device" is a host thread, so its numbers
+are counts and within-run ratios, not chip speed: collective latency
+cannot genuinely hide under compute; the overlap path's measured
 edge comes from issuing K packed collectives instead of one per column
 (dispatch_reduction in the summary).  check_regression.py gates
 `weak_scaling_efficiency` >= BENCH_MIN_WEAK_SCALING (default 0.6) in both
@@ -186,6 +188,7 @@ def _mesh_sweep(shards, reps: int) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(repo, "src") + os.pathsep + repo \
         + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"  # the parent may hold the accelerator
     r = subprocess.run(
         [sys.executable, "-c",
          _MESH_SCRIPT % (MESH, repo, tuple(shards), reps)],

@@ -58,6 +58,9 @@ def main() -> None:
                     help="print available benchmark names and exit")
     args = ap.parse_args()
 
+    from repro.core.pipeline import use_compile_cache
+
+    use_compile_cache()
     from . import (bench_adaptive, bench_aggregation, bench_clickstream,
                    bench_distributed, bench_enumeration, bench_pipeline,
                    bench_q7, bench_q15, bench_roofline, bench_sca,

@@ -272,15 +272,18 @@ def textmining(scale: int = 1_000_000):
 
     def _burn(v, rounds):
         # stand-in for the NLP component's per-record compute: `rounds`
-        # vectorized hash iterations (cost hints mirror the real work)
-        h = v
-        for _ in range(rounds):
+        # vectorized hash iterations (cost hints mirror the real work).
+        # After the first round every value is below 1000003, so the rest
+        # run in int32 and give the same values: a TPU emulates a 64-bit
+        # remainder, and its compiler spends over a second on each one
+        h = ((v * 31 + 7) % 1000003).astype(np.int32)
+        for _ in range(rounds - 1):
             h = (h * 31 + 7) % 1000003
         return h
 
     def preprocess(ir, out):  # tokenization/POS: adds pos_h, expensive
-        out.emit(ir.copy().set(
-            "pos_h", _burn(ir.get("text_h") * 31 + ir.get("length"), 40)))
+        out.emit(ir.copy().set("pos_h", _burn(
+            ir.get("text_h") * 31 + ir.get("length"), 40).astype(np.int64)))
 
     def mk_extractor(name, modulus, sel, cost):
         rounds = int(cost / 100)
@@ -296,7 +299,8 @@ def textmining(scale: int = 1_000_000):
     def relate(ir, out):  # needs all four annotations
         rel = _burn(ir.get("gene_m") + ir.get("drug_m")
                     + ir.get("mut_m") + ir.get("dis_m"), 70)
-        out.emit(ir.copy().set("relation", rel), where=rel % 3 == 0)
+        out.emit(ir.copy().set("relation", rel.astype(np.int64)),
+                 where=rel % 3 == 0)
 
     x = F.map_(docs, preprocess, name="Preprocess",
                hints=Hints(selectivity=1.0, cpu_flops_per_record=4000.0))

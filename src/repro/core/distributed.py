@@ -47,22 +47,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-# newer jax exposes shard_map as jax.shard_map; older versions keep it in
-# jax.experimental.  The replication-check kwarg was renamed check_rep ->
-# check_vma independently of that move, so feature-test the signature
-# rather than inferring it from where the function lives.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
-try:
-    import inspect
-
-    _CHECK_KW = "check_vma" if "check_vma" in inspect.signature(
-        _shard_map).parameters else "check_rep"
-except (ValueError, TypeError):  # pragma: no cover - unintrospectable
-    _CHECK_KW = "check_rep"
-
 from . import masked as M
 from .operators import CoGroupOp, MatchOp, Node, ReduceOp, Source
 from .physical import MESH_SHARDS_ENV, PhysPlan, default_mesh_shards
@@ -154,8 +138,9 @@ def _account(b: M.MaskedBatch, p: int, k: int, broadcast: bool) -> None:
     s.slices += k
     if k == 1:  # serial: one collective per column, plus the validity mask
         s.dispatches += len(b.columns) + 1
-    else:  # sliced: K packed collectives, validity rides as a payload lane
-        s.dispatches += k
+    else:  # sliced: K collectives per lane buffer; validity rides in one
+        s.dispatches += k * len({_lane_kind(v.dtype)
+                                 for v in b.columns.values()} | {"u64"})
 
 
 def _hash_u64(x):
@@ -184,72 +169,95 @@ def _key_hash_np(cols: Mapping, keys, n):
 # ---------------------------------------------------------------------------
 # Lane packing for sliced collectives
 #
-# All columns (plus the validity mask) are bitcast into one uint64 matrix of
-# shape [lanes, capacity], so each slice ships as a SINGLE collective op
-# regardless of column count.  8-byte dtypes bitcast to one lane; narrower
-# dtypes zero-extend into a lane (truncation on unpack is the exact inverse),
-# so packing is bit-exact for every dtype, and the reassembly below is a pure
-# transpose/reshape back to the serial receive layout — the bit-identity
-# argument of DESIGN.md §12.  Wide 8-byte lanes (rather than a uint8 byte
-# matrix) keep the pack/reassemble transposes ~8x smaller.
+# All columns (plus the validity mask) are packed into [lanes, capacity]
+# matrices, one per lane kind, so each slice ships as one collective per
+# kind regardless of column count.  Integer, bool and 4-byte float columns
+# bitcast into uint64 lanes: 8-byte dtypes to one lane, narrower dtypes
+# zero-extended (truncation on unpack is the exact inverse).  float64
+# columns ship as themselves in a float64 matrix: a TPU's 64-bit rewrite has
+# no bitcast from f64 to bits, and moving the values is the same data
+# movement the serial wire does.  Reassembly is a pure transpose/reshape
+# back to the serial receive layout — the bit-identity argument of
+# DESIGN.md §12.  Wide 8-byte lanes (rather than a uint8 byte matrix) keep
+# the pack/reassemble transposes ~8x smaller.
 # ---------------------------------------------------------------------------
 _UINT_OF = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32, 8: jnp.uint64}
 
 
+def _lane_kind(dtype) -> str:
+    """The matrix a column ships in: "f64" for float64, else "u64"."""
+    return "f64" if np.dtype(dtype) == np.float64 else "u64"
+
+
 def _lane_rows(v):
-    """[capacity] column -> [lanes, capacity] uint64 (bit-exact)."""
+    """[capacity] column -> [lanes, capacity] of its lane kind (bit-exact)."""
     dt = np.dtype(v.dtype)
     if dt == np.bool_:
         return v.astype(jnp.uint64)[None, :]
     if dt.itemsize < 8:
         u = jax.lax.bitcast_convert_type(v, _UINT_OF[dt.itemsize])
         return u.astype(jnp.uint64)[None, :]
-    u = jax.lax.bitcast_convert_type(v, jnp.uint64)
-    return u[None, :] if u.ndim == 1 else u.T
+    if _lane_kind(dt) == "u64":
+        v = jax.lax.bitcast_convert_type(v, jnp.uint64)
+    return v[None, :] if v.ndim == 1 else v.T
 
 
 def _from_lane_rows(rows, dtype):
-    """Inverse of `_lane_rows`: [lanes, n] uint64 -> [n] of `dtype`."""
+    """Inverse of `_lane_rows`: [lanes, n] of its lane kind -> [n] of
+    `dtype`."""
     dt = np.dtype(dtype)
     if dt == np.bool_:
         return rows[0] != 0
     if dt.itemsize < 8:
         u = rows[0].astype(_UINT_OF[dt.itemsize])
         return jax.lax.bitcast_convert_type(u, dtype)
-    if rows.shape[0] == 1:
-        return jax.lax.bitcast_convert_type(rows[0], dtype)
-    return jax.lax.bitcast_convert_type(rows.T, dtype)
+    v = rows[0] if rows.shape[0] == 1 else rows.T
+    return jax.lax.bitcast_convert_type(v, dtype) \
+        if _lane_kind(dt) == "u64" else v
 
 
-def _pack_payload(cols: Mapping):
-    """Pack columns into one uint64 [lanes, capacity] matrix."""
-    rows, meta = [], []
+def _pack_payload(cols: Mapping, valid=None):
+    """Pack columns into `{lane kind: [lanes, capacity] matrix}`; `valid`,
+    when given, rides as the last "u64" lane.  `meta` records each column's
+    kind, offset and lane count."""
+    rows: dict = {}
+    meta = []
     for f, v in cols.items():
         r = _lane_rows(v)
-        rows.append(r)
-        meta.append((f, v.dtype, r.shape[0]))
-    return jnp.concatenate(rows, axis=0), meta
+        kind = rows.setdefault(_lane_kind(v.dtype), [])
+        meta.append((f, v.dtype, _lane_kind(v.dtype),
+                     sum(x.shape[0] for x in kind), r.shape[0]))
+        kind.append(r)
+    if valid is not None:
+        rows.setdefault("u64", []).append(valid.astype(jnp.uint64)[None, :])
+    return {k: jnp.concatenate(rs, axis=0) for k, rs in rows.items()}, meta
 
 
-def _unpack_payload(buf, meta) -> dict:
-    cols, off = {}, 0
-    for f, dt, m in meta:
-        cols[f] = _from_lane_rows(buf[off:off + m], dt)
-        off += m
-    return cols
+def _unpack_payload(bufs, meta) -> dict:
+    return {f: _from_lane_rows(bufs[kind][off:off + m], dt)
+            for f, dt, kind, off, m in meta}
 
 
-def _unpack_slices(recv, meta) -> dict:
-    """Reassemble K gathered slices ([W, p, cs] each, disjoint slot ranges)
-    into columns in the serial receive layout ([p*cap], peer-major).  One
-    concat per column — no full-payload transpose — because slice j holds
-    slot range [j*cs, (j+1)*cs) of every peer's block."""
-    cols, off = {}, 0
-    for f, dt, m in meta:
-        lane = jnp.concatenate([r[off:off + m] for r in recv], axis=2)
+def _gather_slices(b: M.MaskedBatch, axis: str, p: int, k: int):
+    """Ship `b` to every peer as K tiled all_gathers per lane kind, over
+    disjoint slot ranges, and reassemble columns and validity in the
+    serial receive layout ([p*cap], peer-major).  One concat per column —
+    no full-payload transpose — because slice j holds slot range
+    [j*cs, (j+1)*cs) of every peer's block."""
+    bufs, meta = _pack_payload(b.columns, b.valid)
+    cs = b.capacity // k
+    recv = [{kind: jax.lax.all_gather(buf[:, j * cs:(j + 1) * cs], axis,
+                                      axis=1, tiled=True
+                                      ).reshape(buf.shape[0], p, cs)
+             for kind, buf in bufs.items()}
+            for j in range(k)]
+    cols = {}
+    for f, dt, kind, off, m in meta:
+        lane = jnp.concatenate([r[kind][off:off + m] for r in recv], axis=2)
         cols[f] = _from_lane_rows(lane.reshape(m, -1), dt)
-        off += m
-    return cols
+    valid = jnp.concatenate([r["u64"][-1] for r in recv],
+                            axis=1).reshape(-1) != 0
+    return cols, valid
 
 
 def _slice_count(capacity: int, slices: int) -> int:
@@ -302,16 +310,7 @@ def _repartition(b: M.MaskedBatch, keys, axis: str, p: int,
                                    concat_axis=0).reshape(-1)
         return M.MaskedBatch(cols, valid)
 
-    payload, meta = _pack_payload(b.columns)  # [lanes, cap]
-    buf = jnp.concatenate(
-        [payload, b.valid.astype(jnp.uint64)[None, :]], axis=0)
-    cs = cap // k
-    recv = [jax.lax.all_gather(buf[:, j * cs:(j + 1) * cs], axis,
-                               axis=1, tiled=True
-                               ).reshape(buf.shape[0], p, cs)
-            for j in range(k)]
-    cols = _unpack_slices(recv, meta)
-    valid = jnp.concatenate([r[-1] for r in recv], axis=1).reshape(-1) != 0
+    cols, valid = _gather_slices(b, axis, p, k)
     tgt = (_key_hash_jnp(cols, keys, valid)
            % jnp.uint64(p)).astype(jnp.int32)
     return M.MaskedBatch(cols, valid & (tgt == jax.lax.axis_index(axis)))
@@ -333,16 +332,7 @@ def _broadcast(b: M.MaskedBatch, axis: str, p: int,
         valid = jax.lax.all_gather(b.valid, axis, axis=0, tiled=True)
         return M.MaskedBatch(cols, valid)
 
-    payload, meta = _pack_payload(b.columns)
-    buf = jnp.concatenate(
-        [payload, b.valid.astype(jnp.uint64)[None, :]], axis=0)  # [W, cap]
-    cs = cap // k
-    recv = [jax.lax.all_gather(buf[:, j * cs:(j + 1) * cs], axis, axis=1,
-                               tiled=True).reshape(buf.shape[0], p, cs)
-            for j in range(k)]
-    cols = _unpack_slices(recv, meta)
-    valid = jnp.concatenate([r[-1] for r in recv], axis=1).reshape(-1) != 0
-    return M.MaskedBatch(cols, valid)
+    return M.MaskedBatch(*_gather_slices(b, axis, p, k))
 
 
 # ---------------------------------------------------------------------------
@@ -572,6 +562,9 @@ def execute_distributed(plan: PhysPlan, bindings: Mapping[str, RecordBatch],
     transfers, bit-identical to the serial wire; `mesh_shards` bounds the
     mesh width when no explicit `mesh` is given (default: all devices, or
     `REPRO_MESH_SHARDS` when set)."""
+    from ..kernels.ops import refuse_on_tpu
+
+    refuse_on_tpu(use_kernels)
     mesh = _default_mesh(mesh, axis, mesh_shards)
     p = mesh.shape[axis]
     if overlap_slices is None:
@@ -591,8 +584,8 @@ def execute_distributed(plan: PhysPlan, bindings: Mapping[str, RecordBatch],
     out_specs = P(axis) if stats_store is None else (P(axis), P())
 
     @functools.partial(
-        _shard_map, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **{_CHECK_KW: False})
+        jax.shard_map, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False)
     def run(*shards):
         local = dict(zip(names, shards))
         observe: Optional[list] = None if stats_store is None else []
@@ -645,8 +638,10 @@ class DistributedPlan:
                  use_kernels: bool = False, slack: float = 4.0,
                  use_order: bool = True,
                  use_megakernel: Optional[bool] = None, cache=None):
+        from ..kernels.ops import refuse_on_tpu
         from . import pipeline as PL
 
+        refuse_on_tpu(use_kernels)
         plan = getattr(plan, "best", plan)   # OptResult / LayoutResult
         plan = getattr(plan, "plan", plan)   # RankedPlan
         if not isinstance(plan, PhysPlan):
@@ -700,8 +695,8 @@ class DistributedPlan:
         overlap = self.overlap_slices
 
         @functools.partial(
-            _shard_map, mesh=self.mesh, in_specs=in_specs,
-            out_specs=out_specs, **{_CHECK_KW: False})
+            jax.shard_map, mesh=self.mesh, in_specs=in_specs,
+            out_specs=out_specs, check_vma=False)
         def run(*shards):
             cache.traces += 1  # trace-time side effect (CacheStats.traces)
             local = dict(zip(names, shards))

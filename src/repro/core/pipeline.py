@@ -54,8 +54,9 @@ work executes the fused stages, with shipping collectives at stage inputs.
 Whole-stage megakernels (DESIGN.md §10): runs of single-consumer
 chain/reduce/PK-match stages whose working set fits VMEM are routed through
 `kernels.megakernel` — one fused span body with dead-column pruning at
-interior compactions and contiguity-aware segmentation, dispatched as a
-single whole-block Pallas call on TPU (inline XLA otherwise).  Routes are
+interior compactions and contiguity-aware segmentation, inlined into the
+executable's XLA program (an interpret-mode Pallas call under
+`REPRO_MEGAKERNEL_PALLAS=1`, off-TPU only).  Routes are
 planned per source signature and fingerprinted (with the dispatch mode)
 into the executable-cache key; `use_megakernel` joins the semantic
 fingerprint, so fused and composed traces never share an executable.
@@ -833,6 +834,30 @@ def executable_cache() -> ExecutableCache:
     return _CACHE
 
 
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process; returns
+    its directory.  Called by entry points only, never on import.
+
+    Where `JAX_COMPILATION_CACHE_DIR` is set JAX reads it itself, and
+    nothing else is set here.  Otherwise the cache lives at the fixed path
+    `<checkout>/.jax_cache` (git-ignored): the directory is what a later
+    process must find, so it never carries a temporary name, pid or time."""
+    env = os.environ.get(COMPILE_CACHE_ENV)
+    if env:
+        return env
+    from jax.experimental.compilation_cache import compilation_cache
+
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    compilation_cache.reset_cache()  # a compile before this call fixed none
+    return path
+
+
 # megakernel routing is on by default; `REPRO_MEGAKERNEL=0` is the global
 # kill switch (falls back to the composed per-stage walk everywhere)
 MEGAKERNEL_ENV = "REPRO_MEGAKERNEL"
@@ -1316,7 +1341,12 @@ def compile_plan(flow_or_plan, use_kernels: bool = False,
     (DESIGN.md §9); `stats` optionally shares a `StatsStore` across handles
     (e.g. seeded from a previous serving session).  `use_megakernel`
     (default on; `REPRO_MEGAKERNEL=0` disables globally) routes fusable
-    stage runs through the whole-stage megakernel (DESIGN.md §10)."""
+    stage runs through the whole-stage megakernel (DESIGN.md §10).
+    `use_kernels=True` raises on a TPU backend, whose compiler refuses the
+    dataflow Pallas kernels (`kernels.ops.refuse_on_tpu`)."""
+    from ..kernels.ops import refuse_on_tpu
+
+    refuse_on_tpu(use_kernels)
     if isinstance(flow_or_plan, PhysPlan):
         flow, stages = flow_or_plan.node, lower_phys(flow_or_plan)
     else:

@@ -27,10 +27,7 @@ import numpy as np
 
 import jax
 
-try:  # jax >= 0.5 moved the jaxpr IR types to jax.extend.core
-    from jax.extend import core as jcore
-except ImportError:  # pragma: no cover
-    from jax import core as jcore
+from jax.extend import core as jcore
 
 from ..udf import Card, Collector, KatEmit, UdfProperties
 from .. import invoke

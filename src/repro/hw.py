@@ -1,7 +1,8 @@
 """Target-hardware constants (TPU v5e) used by the cost model and roofline.
 
-This container runs on CPU; these constants describe the TARGET fabric that
-the dry-run/roofline analysis and the data-flow cost model price against.
+These constants describe the chip the data-flow cost model, the megakernel
+route planner and the dry-run/roofline analysis price against; they are
+read the same way whether the process runs on that chip or on a CPU.
 """
 
 from __future__ import annotations

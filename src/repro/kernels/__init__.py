@@ -12,5 +12,6 @@ Model plane:
 Each kernel file: pl.pallas_call + explicit BlockSpec VMEM tiling.
 `ops.py` holds the jit'd public wrappers; `ref.py` the pure-jnp oracles.
 Kernels run interpret=True on non-TPU backends (validated in tests);
-compiled mode targets TPU v5e.
+compiled mode targets TPU v5e.  The data-plane kernels do not compile for
+it yet (`ops.TPU_KERNEL_REFUSALS`), so `use_kernels=True` raises on a TPU.
 """

@@ -34,14 +34,14 @@ returns the same per-stage `(valid-count, kat-aux)` observation pairs
 boundary-for-boundary, so `record_batch_obs`, truncation detection and
 `StatsStore` keys all work unchanged.
 
-Dispatch: on TPU (or under `REPRO_MEGAKERNEL_PALLAS=1`, which CI uses to
-exercise the path in interpret mode on CPU) the whole span body is wrapped
-in a single whole-block `pl.pallas_call` — grid-free, every input pytree
-leaf one full-array ref — so the batch is VMEM-resident across the chain;
-the fusability predicate's budget check keeps resident bytes under
-`hw.CHIP.vmem_bytes`.  Off-TPU the same traceable body inlines into the
-enclosing jit ("xla" mode): both modes trace identical computations, which
-is what makes megakernel-vs-composed bit-identity testable on CPU.
+Dispatch: on every backend the span body inlines into the enclosing jit
+("xla" mode), which XLA compiles for the chip.  `REPRO_MEGAKERNEL_PALLAS=1`
+wraps the whole body in one grid-free, whole-block `pl.pallas_call` in
+interpret mode instead — CPU CI uses it to exercise the lowering; both
+modes trace identical computations, which is what makes megakernel-vs-
+composed bit-identity testable on CPU.  Mosaic refuses that body on a TPU
+(64-bit columns, no `sort`/`cumsum` lowering), so the setting raises there
+(DESIGN.md §10.2).
 
 Fallback (`plan_routes`): Cross, CoGroup and hint-less Match stages, spans
 shorter than two stages, multi-consumer interior edges, non-8-blockable
@@ -59,23 +59,37 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.extend.core import Literal
 
 from .. import hw
 from ..core import masked as M
 from ..core.reorder import eff_reads
 
-# force the pallas wrapper off-TPU (interpret mode); "0"/unset → backend rule
+# "1": run fused spans through the interpret-mode pallas wrapper (off-TPU only)
 PALLAS_ENV = "REPRO_MEGAKERNEL_PALLAS"
+
+# what Mosaic answers when asked to compile a span body for a TPU
+TPU_PALLAS_REFUSAL = (
+    "Mosaic cannot compile a fused span body for a TPU: every flow column is "
+    "64-bit ('NotImplementedError: 64-bit types are not supported'), and it "
+    "has no lowering for the sort and cumsum the body needs ('Unimplemented "
+    "primitive in Pallas TPU lowering')")
 
 
 def dispatch_mode() -> str:
-    """How a fused span executes: "pallas" (one whole-block `pallas_call`,
-    interpret-mode off TPU) or "xla" (the same body inlined into the
-    enclosing jit).  Part of the executable-cache key — the two modes trace
-    different programs."""
-    if os.environ.get(PALLAS_ENV, "") == "1":
-        return "pallas"
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
+    """How a fused span executes: "xla" (the body inlined into the
+    enclosing jit — every backend) or "pallas" (one whole-block
+    `pallas_call` in interpret mode, only under `REPRO_MEGAKERNEL_PALLAS=1`
+    off-TPU).  Part of the executable-cache key — the two modes trace
+    different programs.  Forcing "pallas" on a TPU backend raises: the rule
+    is fixed by what Mosaic can compile, never rescued by a fallback."""
+    if os.environ.get(PALLAS_ENV, "") != "1":
+        return "xla"
+    if jax.default_backend() == "tpu":
+        raise NotImplementedError(
+            f"{PALLAS_ENV}=1 on a TPU backend: {TPU_PALLAS_REFUSAL}; unset "
+            f"it to run fused spans as XLA")
+    return "pallas"
 
 
 # ---------------------------------------------------------------------------
@@ -259,13 +273,10 @@ def _span_body(span, ins_per_stage, planned_caps, use_kernels, use_order,
 
 
 def _pallas_block_call(body, ins):
-    """Run `body` (pytree-in → pytree-out) as ONE grid-free `pl.pallas_call`
-    with whole-array refs: every leaf is a full block, so the span's
-    intermediates stay VMEM-resident on TPU.  Interpret mode off-TPU traces
-    the identical computation (bit-identity with "xla" dispatch).  Scalar
-    leaves (the obs side-channel) ship as shape-(1,) refs."""
-    from . import ops as kops
-
+    """Run `body` (pytree-in → pytree-out) as ONE grid-free, interpret-mode
+    `pl.pallas_call` with whole-array refs: every leaf is a full block.  It
+    traces the identical computation (bit-identity with "xla" dispatch).
+    Scalar leaves (the obs side-channel) ship as shape-(1,) refs."""
     flat, treedef = jax.tree_util.tree_flatten(ins)
     out_sd = jax.eval_shape(body, ins)
     oflat_sd, otree = jax.tree_util.tree_flatten(out_sd)
@@ -290,10 +301,6 @@ def _pallas_block_call(body, ins):
     # outputs that folded to jaxpr literals (e.g. the constant -1 aux of an
     # aux-free stage) never enter the kernel: a store of a concrete value
     # would itself be a captured constant.  Reattach them host-side.
-    try:
-        from jax.extend.core import Literal
-    except ImportError:  # older jax
-        from jax.core import Literal
     lit = [v.val if isinstance(v, Literal) else None
            for v in closed.jaxpr.outvars]
     keep = [i for i, v in enumerate(lit) if v is None]
@@ -310,8 +317,7 @@ def _pallas_block_call(body, ins):
         for r, i in zip(out_refs, keep):
             r[...] = oflat[i][None] if scal[i] else oflat[i]
 
-    res = pl.pallas_call(kernel, out_shape=out_shape,
-                         interpret=kops._interpret())(*args)
+    res = pl.pallas_call(kernel, out_shape=out_shape, interpret=True)(*args)
     merged = [None if v is None else jnp.asarray(v, oflat_sd[i].dtype)
               for i, v in enumerate(lit)]
     for r, i in zip(res, keep):

@@ -26,6 +26,25 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+# what Mosaic answers when the `use_kernels` dataflow kernels are compiled
+# for a TPU (tests/test_tpu_compile.py pins both as strict xfails)
+TPU_KERNEL_REFUSALS = (
+    "sorted_probe: float64 keys are refused ('64-bit types are not "
+    "supported'), and with int32 keys its index maps return int64 under x64 "
+    "(\"failed to legalize 'func.return' (i64, i32)\"); segmented_scan: the "
+    "in-kernel associative_scan fails to lower (an MLIR 'slice' type error)")
+
+
+def refuse_on_tpu(use_kernels: bool) -> None:
+    """Raise when `use_kernels=True` would send the dataflow Pallas kernels
+    (`sorted_probe`, `segmented_scan`) to the TPU compiler, which refuses
+    both.  Off-TPU they run in interpret mode, as the CPU tests do."""
+    if use_kernels and jax.default_backend() == "tpu":
+        raise NotImplementedError(
+            f"use_kernels=True on a TPU backend: {TPU_KERNEL_REFUSALS}; "
+            "serve with use_kernels=False")
+
+
 def _pad_to(x: jnp.ndarray, mult: int, axis: int, value=0):
     n = x.shape[axis]
     pad = (-n) % mult

@@ -27,20 +27,28 @@ from ..models import make_model
 from ..serve.engine import Engine, Request
 
 
-def _main_dataflow(args):
+def dataflow_tenants() -> list:
+    """The mixed demo workload: `(name, flow, make_bindings(rows, seed))`
+    for q15, clickstream, textmining and a q15-shaped tenant whose filter
+    hint is 25x off the data (true selectivity 0.04, hint 1.0)."""
     from ..configs import flows
-    from ..serve.dataflow import DataflowEngine, ServeConfig
 
     q15_root, q15_b = flows.q15()
     ck_root, ck_b = flows.clickstream()
     tm_root, tm_b = flows.textmining()
     dr_root, dr_b = flows.q15_drift(hint_selectivity=1.0)
-    tenants = [
+    return [
         ("q15", q15_root, lambda n, s: q15_b(n, seed=s)),
         ("click", ck_root, lambda n, s: ck_b(n, seed=s)),
         ("text", tm_root, lambda n, s: tm_b(n, seed=s)),
         ("drift", dr_root, lambda n, s: dr_b(n, seed=s, true_sel=0.04)),
     ]
+
+
+def _main_dataflow(args):
+    from ..serve.dataflow import DataflowEngine, ServeConfig
+
+    tenants = dataflow_tenants()
     eng = DataflowEngine(ServeConfig(max_coalesce=16, probe_every=8))
     for name, root, _ in tenants:
         eng.register(name, root)
@@ -81,6 +89,9 @@ def main():
                     help="rows per dataflow request (--dataflow only)")
     args = ap.parse_args()
 
+    from ..core.pipeline import use_compile_cache
+
+    use_compile_cache()
     if args.dataflow:
         _main_dataflow(args)
         return

@@ -463,6 +463,9 @@ class DataflowEngine:
 
     def __init__(self, config: ServeConfig = ServeConfig(),
                  cache: Optional[ExecutableCache] = None):
+        from ..kernels.ops import refuse_on_tpu
+
+        refuse_on_tpu(config.use_kernels)
         self.config = config
         self.cache = cache if cache is not None else ExecutableCache()
         self._tenants: dict[str, _Tenant] = {}
@@ -716,7 +719,8 @@ class DataflowEngine:
         observed into that tenant's private store — so per-tenant stats
         stay disjoint from the shared stage and from each other.  Any
         truncation (prefix or suffix) falls back to the solo path, whose
-        own repair policy applies."""
+        own repair policy applies; a failure of the prefix executable is
+        every sharing request's error."""
         cfg = self.config
         probes, share = [], []
         for req in reqs:
@@ -737,9 +741,9 @@ class DataflowEngine:
                 {sg.source: share[0].bindings[sg.source]})
             out, counts, caps = plan.run_device_observed(staged, donate=True)
             trunc = plan.fold_observation(sg.store, counts, caps=caps)
-        except BaseException:
+        except Exception as e:
             for req in share:
-                self._serve_solo(req)
+                req._deliver(error=e)
             return len(reqs)
         if trunc is not None:   # prefix overran: its output is missing rows
             self.truncations += 1
@@ -767,7 +771,7 @@ class DataflowEngine:
                 self.shared_requests += 1
                 self.requests_served += 1
                 self.device_batches += 1
-            except BaseException as e:
+            except Exception as e:
                 req._deliver(error=e)
         return len(reqs)
 
@@ -821,7 +825,7 @@ class DataflowEngine:
             self.requests_served += 1
             self.device_batches += 1
             req._deliver(value=out.to_record_batch())
-        except BaseException as e:  # deliver, don't wedge the pump
+        except Exception as e:  # deliver, don't wedge the pump
             req._deliver(error=e)
 
     def _serve_coalesced(self, g: _PlanGroup, reqs: list) -> None:
@@ -837,7 +841,7 @@ class DataflowEngine:
             staged = cp.bind_device(combined)
             out, counts, caps = cp.run_device_observed(staged, donate=True)
             trunc = cp.fold_observation(g.store, counts, caps=caps)
-        except BaseException as e:
+        except Exception as e:
             for r in reqs:
                 r._deliver(error=e)
             return
@@ -938,19 +942,15 @@ class DataflowEngine:
         """Warm a freshly built group's executables off the serving path by
         running them once on copies of a probe's bindings (the coalesced
         plan sees a full-width batch, so the serving-time capacity bucket is
-        the one that traces).  Best-effort: a failure here just means the
-        pump traces lazily on first use."""
-        try:
-            # donate=True: the cache key must match the serving entry
-            g.solo.run_device_observed(g.solo.bind_device(sample),
-                                       donate=True)
-            if g.coalesced is not None:
-                w = g.coalesce_info.width
-                combined = coalesce_bindings([sample] * w, g.coalesce_info)
-                g.coalesced.run_device_observed(
-                    g.coalesced.bind_device(combined), donate=True)
-        except Exception:
-            pass
+        the one that traces).  A failure propagates: the tenant is not
+        moved onto a regime whose executables do not run."""
+        # donate=True: the cache key must match the serving entry
+        g.solo.run_device_observed(g.solo.bind_device(sample), donate=True)
+        if g.coalesced is not None:
+            w = g.coalesce_info.width
+            combined = coalesce_bindings([sample] * w, g.coalesce_info)
+            g.coalesced.run_device_observed(
+                g.coalesced.bind_device(combined), donate=True)
 
     def join_swaps(self, timeout: Optional[float] = None) -> None:
         """Block until every in-flight background regime swap has been

@@ -50,7 +50,10 @@ from repro.core.physical import Ctx, default_mesh_shards
 def test_lane_pack_roundtrip_bit_exact(dtype, vals):
     v = jnp.asarray(np.array(vals, dtype=dtype))
     packed, meta = DX._pack_payload({"c": v})
-    assert packed.dtype == jnp.uint64
+    # float64 ships as itself (a TPU cannot bitcast it); all else in u64
+    kind, lane = ("f64", jnp.float64) if dtype == np.float64 \
+        else ("u64", jnp.uint64)
+    assert list(packed) == [kind] and packed[kind].dtype == lane
     (got,) = DX._unpack_payload(packed, meta).values()
     a, b = np.asarray(v), np.asarray(got)
     assert a.dtype == b.dtype
@@ -60,12 +63,16 @@ def test_lane_pack_roundtrip_bit_exact(dtype, vals):
 def test_lane_pack_multi_column_layout():
     cols = {"a": jnp.arange(8, dtype=jnp.int64),
             "b": jnp.arange(8, dtype=jnp.float32),
-            "c": jnp.ones(8, dtype=jnp.bool_)}
-    packed, meta = DX._pack_payload(cols)
-    # one uint64 lane per (sub-8-byte or 8-byte) column
-    assert packed.shape == (3, 8)
+            "c": jnp.ones(8, dtype=jnp.bool_),
+            "d": jnp.arange(8, dtype=jnp.float64)}
+    packed, meta = DX._pack_payload(cols, valid=jnp.ones(8, dtype=jnp.bool_))
+    # one uint64 lane per (sub-8-byte or 8-byte) column, plus validity last;
+    # float64 in a matrix of its own
+    assert packed["u64"].shape == (4, 8)
+    assert packed["f64"].shape == (1, 8)
+    assert (np.asarray(packed["u64"][-1]) == 1).all()
     out = DX._unpack_payload(packed, meta)
-    assert list(out) == ["a", "b", "c"]
+    assert list(out) == ["a", "b", "c", "d"]
     for f in cols:
         assert (np.asarray(out[f]) == np.asarray(cols[f])).all()
         assert out[f].dtype == cols[f].dtype
@@ -109,6 +116,21 @@ def test_shuffle_stats_accounting():
     st.clear()
     assert st.sites == 0 and st.wire_bytes == 0
     assert st.overlap_fraction() == 0.0
+
+
+def test_float64_lane_ships_its_own_collectives():
+    st = DX.ShuffleStats()
+    old = DX._SHUFFLE_STATS
+    DX._SHUFFLE_STATS = st
+    try:
+        b = M.MaskedBatch({"k": jnp.arange(64, dtype=jnp.int64),
+                           "x": jnp.zeros(64, dtype=jnp.float64)},
+                          jnp.ones(64, dtype=jnp.bool_))
+        DX._account(b, p=4, k=4, broadcast=False)
+    finally:
+        DX._SHUFFLE_STATS = old
+    # K slices each of the uint64 matrix (k + validity) and the float64 one
+    assert st.dispatches == 2 * 4
 
 
 def test_overlap_env_knobs(monkeypatch):

@@ -12,6 +12,7 @@ its new regime and leaves the group; the other stays warm), and the
 """
 
 import numpy as np
+import pytest
 
 from repro.core import executor, flow as F
 from repro.core.operators import Hints
@@ -130,6 +131,29 @@ def test_shared_serving_parity_and_counters():
     for ra, rb in reqs:
         assert _rows(ra.result(10)) == ref_a
         assert _rows(rb.result(10)) == ref_b
+
+
+def test_shared_prefix_failure_reaches_the_request(monkeypatch):
+    """A failure of the fused prefix executable is the sharing requests'
+    error; it is never hidden by re-serving them solo."""
+    eng = _engine()
+    data = _data(7)
+    ra, rb = eng.submit("ta", {"s": data}), eng.submit("tb", {"s": data})
+    eng.drain()   # first requests probe solo
+    ra.result(10), rb.result(10)
+    (sg,) = eng._prefixes.values()
+
+    def broken(*a, **k):
+        raise RuntimeError("prefix executable failed")
+
+    monkeypatch.setattr(sg.plan, "run_device_observed", broken)
+    solo = eng.stats()["solo_requests"]
+    ra, rb = eng.submit("ta", {"s": data}), eng.submit("tb", {"s": data})
+    eng.drain()
+    for r in (ra, rb):
+        with pytest.raises(RuntimeError, match="prefix executable failed"):
+            r.result(10)
+    assert eng.stats()["solo_requests"] == solo
 
 
 def test_sharing_requires_identical_source_batch():
