@@ -20,8 +20,8 @@ elided from declared source orders, linear compaction and no per-call host
 round trip, it must BEAT `eager_bps` on every serving flow
 (`benchmarks/check_regression.py` enforces `pipeline_bps >= eager_bps`).
 The batch size is serving-scale (1k rows/request); `crossover` maps the
-ratio across batch sizes, and `stages` breaks the warm body down per fused
-stage (each stage jitted separately, so rates include one extra dispatch).
+ratio across batch sizes.  Device time per stage comes from a profiler
+trace of the program's stage scopes (`chipbench/stages.py`), not from here.
 """
 
 from __future__ import annotations
@@ -34,9 +34,6 @@ import numpy as np
 
 from repro.configs import flows
 from repro.core import executor
-from repro.core import masked as M
-from repro.core import pipeline as PL
-from repro.core.cost import seed_source_stats
 from repro.core.masked import run_flow_jit
 from repro.core.pipeline import compile_plan, executable_cache
 from repro.core.record import batch_from_dict
@@ -95,74 +92,6 @@ def _device_bps(cp, staged: list, min_time: float = 0.3) -> float:
     while q:
         jax.block_until_ready(q.popleft())
     return n / (time.perf_counter() - t0)
-
-
-def _batch_bytes(b) -> int:
-    """HBM footprint of a masked batch: columns + the validity mask."""
-    return int(sum(v.size * v.dtype.itemsize for v in b.columns.values())
-               + b.valid.size)
-
-
-def _stage_breakdown(cp, masked) -> list:
-    """Per-stage warm timings of the lowered pipeline (each stage jitted on
-    its own, so numbers include one dispatch each — a profile, not a sum).
-
-    Each row carries the roofline leg (DESIGN.md §10 / bench_roofline):
-    `bytes` is the stage's input+output HBM traffic, `achieved_gbps` the
-    measured rate over it, and `roofline_fraction` that rate against the
-    `hw.CHIP` memory-bandwidth roof — how far the stage sits from
-    bandwidth-bound.  `route` marks whether the compiled plan fuses the
-    stage into a megakernel span ("mega") or runs it composed ("solo")."""
-    from repro import hw
-
-    stats_memo = seed_source_stats(
-        cp.flow, {k: b.capacity for k, b in masked.items()}, {})
-    routes = cp._routes({k: b.capacity for k, b in masked.items()}) or ()
-    in_mega = set()
-    for entry in routes:
-        if entry[0] == "mega":
-            in_mega.update(range(entry[1], entry[2]))
-    results: list = []
-    rows = []
-    for si, st in enumerate(cp.stages):
-        orders = st.in_orders or ((),) * len(st.inputs)
-
-        def one(mb, st=st, orders=orders):
-            ins = []
-            for ref, o in zip(st.inputs, orders):
-                x = mb[ref[1]] if ref[0] == "source" else results[ref[1]]
-                if o and not x.order:
-                    x = x.with_order(o)
-                ins.append(x)
-            out = PL.execute_stage(st, ins, cp.use_kernels, cp.use_order)
-            return M.compact_to_estimate(out, st.top, stats_memo,
-                                         cp.compact_slack)
-
-        fn = jax.jit(one)
-        r = fn(masked)
-        jax.block_until_ready(r)
-        reps, t0 = 0, time.perf_counter()
-        while time.perf_counter() - t0 < 0.05:
-            r = fn(masked)
-            reps += 1
-        jax.block_until_ready(r)
-        ms = (time.perf_counter() - t0) / reps * 1e3
-        moved = sum(_batch_bytes(masked[ref[1]] if ref[0] == "source"
-                                 else results[ref[1]])
-                    for ref in st.inputs) + _batch_bytes(r)
-        achieved = moved / (ms / 1e3)
-        rows.append({"stage": st.kind, "op": st.top.name,
-                     "out_cap": r.capacity,
-                     "elides_sort": bool(st.kind in ("reduce", "match")
-                                         and any(st.in_orders or ())),
-                     "ms": round(ms, 4),
-                     "route": "mega" if si in in_mega else "solo",
-                     "bytes": moved,
-                     "achieved_gbps": round(achieved / 1e9, 4),
-                     "roofline_fraction": round(
-                         achieved / hw.CHIP.hbm_bandwidth, 6)})
-        results.append(r)
-    return rows
 
 
 def _crossover(root, mk_bindings, cp, quick: bool) -> dict:
@@ -232,7 +161,6 @@ def _bench_flow(name: str, root, mk_bindings, n: int, n_batches: int,
         "vs_eager": round(pipe_bps / max(eager_bps, 1e-9), 2),
         "host_vs_eager": round(run_bps / max(eager_bps, 1e-9), 2),
         "speedup": round(pipe_bps / max(masked_bps, 1e-9), 1),
-        "stages": _stage_breakdown(cp, staged[0]),
     }
     if name in flows.FLOWS:
         row["crossover"] = _crossover(root, mk_bindings, cp, quick)
@@ -258,13 +186,11 @@ def run(quick: bool = False):
 
     from . import common
 
-    display = [{k: v for k, v in r.items() if k not in ("stages", "crossover")}
+    display = [{k: v for k, v in r.items() if k != "crossover"}
                for r in rows]
     common.print_rows("bench_pipeline (order-aware compiled pipelines)",
                       display)
     for r in rows:
-        parts = ", ".join(f"{s['op']}:{s['ms']}ms" for s in r["stages"])
-        print(f"  {r['flow']:14s} stages: {parts}")
         if "crossover" in r:
             print(f"  {r['flow']:14s} vs_eager by rows: {r['crossover']}")
     stats = executable_cache().stats()

@@ -1,17 +1,7 @@
-"""Beyond-paper: roofline tables — model dry-run artifacts AND dataflow
-stages.
+"""Beyond-paper: roofline table of the model dry-run artifacts.
 
-Two sections:
-
-* model cells: reads results/dryrun_singlepod.json (produced by
-  repro.launch.dryrun) and prints the per-(arch × shape) three-term
-  roofline — no recompilation there.
-* dataflow stages: compiles the serving flows, times every lowered stage
-  warm (`bench_pipeline._stage_breakdown`) and reports achieved HBM
-  bytes/s against the `hw.CHIP` memory-bandwidth roof — the
-  `roofline_fraction` each stage row also carries in BENCH_pipeline.json.
-  Stages the route planner fuses into a megakernel span are marked
-  `route=mega` (DESIGN.md §10).
+Reads results/dryrun_singlepod.json (produced by repro.launch.dryrun) and
+prints the per-(arch × shape) three-term roofline — no recompilation.
 """
 
 from __future__ import annotations
@@ -23,32 +13,6 @@ from . import common
 
 RESULTS = os.path.join(os.path.dirname(__file__), "..", "results",
                        "dryrun_singlepod.json")
-
-DATAFLOW_ROWS = 16_000  # measure at the crossover-gated batch size
-
-
-def _dataflow_rows(quick: bool) -> list:
-    """Per-stage achieved-bandwidth rows for the serving flows."""
-    from repro.configs import flows
-    from repro.core.pipeline import compile_plan
-
-    from .bench_pipeline import _stage_breakdown
-
-    names = ("q15",) if quick else ("q15", "clickstream", "textmining")
-    rows = []
-    for name in names:
-        root, mk = flows.FLOWS[name]()
-        cp = compile_plan(root)
-        b = mk(DATAFLOW_ROWS, seed=7)
-        cp.run(b)  # trace once so the breakdown times warm stages
-        staged = cp.bind_device(b)
-        for r in _stage_breakdown(cp, staged):
-            rows.append({"flow": name, "op": r["op"], "stage": r["stage"],
-                         "route": r["route"], "ms": r["ms"],
-                         "bytes": r["bytes"],
-                         "achieved_gbps": r["achieved_gbps"],
-                         "roofline_fraction": r["roofline_fraction"]})
-    return rows
 
 
 def run(quick: bool = False, path: str = RESULTS):
@@ -72,11 +36,7 @@ def run(quick: bool = False, path: str = RESULTS):
                 "roofline_fraction": rl["roofline_fraction"],
             })
         common.print_rows("bench_roofline (dry-run derived)", rows)
-    stage_rows = _dataflow_rows(quick)
-    common.print_rows("bench_roofline (dataflow stages vs HBM roof)",
-                      stage_rows)
-    return {"name": "roofline", "cells": len(rows),
-            "dataflow_stages": stage_rows}
+    return {"name": "roofline", "cells": len(rows)}
 
 
 if __name__ == "__main__":

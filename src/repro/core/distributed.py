@@ -48,6 +48,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from . import masked as M
+from ..obs import count, scope, span
 from .operators import CoGroupOp, MatchOp, Node, ReduceOp, Source
 from .physical import MESH_SHARDS_ENV, PhysPlan, default_mesh_shards
 from .record import RecordBatch
@@ -398,10 +399,12 @@ def _exec_stages(stages, shards: Mapping[str, M.MaskedBatch],
                 else:
                     raise ValueError(
                         f"partition ship on {type(node).__name__}")
-            b = compact(_repartition(b, keys, axis, p, overlap_slices),
-                        st.input_plans[t].node)
+            with scope("wire"):
+                b = _repartition(b, keys, axis, p, overlap_slices)
+            b = compact(b, st.input_plans[t].node)
         elif how == "broadcast":
-            b = _broadcast(b, axis, p, overlap_slices)
+            with scope("wire"):
+                b = _broadcast(b, axis, p, overlap_slices)
         else:
             raise ValueError(how)
         return b
@@ -412,8 +415,9 @@ def _exec_stages(stages, shards: Mapping[str, M.MaskedBatch],
         # distributed leg of the adaptive feedback loop (DESIGN.md §9),
         # aggregated exactly where shuffle_stats counts the wire.  Aux-free
         # stages keep the composed convention of an un-psum'd -1.
-        return (jax.lax.psum(count, axis),
-                jax.lax.psum(aux, axis) if has_aux else jnp.int32(-1))
+        with scope("wire"):
+            return (jax.lax.psum(count, axis),
+                    jax.lax.psum(aux, axis) if has_aux else jnp.int32(-1))
 
     def psum_obs(valid, aux, has_aux):
         # sliced observation psums (DESIGN.md §12): under overlap each slot
@@ -425,35 +429,39 @@ def _exec_stages(stages, shards: Mapping[str, M.MaskedBatch],
             else 1
         parts = valid.astype(jnp.int32).reshape(k, -1)
         count = jnp.int32(0)
-        for j in range(k):
-            count = count + jax.lax.psum(jnp.sum(parts[j]), axis)
-        return (count,
-                jax.lax.psum(aux, axis) if has_aux else jnp.int32(-1))
+        with scope("wire"):
+            for j in range(k):
+                count = count + jax.lax.psum(jnp.sum(parts[j]), axis)
+            return (count,
+                    jax.lax.psum(aux, axis) if has_aux else jnp.int32(-1))
 
     entries = routes or tuple(("solo", i) for i in range(len(stages)))
     for entry in entries:
         if entry[0] == "solo":
             i = entry[1]
             st = stages[i]
-            in_orders = st.in_orders or ((),) * len(st.inputs)
-            ins = [resolve(st, t, ref, how, in_orders[t])
-                   for t, (ref, how) in enumerate(zip(st.inputs, st.ship))]
-            obs: Optional[dict] = {} if observe is not None else None
-            out = PL.execute_stage(st, ins, use_kernels, use_order, obs)
-            if st.kind == "limit" and p > 1 and "broadcast" in st.ship:
-                # global WITH-TIES limit: the input was replicated, so every
-                # shard computed the IDENTICAL survivor mask on slot-aligned
-                # batches — deterministic per-slot ownership keeps the shards
-                # disjoint while their union is exactly the one-shard result
-                own = (jnp.arange(out.capacity, dtype=jnp.int32)
-                       % jnp.int32(p)) == jax.lax.axis_index(axis)
-                out = M.MaskedBatch(dict(out.columns), out.valid & own,
-                                    out.order)
-            if observe is not None:
-                observe.append(psum_obs(
-                    out.valid,
-                    obs.get("groups", jnp.int32(-1)), "groups" in obs))
-            results[i] = compact(out, st.top)
+            with PL.stage_scope(st):
+                in_orders = st.in_orders or ((),) * len(st.inputs)
+                ins = [resolve(st, t, ref, how, in_orders[t])
+                       for t, (ref, how) in enumerate(zip(st.inputs,
+                                                          st.ship))]
+                obs: Optional[dict] = {} if observe is not None else None
+                out = PL.execute_stage(st, ins, use_kernels, use_order, obs)
+                if st.kind == "limit" and p > 1 and "broadcast" in st.ship:
+                    # global WITH-TIES limit: the input was replicated, so
+                    # every shard computed the IDENTICAL survivor mask on
+                    # slot-aligned batches — deterministic per-slot
+                    # ownership keeps the shards disjoint while their union
+                    # is exactly the one-shard result
+                    own = (jnp.arange(out.capacity, dtype=jnp.int32)
+                           % jnp.int32(p)) == jax.lax.axis_index(axis)
+                    out = M.MaskedBatch(dict(out.columns), out.valid & own,
+                                        out.order)
+                if observe is not None:
+                    observe.append(psum_obs(
+                        out.valid,
+                        obs.get("groups", jnp.int32(-1)), "groups" in obs))
+                results[i] = compact(out, st.top)
         else:
             _, i, j = entry
             span = stages[i:j]
@@ -473,7 +481,8 @@ def _exec_stages(stages, shards: Mapping[str, M.MaskedBatch],
                 # own side-channel), so they psum unsliced
                 observe.extend(psum_scalar(c, a, h) for (c, a), h in
                                zip(span_obs, MK.span_has_aux(span)))
-            results[j - 1] = compact(raw, span[-1].top)
+            with PL.stage_scope(span[-1]):
+                results[j - 1] = compact(raw, span[-1].top)
     return results[-1]
 
 
@@ -491,38 +500,44 @@ def bind_global(root: Node, bindings: Mapping[str, RecordBatch],
     sound inside `shard_map`."""
     sources = {n.name: n for n in root.iter_nodes()
                if isinstance(n, Source)}
-    global_batches: dict[str, M.MaskedBatch] = {}
-    for name, src in sources.items():
-        b = bindings[name].to_numpy().compact().project(
-            list(src.out_schema.fields))
-        n = b.capacity
-        per = int(np.ceil(max(n, 1) / p))
-        cap = per * p
-        if src.partitioned_on:
-            tgt = _key_hash_np(b.columns, src.partitioned_on, n) % np.uint64(p)
-            order = np.argsort(tgt, kind="stable")
-            counts = np.bincount(tgt.astype(np.int64), minlength=p)
-            if counts.max() > per:
-                per = int(counts.max())
-                cap = per * p
-            cols, valid = {}, np.zeros(cap, bool)
-            starts = np.cumsum(counts) - counts
-            dest = np.concatenate(
-                [np.arange(c) + t * per for t, c in enumerate(counts)]
-            ).astype(np.int64)
-            for f in b.fields:
-                arr = np.zeros(cap, dtype=b.columns[f].dtype)
-                arr[dest] = np.asarray(b.columns[f])[order]
-                cols[f] = arr
-            valid[dest] = True
-        else:
-            cols = {f: np.concatenate(
-                [np.asarray(v), np.zeros(cap - n, dtype=v.dtype)])
-                for f, v in b.columns.items()}
-            valid = np.arange(cap) < n
-        global_batches[name] = M.MaskedBatch(
-            {f: jnp.asarray(v) for f, v in cols.items()}, jnp.asarray(valid))
-    return global_batches
+    host: dict = {}
+    nbytes = 0
+    with span("prepare"):
+        for name, src in sources.items():
+            b = bindings[name].to_numpy().compact().project(
+                list(src.out_schema.fields))
+            n = b.capacity
+            per = int(np.ceil(max(n, 1) / p))
+            cap = per * p
+            if src.partitioned_on:
+                tgt = _key_hash_np(b.columns, src.partitioned_on, n) % np.uint64(p)
+                order = np.argsort(tgt, kind="stable")
+                counts = np.bincount(tgt.astype(np.int64), minlength=p)
+                if counts.max() > per:
+                    per = int(counts.max())
+                    cap = per * p
+                cols, valid = {}, np.zeros(cap, bool)
+                starts = np.cumsum(counts) - counts
+                dest = np.concatenate(
+                    [np.arange(c) + t * per for t, c in enumerate(counts)]
+                ).astype(np.int64)
+                for f in b.fields:
+                    arr = np.zeros(cap, dtype=b.columns[f].dtype)
+                    arr[dest] = np.asarray(b.columns[f])[order]
+                    cols[f] = arr
+                valid[dest] = True
+            else:
+                cols = {f: np.concatenate(
+                    [np.asarray(v), np.zeros(cap - n, dtype=v.dtype)])
+                    for f, v in b.columns.items()}
+                valid = np.arange(cap) < n
+            host[name] = (cols, valid)
+            nbytes += valid.nbytes + sum(v.nbytes for v in cols.values())
+    count("bind_bytes", nbytes)
+    with span("transfer"):
+        return {name: M.MaskedBatch({f: jnp.asarray(v) for f, v in cols.items()},
+                                    jnp.asarray(valid))
+                for name, (cols, valid) in host.items()}
 
 
 def _default_mesh(mesh: Optional[Mesh], axis: str,
@@ -599,8 +614,9 @@ def execute_distributed(plan: PhysPlan, bindings: Mapping[str, RecordBatch],
             return out
         # psum'd counts are replicated over the axis, so they leave the
         # shard body under a replicated out-spec
-        src = {n: jax.lax.psum(jnp.sum(b.valid.astype(jnp.int32)), axis)
-               for n, b in local.items()}
+        with scope("wire"):
+            src = {n: jax.lax.psum(jnp.sum(b.valid.astype(jnp.int32)), axis)
+                   for n, b in local.items()}
         obs = {"src": src,
                "out": tuple(o[0] for o in (observe or ())),
                "aux": tuple(o[1] for o in (observe or ()))}
@@ -668,7 +684,8 @@ class DistributedPlan:
     def bind(self, bindings: Mapping[str, RecordBatch]) -> dict:
         """Host-bind a request to global mesh batches (reusable across
         `run_device` calls)."""
-        return bind_global(self.plan.node, bindings, self.p)
+        with span("bind_device"):
+            return bind_global(self.plan.node, bindings, self.p)
 
     def _source_sig(self, staged: Mapping[str, M.MaskedBatch]) -> tuple:
         return tuple(
@@ -709,8 +726,10 @@ class DistributedPlan:
                                    obs_acc, use_megakernel, overlap)
             if not observe:
                 return out
-            src = {n: jax.lax.psum(jnp.sum(b.valid.astype(jnp.int32)), axis)
-                   for n, b in local.items()}
+            with scope("wire"):
+                src = {n: jax.lax.psum(jnp.sum(b.valid.astype(jnp.int32)),
+                                       axis)
+                       for n, b in local.items()}
             return out, {"src": src,
                          "out": tuple(o[0] for o in (obs_acc or ())),
                          "aux": tuple(o[1] for o in (obs_acc or ()))}
@@ -725,15 +744,19 @@ class DistributedPlan:
         output batch (device-resident — chain into further mesh steps)."""
         from . import pipeline as PL
 
-        fn = self._executable(staged, stats_store is not None)
-        args = [staged[n] for n in sorted(staged)]
-        if stats_store is None:
-            return fn(*args)
-        out, obs = fn(*args)
-        obs = jax.device_get(obs)
-        PL.record_batch_obs(stats_store, self.stages, obs["src"],
-                            obs["out"], obs["aux"])
-        return out
+        with span("run_device"):
+            with span("lookup"):
+                fn = self._executable(staged, stats_store is not None)
+                args = [staged[n] for n in sorted(staged)]
+            with span("dispatch"):
+                res = fn(*args)
+            if stats_store is None:
+                return res
+            out, obs = res
+            obs = jax.device_get(obs)
+            PL.record_batch_obs(stats_store, self.stages, obs["src"],
+                                obs["out"], obs["aux"])
+            return out
 
     def run(self, bindings: Mapping[str, RecordBatch],
             stats_store=None) -> RecordBatch:

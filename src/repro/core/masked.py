@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import invoke, scans
+from ..obs import scope
 from .cost import estimate
 from .operators import (CoGroupOp, CrossOp, LimitOp, MapOp, MatchOp, Node,
                         ReduceOp, Source)
@@ -135,9 +136,10 @@ class MaskedBatch:
         construction (positions are strictly increasing in source order), so
         it PRESERVES `order`; slots past the valid count hold clamped
         garbage under valid=False."""
-        src, count = scans.pack_indices(self.valid, capacity)
-        cols = {k: v[src] for k, v in self.columns.items()}
-        valid = jnp.arange(capacity, dtype=jnp.int32) < count
+        with scope("compact"):
+            src, count = scans.pack_indices(self.valid, capacity)
+            cols = {k: v[src] for k, v in self.columns.items()}
+            valid = jnp.arange(capacity, dtype=jnp.int32) < count
         return MaskedBatch(cols, valid, self.order)
 
 
@@ -217,22 +219,21 @@ def _sort_by_key(b: MaskedBatch, key: Sequence[str]):
     segment_ids, is_start).  Single-key inputs sort one sentinel code (a
     cheaper single-operand sort; the gap-tolerant segmentation makes a
     sentinel collision with a genuine max-value key harmless)."""
-    if len(key) == 1:
-        kv = jnp.asarray(b.columns[key[0]])
-        big = (jnp.finfo(kv.dtype).max if jnp.issubdtype(kv.dtype, jnp.floating)
-               else jnp.iinfo(kv.dtype).max)
-        code = jnp.where(b.valid, kv, big)
-        _, order = jax.lax.sort_key_val(
-            code, jnp.arange(b.capacity, dtype=jnp.int32))
+    with scope("sort"):
+        if len(key) == 1:
+            kv = jnp.asarray(b.columns[key[0]])
+            big = (jnp.finfo(kv.dtype).max if jnp.issubdtype(kv.dtype, jnp.floating)
+                   else jnp.iinfo(kv.dtype).max)
+            code = jnp.where(b.valid, kv, big)
+            _, order = jax.lax.sort_key_val(
+                code, jnp.arange(b.capacity, dtype=jnp.int32))
+        else:
+            keys = tuple(jnp.asarray(b.columns[k]) for k in key)
+            order = jnp.lexsort(tuple(reversed(keys)) + (~b.valid,))
         cols = {f: v[order] for f, v in b.columns.items()}
         valid = b.valid[order]
-        seg, is_start = _segments_gappy(cols, key, valid)
-        return MaskedBatch(cols, valid, tuple(key)), seg, is_start
-    keys = tuple(jnp.asarray(b.columns[k]) for k in key)
-    order = jnp.lexsort(tuple(reversed(keys)) + (~b.valid,))
-    cols = {f: v[order] for f, v in b.columns.items()}
-    valid = b.valid[order]
-    seg, is_start = _segments_contiguous(cols, key, valid)
+    segments = _segments_gappy if len(key) == 1 else _segments_contiguous
+    seg, is_start = segments(cols, key, valid)
     return MaskedBatch(cols, valid, tuple(key)), seg, is_start
 
 
@@ -396,7 +397,8 @@ def _match_codes(op: MatchOp, lb: MaskedBatch, rb: MaskedBatch):
         ct = jnp.promote_types(la.dtype, ra.dtype)
         ks.append(jnp.concatenate([la.astype(ct), ra.astype(ct)]))
     n = ks[0].shape[0]
-    order = jnp.lexsort(tuple(reversed(ks)))
+    with scope("sort"):
+        order = jnp.lexsort(tuple(reversed(ks)))
     is_new = jnp.zeros(n, bool).at[0].set(True)
     for k in ks:
         sk = k[order]
@@ -441,17 +443,19 @@ def _exec_match_pk(op: MatchOp, lb: MaskedBatch, rb: MaskedBatch,
         # sort by (code, valid-first): equal-code invalid rows land AFTER the
         # valid ones, so no sentinel arithmetic is needed and a left search
         # still finds the valid row first
-        order = jnp.lexsort((~rb.valid, rcode_raw))
-        rcode = rcode_raw[order]
-        rcols = {f: v[order] for f, v in rb.columns.items()}
-        rvalid = rb.valid[order]
+        with scope("sort"):
+            order = jnp.lexsort((~rb.valid, rcode_raw))
+            rcode = rcode_raw[order]
+            rcols = {f: v[order] for f, v in rb.columns.items()}
+            rvalid = rb.valid[order]
 
-    if use_kernels:
-        from ..kernels import ops as kops
+    with scope("probe"):
+        if use_kernels:
+            from ..kernels import ops as kops
 
-        pos = kops.sorted_probe(rcode, lcode)
-    else:
-        pos = jnp.searchsorted(rcode, lcode)
+            pos = kops.sorted_probe(rcode, lcode)
+        else:
+            pos = jnp.searchsorted(rcode, lcode)
     if first_valid is not None:
         pos = jnp.maximum(pos, first_valid)
     pos = jnp.clip(pos, 0, rb.capacity - 1)
@@ -497,15 +501,17 @@ def _exec_match_anti(op: MatchOp, lb: MaskedBatch, rb: MaskedBatch,
         rvalid = rb.valid
     else:
         first_valid = None
-        order = jnp.lexsort((~rb.valid, rcode_raw))
-        rcode = rcode_raw[order]
-        rvalid = rb.valid[order]
-    if use_kernels:
-        from ..kernels import ops as kops
+        with scope("sort"):
+            order = jnp.lexsort((~rb.valid, rcode_raw))
+            rcode = rcode_raw[order]
+            rvalid = rb.valid[order]
+    with scope("probe"):
+        if use_kernels:
+            from ..kernels import ops as kops
 
-        pos = kops.sorted_probe(rcode, lcode)
-    else:
-        pos = jnp.searchsorted(rcode, lcode)
+            pos = kops.sorted_probe(rcode, lcode)
+        else:
+            pos = jnp.searchsorted(rcode, lcode)
     if first_valid is not None:
         pos = jnp.maximum(pos, first_valid)
     pos = jnp.clip(pos, 0, rb.capacity - 1)
@@ -533,7 +539,8 @@ def _exec_limit(op: LimitOp, b: MaskedBatch,
         cum = scans.cumsum(b.valid.astype(jnp.int32))
         pos = jnp.clip(jnp.searchsorted(cum, kth + 1), 0, b.capacity - 1)
     else:
-        perm = jnp.lexsort(tuple(reversed(keys)) + (~b.valid,))
+        with scope("sort"):
+            perm = jnp.lexsort(tuple(reversed(keys)) + (~b.valid,))
         pos = perm[kth]
     # lexicographic key <= threshold key (empty input: valid is all-False
     # anyway, so the garbage threshold never leaks a row)
@@ -578,16 +585,18 @@ def _exec_cogroup(op: CoGroupOp, lb: MaskedBatch, rb: MaskedBatch,
     rkeys = [jnp.asarray(rb.columns[k]) for k in op.right_key]
     allkeys = [jnp.concatenate([a, b_]) for a, b_ in zip(lkeys, rkeys)]
     allvalid = jnp.concatenate([lb.valid, rb.valid])
-    order = jnp.lexsort(tuple(reversed(allkeys)) + (~allvalid,))
-    sorted_keys = [k[order] for k in allkeys]
-    sorted_valid = allvalid[order]
+    with scope("sort"):
+        order = jnp.lexsort(tuple(reversed(allkeys)) + (~allvalid,))
+        sorted_keys = [k[order] for k in allkeys]
+        sorted_valid = allvalid[order]
     same = jnp.ones(nl + nr, bool)
     for k in sorted_keys:
         same = same & jnp.concatenate([jnp.zeros(1, bool), k[1:] == k[:-1]])
     prev_valid = jnp.concatenate([jnp.zeros(1, bool), sorted_valid[:-1]])
     is_start = sorted_valid & (~same | ~prev_valid)
     seg_sorted = jnp.maximum(jnp.cumsum(is_start.astype(jnp.int32)) - 1, 0)
-    inv = jnp.argsort(order)
+    with scope("sort"):
+        inv = jnp.argsort(order)
     seg_all = seg_sorted[inv]
     lseg, rseg = seg_all[:nl], seg_all[nl:]
     nseg = nl + nr
@@ -605,7 +614,8 @@ def _exec_cogroup(op: CoGroupOp, lb: MaskedBatch, rb: MaskedBatch,
     def side_perm(b_, key, seg):
         if use_order and tuple(b_.order[:len(key)]) == tuple(key):
             return _compact_perm(b_.valid)
-        return jnp.lexsort((~b_.valid, seg))
+        with scope("sort"):
+            return jnp.lexsort((~b_.valid, seg))
 
     lord = side_perm(lb, op.left_key, lseg)
     rord = side_perm(rb, op.right_key, rseg)

@@ -28,6 +28,7 @@ import math
 import time
 from typing import Optional
 
+from ..obs import span
 from .cost import estimate
 from .enumeration import RewriteEngine, _mtab_key, closure, enumerate_plans
 from .operators import MapOp, Node, ReduceOp, Source, commute_id
@@ -273,6 +274,12 @@ def optimize(flow: Node, ctx: Optional[Ctx] = None, max_plans: int = 20000,
     expansions.  `max_plans` caps MATERIALIZED plans (the closure paths and
     `enumerate_plans` raise `PlanSpaceExceeded` past it); the group search
     never materializes orderings, so the cap does not apply there."""
+    with span("optimize"):
+        return _optimize(flow, ctx, max_plans, include_commutes, prune)
+
+
+def _optimize(flow: Node, ctx: Optional[Ctx], max_plans: int,
+              include_commutes: bool, prune: bool) -> OptResult:
     ctx = ctx or Ctx()
     if prune and _is_unary_flow(flow) and not _has_splittable_reduce(flow):
         n_ops = sum(1 for _ in flow.iter_nodes()) - 1

@@ -95,6 +95,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import masked as M
+from ..obs import count, scope, span
 from .cost import StatsStore, calibrate_hints, drift_score, seed_source_stats
 from .operators import (CoGroupOp, CrossOp, LimitOp, MapOp, MatchOp, Node,
                         ReduceOp, Source)
@@ -578,6 +579,13 @@ def stage_key(stage: Stage) -> tuple:
     return tuple(op.name for op in stage.ops)
 
 
+def stage_scope(stage: Stage):
+    """The device scope of one stage, `stage.<kind>.<top operator>`: named
+    after the plan (the operator names `stage_key` uses), so it survives
+    reordering rewrites and recompiles."""
+    return scope(f"stage.{stage.kind}.{stage.top.name}")
+
+
 def run_stages(stages: Sequence[Stage], bindings: Mapping[str, M.MaskedBatch],
                use_kernels: bool, compact_slack: float,
                stats_memo: dict, scale: float = 1.0,
@@ -635,11 +643,12 @@ def run_stages(stages: Sequence[Stage], bindings: Mapping[str, M.MaskedBatch],
         if entry[0] == "solo":
             i = entry[1]
             st = stages[i]
-            orders = st.in_orders or ((),) * len(st.inputs)
-            ins = [resolve(r, o) for r, o in zip(st.inputs, orders)]
-            obs: Optional[dict] = {} if observe is not None else None
-            out = execute_stage(st, ins, use_kernels, use_order, obs)
-            last = results[i] = boundary(st, out, obs)
+            with stage_scope(st):
+                orders = st.in_orders or ((),) * len(st.inputs)
+                ins = [resolve(r, o) for r, o in zip(st.inputs, orders)]
+                obs: Optional[dict] = {} if observe is not None else None
+                out = execute_stage(st, ins, use_kernels, use_order, obs)
+                last = results[i] = boundary(st, out, obs)
         else:
             from ..kernels import megakernel as MK
 
@@ -660,8 +669,9 @@ def run_stages(stages: Sequence[Stage], bindings: Mapping[str, M.MaskedBatch],
                 caps.extend(applied)
             if observe is not None:
                 observe.extend(span_obs[:-1])
-            last = results[j - 1] = boundary(span[-1], raw, None,
-                                             count=span_obs[-1])
+            with stage_scope(span[-1]):
+                last = results[j - 1] = boundary(span[-1], raw, None,
+                                                 count=span_obs[-1])
     return last
 
 
@@ -982,30 +992,38 @@ class CompiledPlan:
         a dispatch each — measurable at serving rates)."""
         masked: dict[str, M.MaskedBatch] = {}
         sig = []
-        for name in sorted(self._sources):
-            src = self._sources[name]
-            if name not in bindings:
-                raise KeyError(f"no binding for source {name!r}")
-            b = bindings[name].to_numpy().compact().project(
-                list(src.out_schema.fields))
-            n = b.capacity
-            cap = M.bucket_capacity(max(n, 1))
-            cols = {}
-            for f in b.fields:
-                v = np.asarray(b.columns[f])
-                # canonicalize host-side (device_put, unlike jnp.asarray,
-                # would keep int64/float64 even under disabled x64)
-                v = v.astype(jax.dtypes.canonicalize_dtype(v.dtype),
-                             copy=False)
-                if cap != n:
-                    pad = np.zeros((cap - n,) + v.shape[1:], dtype=v.dtype)
-                    v = np.concatenate([v, pad])
-                cols[f] = v
-            order = M.order_prefix(src.sorted_on or (), b.fields) \
-                if self.use_order else ()
-            masked[name] = M.MaskedBatch(cols, np.arange(cap) < n, order)
-            sig.append((name, self._ssig[name], cap, order))
-        return jax.device_put(masked), tuple(sig)
+        nbytes = 0
+        with span("prepare"):
+            for name in sorted(self._sources):
+                src = self._sources[name]
+                if name not in bindings:
+                    raise KeyError(f"no binding for source {name!r}")
+                b = bindings[name].to_numpy().compact().project(
+                    list(src.out_schema.fields))
+                n = b.capacity
+                cap = M.bucket_capacity(max(n, 1))
+                cols = {}
+                for f in b.fields:
+                    v = np.asarray(b.columns[f])
+                    # canonicalize host-side (device_put, unlike
+                    # jnp.asarray, would keep int64/float64 even under
+                    # disabled x64)
+                    v = v.astype(jax.dtypes.canonicalize_dtype(v.dtype),
+                                 copy=False)
+                    if cap != n:
+                        pad = np.zeros((cap - n,) + v.shape[1:],
+                                       dtype=v.dtype)
+                        v = np.concatenate([v, pad])
+                    cols[f] = v
+                    nbytes += v.nbytes
+                order = M.order_prefix(src.sorted_on or (), b.fields) \
+                    if self.use_order else ()
+                masked[name] = M.MaskedBatch(cols, np.arange(cap) < n, order)
+                sig.append((name, self._ssig[name], cap, order))
+                nbytes += cap  # the bool validity mask
+        count("bind_bytes", nbytes)
+        with span("transfer"):
+            return jax.device_put(masked), tuple(sig)
 
     def bind_device(self, bindings: Mapping[str, RecordBatch]
                     ) -> dict[str, M.MaskedBatch]:
@@ -1014,7 +1032,8 @@ class CompiledPlan:
         `bucket_capacity` (so repeat sizes reuse traced shapes), masked to
         its valid rows, and carries the order prefix `Source.sorted_on`
         declares (which the lowered stages' sort elision relies on)."""
-        return self._bind(bindings)[0]
+        with span("bind_device"):
+            return self._bind(bindings)[0]
 
     def _masked_sig(self, masked: Mapping[str, M.MaskedBatch]):
         out: dict[str, M.MaskedBatch] = {}
@@ -1241,8 +1260,10 @@ class CompiledPlan:
         attempts = 0
         masked, sig = rebind()
         while True:
-            fn = self._executable(sig, donate=donate)
-            out, obs = fn(masked)
+            with span("lookup"):
+                fn = self._executable(sig, donate=donate)
+            with span("dispatch"):
+                out, obs = fn(masked)
             if not self._observe(fn, obs):
                 self._maybe_replan()
                 return out
@@ -1277,14 +1298,19 @@ class CompiledPlan:
         Under `adaptive`, the observation read synchronizes each step (the
         price of feedback), and donation is rejected: a truncation re-run
         needs the input batches intact."""
-        if self.adaptive is None:
-            masked, sig = self._masked_sig(masked_bindings)
-            return self._executable(sig, donate=donate)(masked)
-        if donate:
-            raise ValueError("donate=True is incompatible with adaptive "
-                             "serving: truncation re-runs reuse the inputs")
-        return self._serve_adaptive(
-            lambda: self._masked_sig(masked_bindings), donate=False)
+        with span("run_device"):
+            if self.adaptive is None:
+                with span("lookup"):
+                    masked, sig = self._masked_sig(masked_bindings)
+                    fn = self._executable(sig, donate=donate)
+                with span("dispatch"):
+                    return fn(masked)
+            if donate:
+                raise ValueError("donate=True is incompatible with adaptive "
+                                 "serving: truncation re-runs reuse the "
+                                 "inputs")
+            return self._serve_adaptive(
+                lambda: self._masked_sig(masked_bindings), donate=False)
 
     def run_device_observed(self, masked_bindings: Mapping[str, M.MaskedBatch],
                             donate: bool = False):
@@ -1347,14 +1373,17 @@ def compile_plan(flow_or_plan, use_kernels: bool = False,
     from ..kernels.ops import refuse_on_tpu
 
     refuse_on_tpu(use_kernels)
-    if isinstance(flow_or_plan, PhysPlan):
-        flow, stages = flow_or_plan.node, lower_phys(flow_or_plan)
-    else:
-        flow, stages = flow_or_plan, lower(flow_or_plan)
-    if use_megakernel is None:
-        use_megakernel = _megakernel_default()
-    return CompiledPlan(flow=flow, stages=stages,
-                        use_kernels=use_kernels, compact_slack=compact_slack,
-                        use_order=use_order, use_megakernel=use_megakernel,
-                        cache=cache or _CACHE,
-                        adaptive=adaptive, stats=stats)
+    with span("compile"):
+        if isinstance(flow_or_plan, PhysPlan):
+            flow, stages = flow_or_plan.node, lower_phys(flow_or_plan)
+        else:
+            flow, stages = flow_or_plan, lower(flow_or_plan)
+        if use_megakernel is None:
+            use_megakernel = _megakernel_default()
+        return CompiledPlan(flow=flow, stages=stages,
+                            use_kernels=use_kernels,
+                            compact_slack=compact_slack,
+                            use_order=use_order,
+                            use_megakernel=use_megakernel,
+                            cache=cache or _CACHE,
+                            adaptive=adaptive, stats=stats)

@@ -238,37 +238,38 @@ def _span_body(span, ins_per_stage, planned_caps, use_kernels, use_order,
     counts, auxes = [], []
     out = None
     for k, (st, raw_ins) in enumerate(zip(span, ins_per_stage)):
-        ins = [prev if b is None else b for b in raw_ins]
-        obs: dict = {}
-        out = PL.execute_stage(st, ins, use_kernels, use_order, obs,
-                               contiguous_in=prev_packed)
-        counts.append(jnp.sum(out.valid.astype(jnp.int32)))
-        auxes.append(jnp.asarray(obs.get("groups", jnp.int32(-1)), jnp.int32))
-        if k == len(span) - 1:
-            break
-        # interior boundary: prune dead columns, compact to exactly the
-        # capacity the composed path would, and record packedness for the
-        # consumer's contiguous segmentation
-        nxt = span[k + 1]
-        live = _live_fields(nxt, out.columns.keys())
-        if len(live) < len(out.columns):
-            out = M.MaskedBatch({f: out.columns[f] for f in live}, out.valid,
-                                M.order_prefix(out.order, live))
-        cap = min(out.capacity, planned_caps[k])
-        caps_acc.append(cap)
-        if cap < out.capacity:
-            out = out.compact(cap)
-            prev_packed = True
-        else:
-            prev_packed = False
-        # attach the lowered order assumption on the in-span edge, exactly
-        # as run_stages does for solo stages
-        orders = nxt.in_orders or ((),) * len(nxt.inputs)
-        for t, b in enumerate(ins_per_stage[k + 1]):
-            if b is None and use_order and orders[t] and not out.order:
-                out = out.with_order(orders[t])
+        with PL.stage_scope(st):
+            ins = [prev if b is None else b for b in raw_ins]
+            obs: dict = {}
+            out = PL.execute_stage(st, ins, use_kernels, use_order, obs,
+                                   contiguous_in=prev_packed)
+            counts.append(jnp.sum(out.valid.astype(jnp.int32)))
+            auxes.append(jnp.asarray(obs.get("groups", jnp.int32(-1)), jnp.int32))
+            if k == len(span) - 1:
                 break
-        prev = out
+            # interior boundary: prune dead columns, compact to exactly the
+            # capacity the composed path would, and record packedness for the
+            # consumer's contiguous segmentation
+            nxt = span[k + 1]
+            live = _live_fields(nxt, out.columns.keys())
+            if len(live) < len(out.columns):
+                out = M.MaskedBatch({f: out.columns[f] for f in live}, out.valid,
+                                    M.order_prefix(out.order, live))
+            cap = min(out.capacity, planned_caps[k])
+            caps_acc.append(cap)
+            if cap < out.capacity:
+                out = out.compact(cap)
+                prev_packed = True
+            else:
+                prev_packed = False
+            # attach the lowered order assumption on the in-span edge, exactly
+            # as run_stages does for solo stages
+            orders = nxt.in_orders or ((),) * len(nxt.inputs)
+            for t, b in enumerate(ins_per_stage[k + 1]):
+                if b is None and use_order and orders[t] and not out.order:
+                    out = out.with_order(orders[t])
+                    break
+            prev = out
     return out, tuple(counts), tuple(auxes)
 
 
