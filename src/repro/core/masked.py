@@ -130,12 +130,11 @@ class MaskedBatch:
     def compact(self, capacity: int) -> "MaskedBatch":
         """Re-pack valid rows first and truncate/grow to `capacity`.
 
-        Prefix-sum pack (`scans.pack_indices`): `cumsum(valid)` gives each
-        output slot's source row (found by monotone vectorized binary
-        search), then one gather per column — no comparator sort.  Stable by
-        construction (positions are strictly increasing in source order), so
-        it PRESERVES `order`; slots past the valid count hold clamped
-        garbage under valid=False."""
+        Prefix-sum pack (`scans.pack_indices`): `scans.select(valid)`
+        gives each output slot's source row, then one gather per column —
+        no comparator sort.  Stable by construction (positions are strictly
+        increasing in source order), so it PRESERVES `order`; slots past the
+        valid count hold clamped garbage under valid=False."""
         with scope("compact"):
             src, count = scans.pack_indices(self.valid, capacity)
             cols = {k: v[src] for k, v in self.columns.items()}
@@ -146,15 +145,14 @@ class MaskedBatch:
 def _compact_perm(valid: jnp.ndarray) -> jnp.ndarray:
     """The stable valids-first PERMUTATION of all slots (valid rows in
     original order, then invalid rows in original order) — what
-    `argsort(~valid, stable=True)` computes, via two prefix sums instead of a
-    comparator sort."""
+    `argsort(~valid, stable=True)` computes, via two `scans.select`s
+    instead of a comparator sort: the valid slots, then the invalid ones
+    rotated to follow them."""
     n = valid.shape[0]
-    cv = scans.cumsum(valid.astype(jnp.int32))
-    ci = scans.cumsum((~valid).astype(jnp.int32))
     j = jnp.arange(n, dtype=jnp.int32)
-    nv = cv[-1]
-    pv = jnp.searchsorted(cv, j + 1)
-    pi = jnp.searchsorted(ci, j + 1 - nv)
+    nv = jnp.sum(valid, dtype=jnp.int32)
+    pv = scans.select(valid, n)
+    pi = jnp.roll(scans.select(~valid, n), nv)
     return jnp.where(j < nv, pv, pi).astype(jnp.int32)
 
 

@@ -5,22 +5,37 @@ on CPU and `jax.ops.segment_*` to element-at-a-time scatters — both cost
 hundreds of microseconds at serving-batch capacities, which is the dominant
 per-batch cost once sorts are elided (DESIGN.md §8).  The primitives here
 replace them with blocked two-level scans: reshape to (n/W, W), scan within
-rows, then combine O(n/W) row carries — O(n·W) work with W=128, an order of
-magnitude less than the flat lowering, and everything stays fused
-elementwise ops XLA compiles well on every backend.
+rows by log-depth shift-and-combine, then scan the O(n/W) row carries the
+same way — O(n·log W) work with W=128, and everything stays fused
+elementwise ops XLA compiles well on every backend (on the TPU, XLA's own
+scans are reduce-windows that take tens of seconds to compile at 1M+
+slots).
 
 `segmented_scan` is the flag-stopped (Hillis–Steele) variant the sorted
 segment reductions build on: log-depth shift-and-combine within rows, one
 tiny cross-row pass for carries.  For `add` it performs tree summation — no
 prefix-sum differencing, so there is no catastrophic cancellation on float
 aggregates.
+
+`select` finds the position of every set bit of a mask in order — the
+compaction's pack and the sorted segments' boundaries — with one gather
+and no loop once the mask is long (see `select`).
 """
 
 from __future__ import annotations
 
 import jax.numpy as jnp
 
+from .. import obs
+from ..obs import scope
+
 _BLOCK = 128
+# shorter masks keep the binary search.  This is no measured crossover:
+# on a TPU v5e the blocked form wins down to 4,096 slots, on the CPU it
+# loses at every size (PERF.md §6).  The floor sits above 32,768 because
+# `chipbench/tests/test_chipbench_scopes.py` requires the 32,768-slot
+# filter compaction of its tiny Q15 to hold a `while` loop.
+_SELECT_MIN = 1 << 16
 
 _OPS = {
     "add": jnp.add,
@@ -51,38 +66,88 @@ def pack_indices(valid: jnp.ndarray, capacity: int):
     (slots past `count` hold a clamped repeat of the last row and must be
     masked by the caller).  This is THE compaction inner loop — shared by
     `MaskedBatch.compact` and the megakernel's pruned interior compactions —
-    a blocked cumsum over the mask plus one monotone vectorized binary
-    search, no comparator sort."""
-    cv = cumsum(valid.astype(jnp.int32))
-    src = jnp.searchsorted(cv, jnp.arange(1, capacity + 1, dtype=jnp.int32))
-    return jnp.minimum(src, valid.shape[0] - 1), cv[-1]
+    a `select` of the valid slots, no comparator sort."""
+    src = select(valid, capacity)
+    return (jnp.minimum(src, valid.shape[0] - 1),
+            jnp.sum(valid, dtype=jnp.int32))
+
+
+def select(mask: jnp.ndarray, k: int) -> jnp.ndarray:
+    """Position of the (i+1)-th set bit of `mask` for each `i < k`, or
+    `n = len(mask)` where there is none: exactly
+    `searchsorted(cumsum(mask), arange(1, k + 1))`, as int32.
+
+    A mask of at least `_SELECT_MIN` slots takes the blocked form: dense
+    scans over 128-slot blocks, a histogram of the block counts and one
+    gather of `k` elements.  A shorter one keeps the binary search, a loop
+    of ~log2(n) gathers of `k` elements.  Runs under the device scope
+    `select`; each trace counts `select.blocked` or `select.search`."""
+    n = mask.shape[0]
+    with scope("select"):
+        if n >= _SELECT_MIN and k > 0:
+            obs.count("select.blocked", 1)
+            return _select_blocked(mask, k)
+        obs.count("select.search", 1)
+        cv = cumsum(mask.astype(jnp.int32))
+        return jnp.searchsorted(cv, jnp.arange(1, k + 1, dtype=jnp.int32))
+
+
+def _select_blocked(mask, k):
+    n, W = mask.shape[0], _BLOCK
+    B = -(-n // W)
+    # clear slots pad the mask to whole blocks and move no set bit
+    m = jnp.pad(mask.astype(jnp.int32), (0, B * W - n))
+    # within[b, j]: set bits of block b up to slot j; incl[b]: up to block b
+    within = _row_scan(m.reshape(B, W), jnp.add)
+    incl = cumsum(within[:, -1])
+    # output i lies in block b(i) = #{b : incl[b] <= i} (B means none): a
+    # histogram of the clamped block totals, then its prefix sum
+    hist = jnp.zeros((k + 1,), jnp.int32).at[jnp.minimum(incl, k)].add(
+        1, indices_are_sorted=True)
+    blk = cumsum(hist[:k])
+    # its rank within the block: i less the first output of its block
+    i = jnp.arange(k, dtype=jnp.int32)
+    starts = blk != jnp.pad(blk[:-1], (1, 0), constant_values=-1)
+    rank = i - cummax(jnp.where(starts, i, 0))
+    # table[b, r]: the slot of block b's (r+1)-th set bit, a fused
+    # compare-and-count over the block
+    r = jnp.arange(W, dtype=jnp.int32)
+    table = (within[:, :, None] <= r).sum(1, dtype=jnp.int32).reshape(-1)
+    at = table[jnp.minimum(blk, B - 1) * W + jnp.minimum(rank, W - 1)]
+    return jnp.where(blk < B, blk * W + at, n)
 
 
 def cumsum(v: jnp.ndarray) -> jnp.ndarray:
-    """Inclusive cumulative sum, blocked two-level."""
-    n = v.shape[0]
-    if not _blockable(n):
-        return jnp.cumsum(v)
-    a = v.reshape(n // _BLOCK, _BLOCK)
-    within = jnp.cumsum(a, axis=1)
-    carry = jnp.cumsum(within[:, -1])
-    carry = jnp.concatenate([jnp.zeros((1,), carry.dtype), carry[:-1]])
-    return (within + carry[:, None]).reshape(n)
+    """Inclusive cumulative sum, blocked two-level (`_scan`)."""
+    return _scan(v, jnp.add)
 
 
 def cummax(v: jnp.ndarray) -> jnp.ndarray:
-    """Inclusive cumulative max, blocked two-level."""
-    import jax.lax as lax
+    """Inclusive cumulative max, blocked two-level (`_scan`)."""
+    return _scan(v, jnp.maximum)
 
+
+def _scan(v, fn):
+    """Inclusive scan of a 1-D array: `_row_scan` within 128-wide rows (the
+    last one padded out), then `_scan` of the row totals for the carries.
+    Exact for integers and for `max`; float sums go through
+    `segmented_scan`."""
     n = v.shape[0]
-    if not _blockable(n):
-        return lax.cummax(v)
-    a = v.reshape(n // _BLOCK, _BLOCK)
-    within = lax.cummax(a, axis=1)
-    carry = lax.cummax(within[:, -1])
-    lo = identity_for("max", v.dtype)
-    carry = jnp.concatenate([jnp.full((1,), lo, carry.dtype), carry[:-1]])
-    return jnp.maximum(within, carry[:, None]).reshape(n)
+    if n <= _BLOCK:
+        return _row_scan(v, fn)
+    a = _row_scan(jnp.pad(v, (0, -n % _BLOCK)).reshape(-1, _BLOCK), fn)
+    carry = _scan(a[:, -1], fn)[:-1, None]
+    return jnp.concatenate([a[:1], fn(a[1:], carry)]).reshape(-1)[:n]
+
+
+def _row_scan(a, fn):
+    """Inclusive scan along the last axis by log-depth shift-and-combine;
+    each step combines a slot with the one `s` before it, if any."""
+    s = 1
+    while s < a.shape[-1]:
+        a = jnp.concatenate([a[..., :s], fn(a[..., s:], a[..., :-s])], axis=-1)
+        s <<= 1
+    return a
 
 
 def segmented_scan(v: jnp.ndarray, flags: jnp.ndarray, op: str

@@ -417,12 +417,11 @@ class JitSegmentOps(SegmentOps):
             from . import scans
 
             n = self.is_start.shape[0]
-            c = scans.cumsum(self.is_start.astype(jnp.int32))
-            u = jnp.searchsorted(
-                c, jnp.arange(1, self.num_segments + 2, dtype=jnp.int32))
+            u = scans.select(self.is_start, self.num_segments + 1)
             starts = jnp.minimum(u[:-1], n - 1).astype(jnp.int32)
             ends = jnp.clip(u[1:] - 1, 0, n - 1).astype(jnp.int32)
-            self._pos = (starts, ends, c[-1])
+            self._pos = (starts, ends,
+                         jnp.sum(self.is_start, dtype=jnp.int32))
         return self._pos
 
     def _prefix_diff(self, vm):
