@@ -8,7 +8,7 @@ Two enumerators are provided:
   once, memo table keyed on the flow's operator multiset + source.
 
 * `enumerate_plans` — the production enumerator for tree-shaped flows with
-  binary operators: a memoized fix-point closure over all valid single-step
+  binary operators: the closure of the flow under all valid single-step
   rewrites (unary swaps, pushes into/out of binary operators, rotations,
   commutations).  On purely unary flows it returns exactly the Algorithm-1
   space (tested); on trees it realizes the paper's "easily extended to
@@ -16,26 +16,23 @@ Two enumerators are provided:
 
 Both return logical plans only; the physical optimizer prices each.
 
-Performance (DESIGN.md §2): trees are hash-consed.  Every node carries an
-interned structural id (`operators.struct_id`), so plan dedup is an integer
-set membership test, and the single-step rewrite list of every distinct
-subtree is computed exactly once per enumeration (`RewriteEngine`).  Rewritten
-trees are interned by id, so a subtree shared by thousands of enumerated
-plans is rewritten and allocated once, not once per enclosing plan.
+The closure is computed as a memo of logical groups (`GroupMemo`, DESIGN.md
+§2.1): the rewrite rules run once per group expression, not once per plan,
+and the plans are then composed from the groups.  The optimizer's group
+search (DESIGN.md §4.2) prices the same memo without composing the plans.
 """
 
 from __future__ import annotations
 
 from typing import Iterable, Optional
 
-from .operators import (MapOp, Node, ReduceOp, Source, commute_id,
-                        commute_ordered, intern_commute_key, replace_child,
-                        struct_id)
-from .reorder import RULES, commute, reorderable
+from .operators import (CrossOp, MapOp, Node, ReduceOp, Source,
+                        commute_ordered, replace_child, struct_id)
+from .reorder import RULES, commute, is_commuted, reorderable
 
 
 class PlanSpaceExceeded(RuntimeError):
-    """The rewrite closure grew past `max_plans`.
+    """The rewrite closure grew past `max_plans` plans.
 
     Carries the configured limit and the number of distinct plans discovered
     before bailing out, so callers can report partial progress or retry with
@@ -106,236 +103,241 @@ def enum_alternatives_alg1(flow: Node,
 
 
 # ---------------------------------------------------------------------------
-# Closure enumerator (trees with binary operators)
+# The closure as a memo of logical groups (DESIGN.md §2.1)
 # ---------------------------------------------------------------------------
-def _hint_unary_swap(node: Node, ctx: tuple) -> int:
-    """Commute id of the result of exchanging `node` with its unary child —
-    computable from interned child ids without building the tree."""
-    child = node.children[0]
-    x_cid = commute_id(child.children[0])
-    return intern_commute_key(
-        child.name, (intern_commute_key(node.name, (x_cid,)),))
+def _first_name(tree: Node) -> str:
+    return min(n.name for n in tree.iter_nodes())
 
 
-def _hint_rotate(node: Node, ctx: tuple) -> int:
-    """Commute id of the (conjugate) rotation result.  The plain rotation
-    splits off the child's first grandchild when the child sits left
-    (p(a(X,Y),Z) -> a(X, p(Y,Z))) and its second when it sits right
-    (p(X, a(Y,Z)) -> a(p(X,Y), Z)); the conjugate splits off the other."""
-    side, conjugate = ctx
-    child = node.children[side]
-    other_cid = commute_id(node.children[1 - side])
-    g1, g2 = (commute_id(g) for g in child.children)
-    out_cid, in_cid = (g1, g2) if side == 0 else (g2, g1)
-    if conjugate:
-        out_cid, in_cid = in_cid, out_cid
-    return intern_commute_key(child.name, (out_cid, intern_commute_key(
-        node.name, (in_cid, other_cid))))
+def as_built(tree: Node, memo: dict) -> Node:
+    """`tree` in the one orientation of its commute class that does not
+    depend on the path a search took to it: every keyed binary operator
+    (Match, CoGroup) with its inputs in the order it was built with
+    (`reorder.commute` undone), every Cross with the input holding the
+    least operator name first (rotations regroup a Cross's inputs freely).
+    Memoized per structural id in `memo`."""
+    sid = struct_id(tree)
+    hit = memo.get(sid)
+    if hit is not None:
+        return hit
+    out = tree
+    for i, c in enumerate(tree.children):
+        b = as_built(c, memo)
+        if b is not c:
+            out = replace_child(out, i, b)
+    if isinstance(out, CrossOp):
+        flip = _first_name(out.right) < _first_name(out.left)
+    else:
+        flip = is_commuted(out)
+    if flip:
+        out = commute(out)
+    memo[sid] = memo[struct_id(out)] = out
+    return out
 
 
-# Per-rule result-id precomputation (DESIGN.md §2 hash-consing fast path).
-# Only rules whose guard is EXACT (sufficient for admissibility, modulo the
-# attrs-preservation check) may appear here: on an intern hit the engine
-# accepts the cached representative without running `apply`.
-_CID_HINTS = {
-    "swap-unary": _hint_unary_swap,
-    "push-limit": _hint_unary_swap,
-    "pull-limit": _hint_unary_swap,
-    "rotate": _hint_rotate,
-}
+class GroupMemo:
+    """The rewrite closure of a flow as a memo of logical groups.
 
+    A group is a logical equivalence class of sub-flows: the same operators
+    over the same base relations with the same output attributes — the
+    `_mtab_key` of Algorithm 1 carried to trees, where a split Reduce's
+    merge and combiner (`<name>.merge`, `<name>.pre`) count as the Reduce
+    `<name>` they came from.  An expression is one operator over child
+    groups, kept as a tree whose children are members of those groups, in
+    its built orientation (`as_built`); expressions are told apart by the
+    operator's name and the child groups, and keep the order in which they
+    were found, the flow's own first.
 
-class RewriteEngine:
-    """Single-step rewrite lists over COMMUTE CLASSES, memoized per class.
-
-    Commutation is unconditionally valid on every binary operator, so the
-    rewrite graph is closed under it: reachability of a plan is equivalent to
-    reachability of its side-order-insensitive class (`commute_id`).  The
-    engine therefore explores one representative per class and never walks
-    the 2^(#binary ops) orientation orbit — rotations, whose applicability
-    does depend on orientation, are *conjugate-completed*: from a class
-    {{X,Y},Z} both regroupings {{X,Z},Y} (plain rotation) and {{Y,Z},X}
-    (rotation of the commuted child) are generated, which covers every
-    rotation any orbit member could perform.  Unary swaps and binary
-    pushes/pulls are orientation-insensitive (both sides are tried).
-
-    `rewrites(node)` returns `(trees, cids)` — one representative per class
-    reachable from `node`'s class by a single non-commute rewrite.  Results
-    are interned per class id and the result id is computed from child ids
-    BEFORE building a tree, so a shape seen earlier in the run costs one
-    dict probe instead of a node construction + schema resolution.  The
-    engine is scoped to one enumeration run: equal ids imply interchangeable
-    subtrees only among trees reachable from a single flow.
-
-    `orbit(tree)` re-materializes the orientation variants of one class
-    (cheap clones, deduplicated by structural id) for callers that need
-    commuted plans as distinct objects (`include_commutes=True`).
-
-    `split_reduces=True` (the default) additionally explores decomposable-
-    aggregation splits: `reduce → merge∘pre`, their inverses, and the eager
-    push of a combiner below a PK-FK Match."""
+    `explore` applies the rules of `reorder.RULES` (all but `commute`,
+    which orientation covers) at the root of every expression, under every
+    binding of one child to each expression of its group — below a Reduce,
+    of a grandchild too, which the combiner rules read — until no rule adds
+    an expression.  Rules read only a node, its children and grandchildren,
+    and beyond them only what every member of a group shares (attribute
+    sets, whether it is a Source), so the trees the groups compose are the
+    closure's commute classes.  `count` sizes the closure without composing
+    it; `trees` composes it."""
 
     def __init__(self, split_reduces: bool = True):
-        self._memo: dict[int, tuple[list[Node], list[int]]] = {}
-        self._reps: dict[int, Node] = {}
-        self._variants: dict[int, list[Node]] = {}
-        self._split = split_reduces
+        self.exprs: dict[tuple, list[Node]] = {}
+        self._ekeys: set = set()
+        self._keys: dict[int, tuple] = {}      # struct_id -> group key
+        self._added: set = set()               # struct_ids registered
+        self._bound: set = set()               # bindings rewritten
+        self._built: dict[int, Node] = {}
+        self.root: Optional[tuple] = None
+        self._rules = [r for r in RULES if r.in_engine
+                       and (split_reduces or not r.needs_split)]
 
-    def intern(self, node: Node) -> Node:
-        return self._reps.setdefault(commute_id(node), node)
+    def key(self, tree: Node) -> tuple:
+        """Group key of `tree`: (operator names, attributes)."""
+        sid = struct_id(tree)
+        k = self._keys.get(sid)
+        if k is None:
+            names: set = set()
+            for c in tree.children:
+                names |= self.key(c)[0]
+            udf = getattr(tree, "udf", None)
+            while hasattr(udf, "__reduce_extension__"):
+                udf = udf.__reduce_extension__[0]
+            split = getattr(udf, "__combine_split__", None)
+            if split is not None:
+                names.discard(split[0] + ".pre")
+                names.add(split[0])
+            else:
+                names.add(tree.name)
+            k = self._keys[sid] = (frozenset(names), tree.attrs())
+        return k
 
-    def _local_into(self, node: Node, trees: list, cids: list) -> None:
-        """Registry walk: every in-engine rule's (pattern, guard, apply) runs
-        uniformly; rules with a cid hint resolve against the intern table
-        BEFORE building a tree (see `_CID_HINTS`)."""
-        reps = self._reps
-        emitted: set = set()
-        for rule in RULES:
-            if not rule.in_engine or (rule.needs_split and not self._split):
-                continue
-            hint_fn = _CID_HINTS.get(rule.name)
+    def add(self, tree: Node) -> tuple:
+        """Register `tree` (built orientation) and every subtree of it as
+        expressions of their groups; returns its group key."""
+        k = self.key(tree)
+        sid = struct_id(tree)
+        if sid in self._added:
+            return k
+        self._added.add(sid)
+        ek = (tree.name, tuple(self.add(c) for c in tree.children))
+        if ek not in self._ekeys:
+            self._ekeys.add(ek)
+            self.exprs.setdefault(k, []).append(tree)
+        return k
+
+    def local(self, node: Node) -> list[Node]:
+        """Every tree one rule builds at `node`'s root, in built
+        orientation — the registry walk `explore` runs per binding."""
+        out = []
+        for rule in self._rules:
             for ctx in rule.pattern(node):
-                if not rule.guard(node, ctx):
+                if rule.guard(node, ctx):
+                    t = rule.apply(node, ctx)
+                    if t is not None:
+                        out.append(as_built(t, self._built))
+        return out
+
+    def _bindings(self, e: Node):
+        """`e` with one child bound to each expression of its group, and
+        below a Reduce also one grandchild — each binding once per memo."""
+        for i, c in enumerate(e.children):
+            for x in self.exprs[self.key(c)]:
+                bid = (id(e), i, id(x))
+                if bid not in self._bound:
+                    self._bound.add(bid)
+                    yield e if x is c else replace_child(e, i, x)
+                if not isinstance(e, ReduceOp):
                     continue
-                if hint_fn is not None:
-                    hint = hint_fn(node, ctx)
-                    if hint in emitted:
-                        continue  # e.g. self-conjugate rotation
-                    rep = reps.get(hint)
-                    if rep is not None:
-                        # same attrs-preservation check as _valid(like=node)
-                        if rep.attrs() == node.attrs():
-                            trees.append(rep)
-                            cids.append(hint)
-                            emitted.add(hint)
-                        continue
-                tree = rule.apply(node, ctx)
-                if tree is not None:
-                    c = commute_id(tree)
-                    trees.append(reps.setdefault(c, tree))
-                    cids.append(c)
-                    emitted.add(c)
+                for j, g in enumerate(x.children):
+                    for y in self.exprs[self.key(g)]:
+                        bid = (id(e), i, id(x), j, id(y))
+                        if y is not g and bid not in self._bound:
+                            self._bound.add(bid)
+                            yield replace_child(
+                                e, i, replace_child(x, j, y))
 
-    def rewrites(self, node: Node) -> tuple[list[Node], list[int]]:
-        cid = commute_id(node)
-        hit = self._memo.get(cid)
-        if hit is not None:
-            return hit
-        reps = self._reps
-        trees: list[Node] = []
-        cids: list[int] = []
-        self._local_into(node, trees, cids)
-        children = node.children
-        if children:
-            child_cids = tuple(commute_id(c) for c in children)
-            ordered = commute_ordered(node)
-            for i, child in enumerate(children):
-                sub_trees, sub_cids = self.rewrites(child)
-                for sub, sub_cid in zip(sub_trees, sub_cids):
-                    # id of the substituted tree is known before building it
-                    new_cid = intern_commute_key(
-                        node.name,
-                        child_cids[:i] + (sub_cid,) + child_cids[i + 1:],
-                        ordered=ordered)
-                    rep = reps.get(new_cid)
-                    if rep is None:
-                        rep = replace_child(node, i, sub)
-                        if rep is None:  # schema conflict after substitution
-                            continue
-                        reps[new_cid] = rep
-                    trees.append(rep)
-                    cids.append(new_cid)
-        out = (trees, cids)
-        self._memo[cid] = out
-        return out
+    def explore(self, flow: Node, max_plans: Optional[int] = None) -> tuple:
+        """Grow the memo from `flow` to its fixed point; returns the root
+        group's key (also kept as `root`).  With `max_plans`, raises
+        `PlanSpaceExceeded` once the classes composed so far — a lower bound
+        on the closure — exceed it (checked whenever the expressions
+        double past it, so a huge lattice is never built out)."""
+        self.root = self.add(as_built(flow, self._built))
+        check = max_plans
+        grown = True
+        while grown:
+            grown = False
+            for k in list(self.exprs):
+                for e in list(self.exprs[k]):
+                    for b in self._bindings(e):
+                        n = len(self._ekeys)
+                        for t in self.local(b):
+                            self.add(t)
+                        grown |= len(self._ekeys) > n
+                        if check is not None and len(self._ekeys) > check:
+                            check *= 2
+                            if self.count(self.root, False) > max_plans:
+                                raise PlanSpaceExceeded(max_plans, max_plans)
+        return self.root
 
-    # -- orientation orbit ---------------------------------------------------
-    def _subtree_variants(self, node: Node) -> list[Node]:
-        sid = struct_id(node)
-        hit = self._variants.get(sid)
-        if hit is not None:
-            return hit
-        if not node.children:
-            out = [node]
-        elif node.is_unary:
-            out = []
-            for v in self._subtree_variants(node.children[0]):
-                t = node if v is node.children[0] else replace_child(node, 0, v)
-                if t is not None:
-                    out.append(t)
-        else:
-            seen: set = set()
-            out = []
-            lefts = self._subtree_variants(node.children[0])
-            rights = self._subtree_variants(node.children[1])
-            for lv in lefts:
-                for rv in rights:
-                    if lv is node.children[0] and rv is node.children[1]:
-                        base: Optional[Node] = node
-                    else:
-                        base = replace_child(node, 0, lv)
-                        if base is not None:
-                            base = replace_child(base, 1, rv)
-                    for t in (base, commute(base) if base is not None
-                              else None):
-                        if t is None:
-                            continue
-                        s = struct_id(t)
-                        if s not in seen:
-                            seen.add(s)
-                            out.append(t)
-        self._variants[sid] = out
-        return out
+    def count(self, key: tuple, include_commutes: bool = True,
+              memo: Optional[dict] = None) -> int:
+        """Flows the closure enumerates for the group `key`: one per class,
+        times its orientation orbit with `include_commutes`."""
+        memo = {} if memo is None else memo
+        hit = memo.get(key)
+        if hit is None:
+            hit = 0
+            for e in self.exprs[key]:
+                n = 2 if include_commutes and len(e.children) == 2 \
+                    and not commute_ordered(e) else 1
+                for c in e.children:
+                    n *= self.count(self.key(c), include_commutes, memo)
+                hit += n
+            memo[key] = hit
+        return hit
 
-    def orbit(self, tree: Node) -> list[Node]:
-        """All orientation variants of `tree`'s commute class, the class
-        representative first, deduplicated by structural id."""
-        tid = struct_id(tree)
-        return [tree] + [v for v in self._subtree_variants(tree)
-                         if struct_id(v) != tid]
+    def trees(self, key: tuple) -> Iterable[Node]:
+        """Every class of group `key`, lazily, in the memo's order: by
+        expression, then the first child's classes before the second's."""
+        for e in self.exprs[key]:
+            yield from self._over(e, 0)
+
+    def _over(self, node: Node, i: int) -> Iterable[Node]:
+        if i == len(node.children):
+            yield node
+            return
+        c = node.children[i]
+        for t in self.trees(self.key(c)):
+            yield from self._over(node if t is c else
+                                  replace_child(node, i, t), i + 1)
+
+
+def orbit(tree: Node) -> Iterable[Node]:
+    """Every orientation of `tree`'s binary operators, `tree` first: the
+    first input's orientations, then the second's, then the operator's own
+    (anti joins keep theirs)."""
+    kids = tree.children
+    if not kids:
+        yield tree
+    elif len(kids) == 1:
+        for v in orbit(kids[0]):
+            yield tree if v is kids[0] else replace_child(tree, 0, v)
+    else:
+        for lv in orbit(kids[0]):
+            base = tree if lv is kids[0] else replace_child(tree, 0, lv)
+            for rv in orbit(kids[1]):
+                t = base if rv is kids[1] else replace_child(base, 1, rv)
+                yield t
+                c = commute(t)
+                if c is not None:
+                    yield c
 
 
 def closure(flow: Node, max_plans: int = 20000,
-            engine: Optional[RewriteEngine] = None,
-            include_commutes: bool = True,
-            split_reduces: bool = True) -> Iterable[Node]:
-    """Lazily yield every flow reachable from `flow` by valid rewrites, in
-    discovery order (depth-first over the class graph, `flow`'s class first;
-    with `include_commutes=True` each class's orientation orbit is emitted
-    when the class is discovered).
+            include_commutes: bool = True, split_reduces: bool = True,
+            memo: Optional[GroupMemo] = None) -> Iterable[Node]:
+    """Lazily yield every flow reachable from `flow` by valid rewrites: one
+    tree per commute class in the memo's order (`GroupMemo.trees`; the
+    first is `flow` itself, in built orientation), each followed by its
+    orientation orbit with `include_commutes=True`.  `memo` is the memo of
+    `flow`, already explored, to reuse.
 
     The interleaved optimizer consumes this generator directly so costing
     overlaps enumeration.  Raises `PlanSpaceExceeded` when more than
-    `max_plans` plans are yielded."""
-    engine = engine or RewriteEngine(split_reduces=split_reduces)
-    root = engine.intern(flow)
-    seen = {commute_id(root)}
+    `max_plans` plans would be yielded."""
+    if memo is None:
+        memo = GroupMemo(split_reduces=split_reduces)
+        memo.explore(flow, max_plans=max_plans)
     count = 0
-
-    def emit(rep: Node):
-        nonlocal count
-        members = engine.orbit(rep) if include_commutes else [rep]
-        for m in members:
+    for rep in memo.trees(memo.root):
+        for m in orbit(rep) if include_commutes else (rep,):
             if count >= max_plans:
                 raise PlanSpaceExceeded(max_plans, count)
             count += 1
             yield m
 
-    yield from emit(root)
-    work = [root]
-    while work:
-        cur = work.pop()
-        trees, cids = engine.rewrites(cur)
-        for t, c in zip(trees, cids):
-            if c not in seen:
-                seen.add(c)
-                yield from emit(t)
-                work.append(t)
-
 
 def enumerate_plans(flow: Node, max_plans: int = 20000,
                     include_commutes: bool = True,
-                    engine: Optional[RewriteEngine] = None,
                     split_reduces: bool = True) -> list[Node]:
     """All data flows reachable from `flow` by valid pairwise reorderings.
 
@@ -346,10 +348,7 @@ def enumerate_plans(flow: Node, max_plans: int = 20000,
     `split_reduces=False` restricts the space to pure reorderings (no
     combiner/merge splits of decomposable Reduces).
     """
-    return list(closure(flow, max_plans=max_plans, engine=engine,
+    return list(closure(flow, max_plans=max_plans,
                         include_commutes=include_commutes,
                         split_reduces=split_reduces))
 
-
-def count_plans(flow: Node, **kw) -> int:
-    return len(enumerate_plans(flow, **kw))

@@ -2,8 +2,10 @@
 
     optimize(flow) =
         SCA properties (already attached at flow construction)
-        -> interleaved search: each flow discovered by the rewrite closure is
-           priced IMMEDIATELY through the shared Volcano memo, and flows whose
+        -> the memo of logical groups (`enumeration.GroupMemo`)
+        -> a large space: the group search prices the groups (`_GroupSearch`)
+           a small one: each flow of the rewrite closure is priced
+           IMMEDIATELY through the shared Volcano memo, and flows whose
            admissible lower bound (`physical.cost_lower_bound`) already
            exceeds the best cost seen so far are skipped (branch-and-bound)
         -> rank priced flows by estimated cost, return the best
@@ -13,9 +15,9 @@ so the (often heavily overlapping) enumerated flows are priced with shared
 work — the integration of enumeration and costing sketched in the paper's
 Sec. 6, plus the Cascades-style bound pruning from the Volcano line of work.
 
-Pruning only skips flows that provably cannot beat the incumbent, so `best`
-is identical (same flow order, same cost) to exhaustively pricing every
-enumerated flow — `optimize_two_phase` keeps the original enumerate-then-cost
+Pruning only skips flows that provably cannot beat the incumbent, and the
+group search is exact (DESIGN.md §4.2), so `best` is identical (same flow
+order, same cost) to exhaustively pricing every enumerated flow — `optimize_two_phase` keeps the original enumerate-then-cost
 pipeline precisely so tests and benchmarks can verify that equivalence.
 Benchmarks that need the full cost spectrum (the paper's Figs. 5-7 rank
 plots) pass `prune=False`.
@@ -24,17 +26,17 @@ plots) pass `prune=False`.
 from __future__ import annotations
 
 import dataclasses
-import math
+import itertools
 import time
 from typing import Optional
 
-from ..obs import span
+from .. import obs
 from .cost import estimate
-from .enumeration import RewriteEngine, _mtab_key, closure, enumerate_plans
-from .operators import MapOp, Node, ReduceOp, Source, commute_id
-from .physical import (Ctx, PhysPlan, _expand, _prune, best_physical,
+from .enumeration import GroupMemo, closure, enumerate_plans
+from .operators import MapOp, Node, Source, replace_child
+from .physical import (Ctx, PhysPlan, _expand, best_physical,
                        cost_lower_bound, default_mesh_shards, dop_ladder)
-from .reorder import reorderable
+from .reorder import commute
 
 
 @dataclasses.dataclass(frozen=True)
@@ -115,145 +117,137 @@ class OptResult:
 
 
 # ---------------------------------------------------------------------------
-# Group-level memoized search for unary flows (DESIGN.md §4.2)
+# Memoized group search (DESIGN.md §4.2)
 #
-# On purely unary flows the rewrite closure equals the paper's Algorithm-1
-# space (tested), and Algorithm 1's memo insight — all orders of the same
-# operator multiset over the same source share one alternative set — lets the
-# search run over GROUPS (operator subsets, O(2^n) of them) instead of
-# materialized orderings (O(n!)).  Costing is interleaved per group: each
-# group keeps, per (output-stats, physical-props) key, the cheapest physical
-# sub-plan over any reachable ordering.  Keying by output stats keeps the
-# search exact under the order-SENSITIVE cardinality estimator: two orderings
+# Algorithm 1's memo insight — all orders of one operator set over the same
+# inputs share one alternative set — carried to trees: the search runs over
+# the logical groups of `enumeration.GroupMemo` (connected sub-flows, O(2^n)
+# of them) instead of materialized flows (the closure holds a Catalan-times-
+# factorial number of them).  Costing is interleaved per group: each group
+# keeps, per (output stats, physical props) key, the cheapest physical
+# sub-plan over any of its members.  Keying by output stats keeps the
+# search exact under the order-SENSITIVE cardinality estimator: two members
 # only share a memo slot when every enclosing operator would be priced
 # identically on top of them.
 # ---------------------------------------------------------------------------
-def _is_unary_flow(flow: Node) -> bool:
-    n = flow
-    while not isinstance(n, Source):
-        if not isinstance(n, (MapOp, ReduceOp)):
-            return False
-        n = n.children[0]
-    return True
+class _GroupSearch:
+    """Volcano costing over the groups of an explored `GroupMemo`.
 
+    Ties go as in the closure, whose stable sort keeps the first flow it
+    yielded: every kept plan carries its tree's place in the closure's
+    order — the class's (`GroupMemo.trees`: expression index, then the
+    inputs' classes) and, within the class, the orientation's (`orbit`:
+    the inputs' orientations, then the operator's) — and the least
+    (cost, class place, orientation place) wins each memo slot.  Both
+    places compare input by input, so the least for an operator is built
+    from the least of each input's slot."""
 
-def _has_splittable_reduce(flow: Node) -> bool:
-    """Does the closure explore combiner/merge splits for this flow?  The
-    group-lattice fast path only covers reorderings, so such flows must go
-    through the closure to keep `optimize == optimize_two_phase`."""
-    return any(isinstance(n, ReduceOp)
-               and (n.combiner or n.props.combine is not None
-                    or getattr(n.udf, "__combine_split__", None) is not None)
-               for n in flow.iter_nodes())
-
-
-class _UnaryGroupSearch:
-    """Interleaved Algorithm-1 exploration + Volcano costing over op groups."""
-
-    def __init__(self, ctx: Ctx, stats_memo: dict):
+    def __init__(self, memo: GroupMemo, ctx: Ctx, include_commutes: bool):
+        self.memo = memo
         self.ctx = ctx
-        self.stats_memo = stats_memo
-        self._roots: dict = {}
+        self.commutes = include_commutes
+        self.stats_memo: dict = {}
+        self.priced = 0                    # physical alternatives built
         self._cands: dict = {}
-        self._counts: dict = {}
+        self._place: dict = {}             # id(kept plan) -> its places
 
-    # -- logical exploration (Algorithm 1's candidate-root recursion) -------
-    def roots(self, flow: Node) -> list:
-        """[(root operator instance, representative flow of group-minus-root)]
-        — every operator that can top some reachable ordering of flow's
-        group.  Mirrors Algorithm 1 lines 19-27: the original root always
-        qualifies; a root s of the sub-group additionally qualifies when
-        `reorderable(r, s)` (the checks only read group-invariant inputs:
-        UDF properties, keys, and the sub-group's attribute set)."""
-        key = _mtab_key(flow)
-        hit = self._roots.get(key)
-        if hit is not None:
-            return hit
-        out: list = []
-        if not isinstance(flow, Source):
-            r = flow
-            sub = flow.children[0]
-            out.append((r, sub))
-            names = {r.name}
-            for s, s_sub in self.roots(sub):
-                if s.name in names or not reorderable(r, s):
-                    continue
-                try:
-                    alt_sub = r.with_children(s_sub)  # Alg. 1 line 24
-                except (ValueError, KeyError):
-                    continue
-                names.add(s.name)
-                out.append((s, alt_sub))
-        self._roots[key] = out
-        return out
+    def _slot(self, node: Node) -> tuple:
+        """What an enclosing operator's pricing reads of a sub-plan besides
+        its props: the output stats (same dop as `_expand`, so the
+        struct_id-keyed stats memo is shared) and whether its root is a Map
+        (the fused-span candidates ask)."""
+        st = estimate(node, self.stats_memo, self.ctx.dop)
+        return (st.rows, st.width, st.distinct, isinstance(node, MapOp))
 
-    def count(self, flow: Node) -> int:
-        """Number of distinct reachable orderings (== len(enumerate_plans))."""
-        key = _mtab_key(flow)
-        hit = self._counts.get(key)
+    def _price(self, idx: int, e: Node, out: dict, tag: tuple = ()) -> None:
+        """Price expression `e` (number `idx` of its group), in each
+        orientation, over every slot of its child groups into `out`:
+        {slot: {Props: PhysPlan}}."""
+        orients = [(e, False)]
+        if self.commutes and e.is_binary:
+            c = commute(e)
+            if c is not None:
+                orients.append((c, True))
+        for v, flipped in orients:
+            slots = [list(self.cands(self.memo.key(c)).values())
+                     for c in v.children]
+            for maps in itertools.product(*slots):
+                n = v
+                for i, pmap in enumerate(maps):
+                    rep = next(iter(pmap.values())).node
+                    if n.children[i] is not rep:
+                        n = replace_child(n, i, rep)
+                plans = _expand(n, self.ctx, self.stats_memo, list(maps))
+                self.priced += len(plans)
+                bucket = out.setdefault(tag + self._slot(n), {})
+                for p in plans:
+                    ins = [self._place[id(i)] for i in p.inputs]
+                    if flipped:
+                        ins.reverse()
+                    cls = (idx,) + tuple(c for c, _ in ins)
+                    ori = tuple(o for _, o in ins)
+                    if len(ins) == 2:
+                        ori += (flipped,)
+                    elif ins:
+                        ori = ins[0][1]
+                    rank = (p.exact_cost, cls, ori)
+                    cur = bucket.get(p.props)
+                    if cur is None or rank < self._rank(cur):
+                        bucket[p.props] = p
+                        self._place[id(p)] = (cls, ori)
+
+    def _rank(self, p: PhysPlan) -> tuple:
+        return (p.exact_cost,) + self._place[id(p)]
+
+    def cands(self, key: tuple) -> dict:
+        """{slot: {Props: PhysPlan}} of one group.  A plan's `node` is its
+        operator over one member per child slot; `_materialize` rebuilds
+        the tree its inputs actually hold."""
+        hit = self._cands.get(key)
         if hit is None:
-            if isinstance(flow, Source):
-                hit = 1
-            else:
-                hit = sum(self.count(sub) for _, sub in self.roots(flow))
-            self._counts[key] = hit
+            hit = {}
+            for idx, e in enumerate(self.memo.exprs[key]):
+                self._price(idx, e, hit)
+            self._cands[key] = hit
         return hit
 
-    # -- interleaved costing ------------------------------------------------
-    def _stats_key(self, node: Node) -> tuple:
-        # same dop as _expand so the (struct_id, dop)-keyed memo is shared
-        st = estimate(node, self.stats_memo, self.ctx.dop)
-        return (st.rows, st.width, st.distinct)
-
-    def cands(self, flow: Node) -> dict:
-        """{stats_key: {Props: (PhysPlan, flow_tree)}} — cheapest physical
-        sub-plan per (output stats, properties) over every reachable ordering
-        of flow's group.  Dropping a costlier same-key entry is exact: any
-        enclosing operator's cost depends on the sub-plan only through its
-        stats, properties and cost."""
-        key = _mtab_key(flow)
-        hit = self._cands.get(key)
-        if hit is not None:
-            return hit
+    def ranked(self, root: tuple) -> list[RankedPlan]:
+        """The root group's plans — the cheapest per root expression, slot
+        and props — in the closure's order: by cost, then place."""
         out: dict = {}
-        if isinstance(flow, Source):
-            plans = _prune(_expand(flow, self.ctx, self.stats_memo, []))
-            out[self._stats_key(flow)] = {
-                p: (plan, flow) for p, plan in plans.items()}
-        else:
-            for s, s_sub in self.roots(flow):
-                for pmap in self.cands(s_sub).values():
-                    for iprops, (iplan, itree) in pmap.items():
-                        try:
-                            n = s.with_children(itree)
-                        except (ValueError, KeyError):
-                            continue
-                        bucket = out.setdefault(self._stats_key(n), {})
-                        for p in _expand(n, self.ctx, self.stats_memo,
-                                         [{iprops: iplan}]):
-                            cur = bucket.get(p.props)
-                            if cur is None or p.total_cost.total \
-                                    < cur[0].total_cost.total:
-                                bucket[p.props] = (p, n)
-        self._cands[key] = out
-        return out
-
-    def ranked(self, flow: Node) -> list[RankedPlan]:
-        """Root-group entries as RankedPlans (cost-ascending, stable)."""
-        out = []
-        for pmap in self.cands(flow).values():
-            for plan, tree in pmap.values():
-                out.append(RankedPlan(flow=tree, plan=plan,
-                                      cost=plan.total_cost.total))
-        out.sort(key=lambda r: r.cost)
-        return out
+        for idx, e in enumerate(self.memo.exprs[root]):
+            self._price(idx, e, out, (idx,))
+        kept = sorted((p for pmap in out.values() for p in pmap.values()),
+                      key=self._rank)
+        built: dict = {}
+        plans = [_materialize(p, built) for p in kept]
+        return [RankedPlan(flow=p.node, plan=p, cost=p.cost)
+                for p in plans]
 
 
-# number of orderings above which a unary flow is searched group-wise rather
-# than through the materializing closure (which must touch every ordering)
+def _materialize(plan: PhysPlan, built: dict) -> PhysPlan:
+    """`plan` with every operator re-rooted over its inputs' own nodes, so
+    `plan.node` is the tree the plan prices (costs carry over: the group
+    search only pairs an operator with a member whose slot is the one the
+    plan was priced on)."""
+    hit = built.get(id(plan))
+    if hit is None:
+        inputs = tuple(_materialize(i, built) for i in plan.inputs)
+        node = plan.node
+        for i, p in enumerate(inputs):
+            if node.children[i] is not p.node:
+                node = replace_child(node, i, p.node)
+        hit = built[id(plan)] = dataclasses.replace(plan, node=node,
+                                                    inputs=inputs)
+    return hit
+
+
+# flows the closure enumerates above which the group search prices the
+# memo instead (below it every flow is priced, so rank-spectrum consumers
+# keep a full `ranked` list, and `max_plans` still bounds the space)
 GROUP_SEARCH_THRESHOLD = 2000
-# fully-commuting flows make the group lattice itself exponential (2^n);
-# past this many operators fall back to the closure + its max_plans guard
+# a flow of n operators has up to 2^n groups: past this many operators the
+# closure and its max_plans guard take the flow
 GROUP_SEARCH_MAX_OPS = 16
 
 
@@ -267,39 +261,43 @@ def optimize(flow: Node, ctx: Optional[Ctx] = None, max_plans: int = 20000,
     side-order-insensitive plan class, exactly as the two-phase pipeline
     deduplicated before pricing.
 
-    Purely unary flows whose reachable space exceeds GROUP_SEARCH_THRESHOLD
-    orderings are searched group-wise (`_UnaryGroupSearch`): the memoized
-    lattice of operator subsets is priced instead of each ordering, so e.g.
-    a fully-commuting 9-map chain (9! = 362880 orderings) costs ~2^9 group
+    With `prune`, flows whose closure holds more than GROUP_SEARCH_THRESHOLD
+    flows are searched group-wise (`_GroupSearch`): the memo of logical
+    groups is priced instead of each flow, so e.g. TPC-H Q7's six-relation
+    join (221 056 flows with commutes) costs a few hundred group
     expansions.  `max_plans` caps MATERIALIZED plans (the closure paths and
     `enumerate_plans` raise `PlanSpaceExceeded` past it); the group search
-    never materializes orderings, so the cap does not apply there."""
-    with span("optimize"):
+    never materializes flows, so the cap does not apply there.
+
+    Counters (`repro.obs`): `optimize.groups`, the memo groups built, and
+    `optimize.priced`, the physical alternatives priced."""
+    with obs.span("optimize"):
         return _optimize(flow, ctx, max_plans, include_commutes, prune)
 
 
 def _optimize(flow: Node, ctx: Optional[Ctx], max_plans: int,
               include_commutes: bool, prune: bool) -> OptResult:
     ctx = ctx or Ctx()
-    if prune and _is_unary_flow(flow) and not _has_splittable_reduce(flow):
-        n_ops = sum(1 for _ in flow.iter_nodes()) - 1
-        # n_ops! bounds the ordering count, so small flows skip the lattice
-        # construction that exact counting requires
-        if n_ops <= GROUP_SEARCH_MAX_OPS \
-                and math.factorial(n_ops) > GROUP_SEARCH_THRESHOLD:
-            t0 = time.perf_counter()
-            search = _UnaryGroupSearch(ctx, {})
-            total = search.count(flow)
-            if total > GROUP_SEARCH_THRESHOLD:
-                t1 = time.perf_counter()
-                ranked = search.ranked(flow)
-                t2 = time.perf_counter()
-                return OptResult(best=ranked[0], ranked=tuple(ranked),
-                                 enumeration_s=t1 - t0, costing_s=t2 - t1,
-                                 num_enumerated=total,
-                                 num_pruned=total - len(ranked))
-    engine = RewriteEngine()
-    memo: dict = {}
+    n_ops = sum(not isinstance(n, Source) for n in flow.iter_nodes())
+    if prune and n_ops <= GROUP_SEARCH_MAX_OPS:
+        t0 = time.perf_counter()
+        memo = GroupMemo()
+        root = memo.explore(flow)
+        obs.count("optimize.groups", len(memo.exprs))
+        total = memo.count(root, include_commutes)
+        if total > GROUP_SEARCH_THRESHOLD:
+            t1 = time.perf_counter()
+            search = _GroupSearch(memo, ctx, include_commutes)
+            ranked = search.ranked(root)
+            obs.count("optimize.priced", search.priced)
+            t2 = time.perf_counter()
+            return OptResult(best=ranked[0], ranked=tuple(ranked),
+                             enumeration_s=t1 - t0, costing_s=t2 - t1,
+                             num_enumerated=total,
+                             num_pruned=total - len(ranked))
+    else:
+        memo = None
+    plans: dict = {}
     stats_memo: dict = {}
     bound_memo: dict = {}
     ranked: list[RankedPlan] = []
@@ -309,8 +307,8 @@ def _optimize(flow: Node, ctx: Optional[Ctx], max_plans: int,
     costing_s = 0.0
 
     t0 = time.perf_counter()
-    for f in closure(flow, max_plans=max_plans, engine=engine,
-                     include_commutes=include_commutes):
+    for f in closure(flow, max_plans=max_plans,
+                     include_commutes=include_commutes, memo=memo):
         num_enumerated += 1
         tc = time.perf_counter()
         if prune and ranked:
@@ -324,15 +322,15 @@ def _optimize(flow: Node, ctx: Optional[Ctx], max_plans: int,
                 num_pruned += 1
                 costing_s += time.perf_counter() - tc
                 continue
-        plan = best_physical(f, ctx, memo, stats_memo)
-        cost = plan.total_cost.total
+        plan = best_physical(f, ctx, plans, stats_memo)
+        cost = plan.cost
         ranked.append(RankedPlan(flow=f, plan=plan, cost=cost))
         if cost < upper:
             upper = cost
         costing_s += time.perf_counter() - tc
     total_s = time.perf_counter() - t0
 
-    ranked.sort(key=lambda r: r.cost)  # stable: discovery order breaks ties
+    ranked.sort(key=lambda r: r.plan.exact_cost)  # stable: order breaks ties
     return OptResult(best=ranked[0], ranked=tuple(ranked),
                      enumeration_s=total_s - costing_s, costing_s=costing_s,
                      num_enumerated=num_enumerated, num_pruned=num_pruned)
@@ -403,9 +401,9 @@ def optimize_two_phase(flow: Node, ctx: Optional[Ctx] = None,
     for f in flows:
         plan = best_physical(f, ctx, memo, stats_memo)
         ranked.append(RankedPlan(flow=f, plan=plan,
-                                 cost=plan.total_cost.total))
+                                 cost=plan.cost))
     t2 = time.perf_counter()
-    ranked.sort(key=lambda r: r.cost)
+    ranked.sort(key=lambda r: r.plan.exact_cost)
     return OptResult(best=ranked[0], ranked=tuple(ranked),
                      enumeration_s=t1 - t0, costing_s=t2 - t1,
                      num_enumerated=len(flows), num_pruned=0)

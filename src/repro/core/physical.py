@@ -45,7 +45,7 @@ import math
 import os
 from typing import Optional
 
-from .. import hw
+from .. import hw, obs
 from .cost import Stats, estimate, sort_flops
 from .operators import (CoGroupOp, CrossOp, LimitOp, MapOp, MatchOp, Node,
                         ReduceOp, Source, struct_id)
@@ -117,9 +117,6 @@ class CostVec:
     def total(self) -> float:
         return self.net + self.mem + self.cpu
 
-    def __add__(self, o: "CostVec") -> "CostVec":
-        return CostVec(self.net + o.net, self.mem + o.mem, self.cpu + o.cpu)
-
 
 @dataclasses.dataclass(frozen=True)
 class Ctx:
@@ -147,6 +144,14 @@ class Ctx:
         return self.chip.hbm_bandwidth
 
 
+_ULP = 1 << 1074    # every double is an integer multiple of 2**-1074
+
+
+def _exact(x: float) -> int:
+    num, den = x.as_integer_ratio()
+    return num * (_ULP // den)
+
+
 @dataclasses.dataclass(frozen=True)
 class PhysPlan:
     node: Node
@@ -162,17 +167,24 @@ class PhysPlan:
     ship_keys: tuple = ()
 
     @property
-    def total_cost(self) -> CostVec:
-        # cached: plans are immutable and the pruning sweep + branch-and-bound
-        # query this O(plans) times, so the naive O(tree) recursion per call
-        # dominated optimizer time
-        c = self.__dict__.get("_tc")
+    def exact_cost(self) -> int:
+        """Total cost as an exact integer count of 2**-1074 s (the spacing
+        of the smallest doubles): this operator's plus its inputs'.  Exact
+        sums are monotone and independent of the order of the inputs, so
+        two plans compare the same way wherever they sit in a larger plan —
+        what memo searches and tie-breaks rely on."""
+        c = self.__dict__.get("_xc")
         if c is None:
-            c = self.node_cost
+            c = _exact(self.node_cost.total)
             for i in self.inputs:
-                c = c + i.total_cost
-            self.__dict__["_tc"] = c
+                c += i.exact_cost
+            self.__dict__["_xc"] = c
         return c
+
+    @property
+    def cost(self) -> float:
+        """Total seconds: `exact_cost`, rounded once."""
+        return self.exact_cost / _ULP
 
     def pretty(self, indent: int = 0) -> str:
         pad = "  " * indent
@@ -258,20 +270,20 @@ def _prune(cands: list[PhysPlan]) -> dict[Props, PhysPlan]:
     by_prop: dict[Props, PhysPlan] = {}
     for c in cands:
         cur = by_prop.get(c.props)
-        if cur is None or c.total_cost.total < cur.total_cost.total:
+        if cur is None or c.exact_cost < cur.exact_cost:
             by_prop[c.props] = c
     if len(by_prop) <= 1:
         return by_prop
 
-    items = sorted(by_prop.items(), key=lambda kv: kv[1].total_cost.total)
+    items = sorted(by_prop.items(), key=lambda kv: kv[1].exact_cost)
     out: dict[Props, PhysPlan] = {}
     i, n = 0, len(items)
     while i < n:
         # batch of equal-cost entries (ties may dominate each other; mutual
         # dominance is impossible after the per-props dedup above)
         j = i + 1
-        cost_i = items[i][1].total_cost.total
-        while j < n and items[j][1].total_cost.total == cost_i:
+        cost_i = items[i][1].exact_cost
+        while j < n and items[j][1].exact_cost == cost_i:
             j += 1
         batch = items[i:j]
         for p, plan in batch:
@@ -297,7 +309,9 @@ def candidates(node: Node, ctx: Ctx, memo: Optional[dict] = None,
         return hit
     child_cands = [candidates(c, ctx, memo, stats_memo)
                    for c in node.children]
-    pruned = _prune(_expand(node, ctx, stats_memo, child_cands))
+    alts = _expand(node, ctx, stats_memo, child_cands)
+    obs.count("optimize.priced", len(alts))
+    pruned = _prune(alts)
     memo[key] = pruned
     return pruned
 
@@ -435,7 +449,7 @@ def _expand(node: Node, ctx: Ctx, stats_memo: dict,
                     props=_preserved(iprops, node), node_cost=cost))
         else:
             cheap = min(child_cands[0].values(),
-                        key=lambda p: p.total_cost.total)
+                        key=lambda p: p.exact_cost)
             cost = CostVec(net=_t_broadcast(cin.bytes, ctx),
                            mem=_t_mem(cin.bytes * ctx.dop, st.bytes, ctx),
                            cpu=_t_cpu(sort_flops(cin.rows) * ctx.dop, ctx))
@@ -501,8 +515,8 @@ def _expand(node: Node, ctx: Ctx, stats_memo: dict,
         # replicated side's properties, so only its CHEAPEST sub-plan can
         # survive pruning — pairing every forwarded candidate with it yields
         # the same Pareto set as the full product, minus dominated clones.
-        cheap_l = min(lcands.values(), key=lambda p: p.total_cost.total)
-        cheap_r = min(rcands.values(), key=lambda p: p.total_cost.total)
+        cheap_l = min(lcands.values(), key=lambda p: p.exact_cost)
+        cheap_r = min(rcands.values(), key=lambda p: p.exact_cost)
         for bc_side in (0, 1):
             # anti: only broadcast-RIGHT is sound — a replicated LEFT row
             # would be judged against each shard's partial right multiset
@@ -584,7 +598,7 @@ def best_physical(flow: Node, ctx: Optional[Ctx] = None,
     """Cheapest physical plan for one logical flow."""
     ctx = ctx or Ctx()
     cands = candidates(flow, ctx, memo, stats_memo)
-    return min(cands.values(), key=lambda p: p.total_cost.total)
+    return min(cands.values(), key=lambda p: p.exact_cost)
 
 
 # ---------------------------------------------------------------------------
@@ -611,7 +625,7 @@ def _can_partition(node: Node, memo: dict) -> bool:
 
 def cost_lower_bound(node: Node, ctx: Ctx, stats_memo: dict,
                      bound_memo: dict) -> float:
-    """Admissible lower bound on `best_physical(node).total_cost.total`.
+    """Admissible lower bound on `best_physical(node).cost`.
 
     Sums, per operator, only cost terms that EVERY physical alternative pays:
     the HBM traffic of reading inputs and writing output, the UDF flops, and

@@ -538,8 +538,14 @@ def _swap_args_udf(udf):
     return swapped
 
 
+def is_commuted(b: Node) -> bool:
+    """Are `b`'s inputs in the reverse of the order it was built with?"""
+    return hasattr(getattr(b, "udf", None), "__wrapped_pair_udf__")
+
+
 def commute(b: Node) -> Optional[Node]:
-    """Swap the two inputs of a Match/Cross/CoGroup (schema is name-based)."""
+    """Swap the two inputs of a Match/Cross/CoGroup (schema is name-based).
+    Commuting twice gives back the operator as it was built, UDF included."""
     if not _is_binary_op(b):
         return None
     if getattr(b, "anti", False):
@@ -548,7 +554,8 @@ def commute(b: Node) -> Optional[Node]:
     # so the resolved out_schema carries over and no re-validation is needed
     new, d = shallow_clone(b)
     d["left"], d["right"] = b.right, b.left
-    d["udf"] = _swap_args_udf(b.udf)
+    d["udf"] = b.udf.__wrapped_pair_udf__ if is_commuted(b) \
+        else _swap_args_udf(b.udf)
     if not isinstance(b, CrossOp):
         d["left_key"], d["right_key"] = b.right_key, b.left_key
         if b.hints.pk_side in ("left", "right"):
@@ -690,17 +697,21 @@ def reorderable(r: Node, s: Node) -> bool:
 # * `pattern(node)` yields context tuples — one per structural position the
 #   rule could fire at (sides, conjugate flags).  Pure shape matching, no
 #   property checks.
-# * `guard(node, ctx)` decides admissibility from operator properties alone.
-#   For hint-accelerated rules (see enumeration._CID_HINTS) the guard is
-#   EXACT up to the attrs-preservation check; elsewhere it may be a cheap
-#   necessary filter with `apply` holding the full conditions.
+# * `guard(node, ctx)` decides admissibility from operator properties alone;
+#   it may be a cheap necessary filter with `apply` holding the full
+#   conditions.
 # * `apply(node, ctx)` builds the rewritten tree or returns None.
 #
-# `local_rewrites` and the memoized RewriteEngine both walk this registry, so
-# a new operator plugs into enumeration, search, and the differential harness
-# by registering rules here.  `in_engine=False` marks rules the commute-class
-# engine must skip (it explores side-order-insensitive classes, so commute is
-# an orbit materialization, not a class edge).
+# A pattern and its guard may read the node, its children and grandchildren
+# (the combiner rules read that far below a Reduce); deeper, only what all
+# equivalent sub-flows share, such as attribute sets.  The group memo binds
+# that far (`enumeration.GroupMemo`).
+#
+# `local_rewrites` and the group memo both walk this registry, so a new
+# operator plugs into enumeration, search, and the differential harness by
+# registering rules here.  `in_engine=False` marks rules the memo must skip
+# (it explores side-order-insensitive classes, so commute is an orbit
+# materialization, not a class edge).
 # ---------------------------------------------------------------------------
 @dataclasses.dataclass(frozen=True)
 class Rule:
@@ -709,7 +720,7 @@ class Rule:
     guard: object     # (Node, ctx) -> bool
     apply: object     # (Node, ctx) -> Optional[Node]
     needs_split: bool = False   # only explored when split_reduces is on
-    in_engine: bool = True      # walked by RewriteEngine._local_into
+    in_engine: bool = True      # walked by GroupMemo.local
 
 
 def _pat_swap_unary(node):

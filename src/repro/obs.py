@@ -19,9 +19,11 @@ clears both.  Records accumulate until then.
 
 Scope names used by the program: `stage.<kind>.<top operator>` around each
 lowered stage (the operator names `pipeline.stage_key` uses), and inside
-them `compact`, `sort`, `probe` and `wire`.  Span names: `optimize`,
-`compile`, `bind_device` (children `prepare`, `transfer`; counter
-`bind_bytes`), `run_device` (children `lookup`, `dispatch`).
+them `compact`, `sort`, `probe` and `wire`.  Span names: `optimize`
+(counters `optimize.groups`, the logical groups of the plan-space memo
+built, and `optimize.priced`, the physical alternatives the cost model
+priced), `compile`, `bind_device` (children `prepare`, `transfer`;
+counter `bind_bytes`), `run_device` (children `lookup`, `dispatch`).
 """
 
 from __future__ import annotations

@@ -193,3 +193,104 @@ def test_structural_ids_follow_canonical():
     split_cids = {commute_id(p) for p in plans}
     assert {commute_id(p) for p in reorder_only} < split_cids
     assert any(".pre" in p.canonical() for p in plans)
+
+
+# ---------------------------------------------------------------------------
+# The memoized group search on flows with joins (DESIGN.md §4.2)
+# ---------------------------------------------------------------------------
+def _tpch_q7_flow():
+    """TPC-H Q7's six-relation flow from the benchmark's configuration, at
+    a tiny scale (the space does not depend on the row counts)."""
+    import importlib.util
+    import json
+    import os
+
+    here = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chipbench", "configs")
+    with open(os.path.join(here, "tpch-q7-sf1.json")) as f:
+        cfg = json.load(f)
+    cfg.update(lineitem_rows=24_000, supplier_rows=40, part_rows=800,
+               orders_rows=6_000, customer_rows=600)
+    spec = importlib.util.spec_from_file_location(
+        "tpch_q7_sf1_flow", os.path.join(here, "tpch-q7-sf1.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.flow(cfg)
+
+
+def _joined_flowgen_seeds(n: int) -> list:
+    """The first `n` seeds whose `tests/flowgen.py` flow has a binary
+    operator."""
+    from flowgen import random_flow
+    from repro.core.operators import CoGroupOp, CrossOp, MatchOp
+
+    out, seed = [], 0
+    while len(out) < n:
+        root, _ = random_flow(seed)
+        if any(isinstance(x, (MatchOp, CrossOp, CoGroupOp))
+               for x in root.iter_nodes()):
+            out.append(seed)
+        seed += 1
+    return out
+
+
+_GROUP_CASES = (
+    [(f"{name}-commutes-{c}", name, c)
+     for name in ("q7", "q15", "clickstream") for c in (True, False)]
+    + [(f"{b}-5-commutes-{c}", b, c)
+       for b in ("chain_join", "star_join") for c in (True, False)]
+    + [(f"flowgen-{s}-commutes-{c}", s, c)
+       for s in _joined_flowgen_seeds(24) for c in (True, False)]
+    + [("tpch-q7-commutes-False", "tpch-q7", False)])
+
+
+@pytest.mark.parametrize("case,flow_id,include_commutes", _GROUP_CASES,
+                         ids=[c[0] for c in _GROUP_CASES])
+def test_group_search_matches_two_phase(case, flow_id, include_commutes,
+                                        monkeypatch):
+    """Forced onto every flow, the group search finds the exhaustive
+    reference's best plan — the same cost, and on cost ties the same flow,
+    the one the closure yields first — and sizes the same space."""
+    from flowgen import random_flow
+
+    monkeypatch.setattr(optimizer_mod, "GROUP_SEARCH_THRESHOLD", 0)
+    if flow_id in flows.FLOWS:
+        root, _ = flows.FLOWS[flow_id]()
+    elif flow_id == "tpch-q7":
+        root = _tpch_q7_flow()
+    elif isinstance(flow_id, int):
+        root, _ = random_flow(flow_id)
+    else:
+        root = getattr(flows, flow_id)(5)
+    a, b = _assert_same_best(root, include_commutes=include_commutes,
+                             max_plans=300_000)
+    assert a.num_enumerated == b.num_enumerated
+
+
+def test_tpch_q7_group_search_sizes_the_closure():
+    """Q7's closure holds 6,908 commute classes and 221,056 flows; the
+    default search plans it without materializing them."""
+    root = _tpch_q7_flow()
+    res = optimize(root)
+    assert res.num_enumerated == 221_056
+    assert len(res.ranked) < 100
+    assert optimize(root, include_commutes=False).num_enumerated == 6_908
+    assert "JoinSuppNation" in res.best.order()
+
+
+def test_group_search_counters_count_only_when_enabled():
+    from repro import obs
+
+    root = _tpch_q7_flow()
+    obs.reset()
+    optimize(root)
+    assert obs.snapshot()["counts"] == {}
+    obs.enable()
+    try:
+        optimize(root)
+        counts = obs.snapshot()["counts"]
+    finally:
+        obs.disable()
+        obs.reset()
+    assert counts["optimize.groups"] > 6          # more than the relations
+    assert counts["optimize.priced"] >= counts["optimize.groups"]

@@ -79,16 +79,16 @@ def test_prune_never_drops_overall_cheapest():
                                sort=sort))
         out = _prune(cands)
         survivors = list(out.values())
-        best_in = min(c.total_cost.total for c in cands)
-        assert min(s.total_cost.total for s in survivors) == best_in
+        best_in = min(c.cost for c in cands)
+        assert min(s.cost for s in survivors) == best_in
         for s in survivors:
             assert not any(
                 o.props.dominates(s.props) and o.props != s.props
-                and o.total_cost.total <= s.total_cost.total
+                and o.cost <= s.cost
                 for o in survivors)
         for c in cands:
             if all(s is not c for s in survivors):
                 assert any(
                     s.props.dominates(c.props)
-                    and s.total_cost.total <= c.total_cost.total
+                    and s.cost <= c.cost
                     for s in survivors), "dropped plan has no dominator"

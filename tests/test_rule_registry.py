@@ -22,8 +22,9 @@ import numpy as np
 import pytest
 
 from repro.core import executor, flow as F
-from repro.core.enumeration import RewriteEngine, commute_id
-from repro.core.operators import Hints, LimitOp, MapOp, MatchOp, ReduceOp
+from repro.core.enumeration import GroupMemo
+from repro.core.operators import (Hints, LimitOp, MapOp, MatchOp, ReduceOp,
+                                  commute_id)
 from repro.core.record import Schema, batch_from_dict
 from repro.core.reorder import (RULES, RULES_BY_NAME, Rule, local_rewrites,
                                 register_rule, rotate, split_reduce)
@@ -248,19 +249,17 @@ def test_pull_limit_rule():
 @pytest.mark.parametrize("parent_key", ["k2", "x"])
 def test_local_rewrites_matches_engine_expansion_on_three_join(parent_key):
     """On a 3-join tree, `local_rewrites`' root-level neighbourhood —
-    projected onto commute classes — must equal the RewriteEngine's local
+    projected onto commute classes — must equal the group memo's local
     expansion of the root's class.  `parent_key="x"` (the key living on
     J1's LEFT grandchild) is the regression: its only rotation is the
     CONJUGATE one, which `local_rewrites` historically never generated."""
     root = _three_join(parent_key)
-    eng = RewriteEngine()
-    trees, cids = [], []
-    eng._local_into(root, trees, cids)
+    cids = {commute_id(t) for t in GroupMemo().local(root)}
     mine = {commute_id(t) for t in local_rewrites(root)}
     # the commute rule's result is the root's own class (classes are
     # side-order-insensitive); the engine never emits it
     mine.discard(commute_id(root))
-    assert mine == set(cids), (root.pretty(), len(mine), len(cids))
+    assert mine == cids, (root.pretty(), len(mine), len(cids))
     if parent_key == "x":   # the conjugate-only case really rotates
         assert rotate(root, 0) is None
         assert rotate(root, 0, conjugate=True) is not None
