@@ -408,6 +408,20 @@ def _match_codes(op: MatchOp, lb: MaskedBatch, rb: MaskedBatch):
     return rank[:nl], rank[nl:]
 
 
+def _probe(rcode, lcode, start, use_kernels: bool):
+    """The PK probe: `max(searchsorted(rcode, lcode), start)` for the sorted
+    PK codes `rcode`, under the device scope `probe` (`scans.search_sorted`,
+    or the Pallas kernel; a `start` of 0 clamps nothing)."""
+    if not use_kernels:
+        with scope("probe"):
+            return scans.search_sorted(rcode, lcode, start)
+    from ..kernels import ops as kops
+
+    with scope("probe"):
+        pos = kops.sorted_probe(rcode, lcode)
+    return pos if isinstance(start, int) else jnp.maximum(pos, start)
+
+
 def _exec_match_pk(op: MatchOp, lb: MaskedBatch, rb: MaskedBatch,
                    use_kernels: bool, use_order: bool = True,
                    obs: Optional[dict] = None) -> MaskedBatch:
@@ -437,7 +451,7 @@ def _exec_match_pk(op: MatchOp, lb: MaskedBatch, rb: MaskedBatch,
         first_valid = jnp.argmax(rb.valid).astype(jnp.int32)
         rcols, rvalid = rb.columns, rb.valid
     else:
-        first_valid = None
+        first_valid = 0
         # sort by (code, valid-first): equal-code invalid rows land AFTER the
         # valid ones, so no sentinel arithmetic is needed and a left search
         # still finds the valid row first
@@ -447,16 +461,8 @@ def _exec_match_pk(op: MatchOp, lb: MaskedBatch, rb: MaskedBatch,
             rcols = {f: v[order] for f, v in rb.columns.items()}
             rvalid = rb.valid[order]
 
-    with scope("probe"):
-        if use_kernels:
-            from ..kernels import ops as kops
-
-            pos = kops.sorted_probe(rcode, lcode)
-        else:
-            pos = jnp.searchsorted(rcode, lcode)
-    if first_valid is not None:
-        pos = jnp.maximum(pos, first_valid)
-    pos = jnp.clip(pos, 0, rb.capacity - 1)
+    pos = jnp.clip(_probe(rcode, lcode, first_valid, use_kernels), 0,
+                   rb.capacity - 1)
     hit = (rcode[pos] == lcode) & lb.valid & rvalid[pos]
     if obs is not None:  # observed probe hits (adaptive join-fanout feedback)
         obs["groups"] = jnp.sum(hit.astype(jnp.int32))
@@ -498,21 +504,13 @@ def _exec_match_anti(op: MatchOp, lb: MaskedBatch, rb: MaskedBatch,
         first_valid = jnp.argmax(rb.valid).astype(jnp.int32)
         rvalid = rb.valid
     else:
-        first_valid = None
+        first_valid = 0
         with scope("sort"):
             order = jnp.lexsort((~rb.valid, rcode_raw))
             rcode = rcode_raw[order]
             rvalid = rb.valid[order]
-    with scope("probe"):
-        if use_kernels:
-            from ..kernels import ops as kops
-
-            pos = kops.sorted_probe(rcode, lcode)
-        else:
-            pos = jnp.searchsorted(rcode, lcode)
-    if first_valid is not None:
-        pos = jnp.maximum(pos, first_valid)
-    pos = jnp.clip(pos, 0, rb.capacity - 1)
+    pos = jnp.clip(_probe(rcode, lcode, first_valid, use_kernels), 0,
+                   rb.capacity - 1)
     present = (rcode[pos] == lcode) & rvalid[pos]
     keep = lb.valid & ~present
     if obs is not None:  # observed survivors (adaptive selectivity feedback)

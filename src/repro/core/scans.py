@@ -19,12 +19,15 @@ aggregates.
 
 `select` finds the position of every set bit of a mask in order — the
 compaction's pack and the sorted segments' boundaries — with one gather
-and no loop once the mask is long (see `select`).
+and no loop once the mask is long (see `select`).  `search_sorted` is the
+PK probe's sorted search: a bucket directory over the distinct keys, then a
+binary search only as deep as the widest bucket (see `search_sorted`).
 """
 
 from __future__ import annotations
 
 import jax.numpy as jnp
+from jax import lax
 
 from .. import obs
 from ..obs import scope
@@ -36,6 +39,9 @@ _BLOCK = 128
 # `chipbench/tests/test_chipbench_scopes.py` requires the 32,768-slot
 # filter compaction of its tiny Q15 to hold a `while` loop.
 _SELECT_MIN = 1 << 16
+# shorter probe sides keep `jnp.searchsorted`.  `_SELECT_MIN`'s value, no
+# measured crossover (PERF.md §6).
+_PROBE_MIN = _SELECT_MIN
 
 _OPS = {
     "add": jnp.add,
@@ -115,6 +121,118 @@ def _select_blocked(mask, k):
     table = (within[:, :, None] <= r).sum(1, dtype=jnp.int32).reshape(-1)
     at = table[jnp.minimum(blk, B - 1) * W + jnp.minimum(rank, W - 1)]
     return jnp.where(blk < B, blk * W + at, n)
+
+
+def search_sorted(a: jnp.ndarray, x: jnp.ndarray, start=0) -> jnp.ndarray:
+    """Left insertion point of each `x` in the nondecreasing `a`, clamped
+    to at least `start` (0 <= start <= len(a)): exactly
+    `max(jnp.searchsorted(a, x, side='left'), start)`, as int32.
+
+    Integer codes probed by at least `_PROBE_MIN` queries take a bucket
+    directory (`_search_directory`): its in-bucket binary search is as deep
+    as the widest bucket, one or a few steps on real keys, where the plain
+    search takes ~log2(len(a)).  Float codes and shorter probe sides keep
+    `jnp.searchsorted`; a `start` of 0 then adds no clamp.  Each trace
+    counts `probe.directory` or `probe.search`."""
+    ct = jnp.promote_types(a.dtype, x.dtype)
+    if (jnp.issubdtype(ct, jnp.integer) and x.shape[0] >= _PROBE_MIN
+            and a.shape[0] > 0):
+        obs.count("probe.directory", 1)
+        return _search_directory(a.astype(ct), x.astype(ct), start)
+    obs.count("probe.search", 1)
+    pos = jnp.searchsorted(a, x)
+    if isinstance(start, int) and start == 0:
+        return pos
+    return jnp.maximum(pos, start)
+
+
+def _search_directory(a, x, start):
+    """`search_sorted` through a directory over the distinct codes.
+
+    The left insertion point of `x` in `a[start:]` is the first slot of the
+    smallest distinct code >= `x`, so the search runs over the distinct
+    codes (`_directory`).  Buckets are monotone in the code, so the
+    answer's index among them lies in `[dir[b], dir[b + 1])` of the
+    query's bucket `b`, found by a binary search of `steps` =
+    `bit_length(widest bucket)` steps: exact for any data, and as deep as
+    the plain search only when every code shares one bucket.  The steps
+    compare offsets from `lo`, and 64-bit codes compare their low 32 bits
+    while the shift is at most 32: a bucket's offsets then differ in those
+    bits alone, and a 32-bit gather costs a third of a 64-bit one on a TPU
+    v5e (PERF.md §6)."""
+    if a.dtype.itemsize < 4:
+        a, x = a.astype(jnp.int32), x.astype(jnp.int32)
+    n = a.shape[0]
+    U, d, ku, offset, shift, dir_, steps = _directory(a, start)
+    B = dir_.shape[0] - 1
+    bx = jnp.minimum(offset(x) >> shift, B).astype(jnp.int32)
+    lr = (dir_[bx], dir_[jnp.minimum(bx + 1, B)])
+
+    def search(narrow: bool):
+        keys, kx = ku, offset(x)
+        if narrow:
+            keys, kx = keys.astype(jnp.uint32), kx.astype(jnp.uint32)
+
+        def step(_, lr):
+            lft, rgt = lr
+            mid = (lft + rgt) >> 1
+            less = keys[jnp.minimum(mid, n - 1)] < kx
+            live = lft < rgt
+            return (jnp.where(live & less, mid + 1, lft),
+                    jnp.where(live & ~less, mid, rgt))
+
+        return lax.fori_loop(0, steps, step, lr)[0]
+
+    if a.dtype.itemsize == 4:
+        j = search(False)
+    else:
+        j = lax.cond(shift <= 32, lambda: search(True),
+                     lambda: search(False))
+    return jnp.where(j < d, U[jnp.minimum(j, n - 1)], n)
+
+
+def _directory(a, start):
+    """The directory over the distinct codes of `a[start:]` (int32 or
+    wider): `(U, d, ku, offset, shift, dir, steps)`.
+
+    `U` holds the slots of the `d` run starts of `a[start:]` (as `select`
+    gives them: `n` past them) and `ku` their codes' offsets: a cummax fill
+    of invalid slots repeats one code many times, and the repeats must not
+    widen a bucket.  `offset(v)` is `v - lo`, with `lo = a[start]`, as an
+    unsigned integer of the code's width (exact for every `v >= lo`, with
+    no overflow near either end of the range), and 0 for `v < lo`.  A
+    code's bucket is `offset >> shift`, `shift` the least that puts `a[-1]`
+    in one of `B` (a power of two >= len(a)) buckets; codes past `a[-1]`
+    may reach `B` and are clamped to it.  `dir[b]` (B + 1 entries) counts
+    the distinct codes in buckets below `b`: a sorted-index histogram and
+    its prefix sum.  `steps` is the widest bucket's bit length."""
+    n = a.shape[0]
+    ut = jnp.dtype(f"uint{8 * a.dtype.itemsize}")
+    B = 1 << (n - 1).bit_length()
+    start = jnp.asarray(start, jnp.int32)
+    i = jnp.arange(n, dtype=jnp.int32)
+    prev = jnp.concatenate([a[:1], a[:-1]])
+    # the blocked select at any length: the short form's binary search
+    # would put a loop back into the probe
+    U = _select_blocked((i >= start) & ((i == start) | (a != prev)), n)
+    d = jnp.sum(U < n, dtype=jnp.int32)
+    lo = a[jnp.minimum(start, n - 1)]
+    ulo = lax.bitcast_convert_type(lo, ut)
+
+    def offset(v):
+        return jnp.where(v < lo, jnp.zeros((), ut),
+                         lax.bitcast_convert_type(v, ut) - ulo)
+
+    span = lax.bitcast_convert_type(a[-1], ut) - ulo
+    shift = jnp.maximum(8 * ut.itemsize - lax.clz(span).astype(jnp.int32)
+                        - (B.bit_length() - 1), 0).astype(ut)
+    ku = offset(a[jnp.minimum(U, n - 1)])
+    bu = jnp.where(i < d, (ku >> shift).astype(jnp.int32), B)
+    hist = jnp.zeros((B + 1,), jnp.int32).at[bu].add(
+        1, indices_are_sorted=True)
+    dir_ = jnp.concatenate([jnp.zeros((1,), jnp.int32), cumsum(hist[:B])])
+    steps = 32 - lax.clz(jnp.max(hist[:B]))
+    return U, d, ku, offset, shift, dir_, steps
 
 
 def cumsum(v: jnp.ndarray) -> jnp.ndarray:
