@@ -346,8 +346,7 @@ def _broadcast(b: M.MaskedBatch, axis: str, p: int,
 # placed them, hashing the partition columns the plan chose.
 # ---------------------------------------------------------------------------
 def _exec_stages(stages, shards: Mapping[str, M.MaskedBatch],
-                 axis: str, p: int, use_kernels: bool,
-                 stats_memo: dict, slack: float,
+                 axis: str, p: int, stats_memo: dict, slack: float,
                  root: Node, use_order: bool = True,
                  observe: Optional[list] = None,
                  use_megakernel: bool = True,
@@ -446,7 +445,7 @@ def _exec_stages(stages, shards: Mapping[str, M.MaskedBatch],
                        for t, (ref, how) in enumerate(zip(st.inputs,
                                                           st.ship))]
                 obs: Optional[dict] = {} if observe is not None else None
-                out = PL.execute_stage(st, ins, use_kernels, use_order, obs)
+                out = PL.execute_stage(st, ins, use_order, obs)
                 if st.kind == "limit" and p > 1 and "broadcast" in st.ship:
                     # global WITH-TIES limit: the input was replicated, so
                     # every shard computed the IDENTICAL survivor mask on
@@ -474,8 +473,7 @@ def _exec_stages(stages, shards: Mapping[str, M.MaskedBatch],
                     for t, (ref, how) in enumerate(zip(st.inputs, st.ship))])
             planned = [M.planned_capacity(st.top, stats_memo, slack,
                                           shards=p) for st in span]
-            raw, span_obs, _ = MK.run_span(span, ins_per, planned,
-                                           use_kernels, use_order)
+            raw, span_obs, _ = MK.run_span(span, ins_per, planned, use_order)
             if observe is not None:
                 # span interiors surface scalar counts (the megakernel's
                 # own side-channel), so they psum unsliced
@@ -557,7 +555,7 @@ def _default_mesh(mesh: Optional[Mesh], axis: str,
 # ---------------------------------------------------------------------------
 def execute_distributed(plan: PhysPlan, bindings: Mapping[str, RecordBatch],
                         mesh: Optional[Mesh] = None, axis: str = "data",
-                        use_kernels: bool = False, slack: float = 4.0,
+                        slack: float = 4.0,
                         out_capacity: Optional[int] = None,
                         use_order: bool = True,
                         stats_store=None,
@@ -577,9 +575,6 @@ def execute_distributed(plan: PhysPlan, bindings: Mapping[str, RecordBatch],
     transfers, bit-identical to the serial wire; `mesh_shards` bounds the
     mesh width when no explicit `mesh` is given (default: all devices, or
     `REPRO_MESH_SHARDS` when set)."""
-    from ..kernels.ops import refuse_on_tpu
-
-    refuse_on_tpu(use_kernels)
     mesh = _default_mesh(mesh, axis, mesh_shards)
     p = mesh.shape[axis]
     if overlap_slices is None:
@@ -607,9 +602,9 @@ def execute_distributed(plan: PhysPlan, bindings: Mapping[str, RecordBatch],
         if not stages:
             out = local[plan.node.name]
         else:
-            out = _exec_stages(stages, local, axis, p, use_kernels,
-                               stats_memo, slack, plan.node, use_order,
-                               observe, use_megakernel, overlap_slices)
+            out = _exec_stages(stages, local, axis, p, stats_memo, slack,
+                               plan.node, use_order, observe, use_megakernel,
+                               overlap_slices)
         if stats_store is None:
             return out
         # psum'd counts are replicated over the axis, so they leave the
@@ -650,14 +645,11 @@ class DistributedPlan:
 
     def __init__(self, plan, mesh: Optional[Mesh] = None, axis: str = "data",
                  mesh_shards: Optional[int] = None,
-                 overlap_slices: Optional[int] = None,
-                 use_kernels: bool = False, slack: float = 4.0,
+                 overlap_slices: Optional[int] = None, slack: float = 4.0,
                  use_order: bool = True,
                  use_megakernel: Optional[bool] = None, cache=None):
-        from ..kernels.ops import refuse_on_tpu
         from . import pipeline as PL
 
-        refuse_on_tpu(use_kernels)
         plan = getattr(plan, "best", plan)   # OptResult / LayoutResult
         plan = getattr(plan, "plan", plan)   # RankedPlan
         if not isinstance(plan, PhysPlan):
@@ -668,7 +660,6 @@ class DistributedPlan:
         self.p = self.mesh.shape[axis]
         self.overlap_slices = overlap_slices_default() \
             if overlap_slices is None else max(1, int(overlap_slices))
-        self.use_kernels = use_kernels
         self.slack = float(slack)
         self.use_order = use_order
         self.use_megakernel = PL._megakernel_default() \
@@ -677,8 +668,8 @@ class DistributedPlan:
         self.stages = PL.lower_phys(plan)
         self._sem = PL._Interned((
             PL.semantic_key(plan.node), PL._order_sig(self.stages), self.p,
-            self.overlap_slices, self.use_megakernel, self.use_kernels,
-            self.slack, self.use_order))
+            self.overlap_slices, self.use_megakernel, self.slack,
+            self.use_order))
 
     # -- binding ---------------------------------------------------------
     def bind(self, bindings: Mapping[str, RecordBatch]) -> dict:
@@ -707,7 +698,7 @@ class DistributedPlan:
         out_specs = P(self.axis) if not observe else (P(self.axis), P())
         plan, p, axis, cache = self.plan, self.p, self.axis, self.cache
         stages = self.stages
-        use_kernels, slack = self.use_kernels, self.slack
+        slack = self.slack
         use_order, use_megakernel = self.use_order, self.use_megakernel
         overlap = self.overlap_slices
 
@@ -721,9 +712,9 @@ class DistributedPlan:
             if not stages:
                 out = local[plan.node.name]
             else:
-                out = _exec_stages(stages, local, axis, p, use_kernels,
-                                   {}, slack, plan.node, use_order,
-                                   obs_acc, use_megakernel, overlap)
+                out = _exec_stages(stages, local, axis, p, {}, slack,
+                                   plan.node, use_order, obs_acc,
+                                   use_megakernel, overlap)
             if not observe:
                 return out
             with scope("wire"):

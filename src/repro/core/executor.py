@@ -2,7 +2,7 @@
 
 Executes a PACT flow bottom-up against bound source batches.  This is the
 reference semantics for the whole system: the masked jit executor, the
-shard_map distributed executor and the Pallas kernels are all tested for
+compiled pipeline and the shard_map distributed executor are all tested for
 multiset-equality (`RecordBatch.equivalent`) against this path.
 
 Physical choices here are fixed (sort-based grouping, sort-probe join);

@@ -22,9 +22,8 @@ apart from a vectorized binary search, and stable by construction, so it
 PRESERVES sort order — the property that lets order survive stage
 boundaries.
 
-Hot loops (segment reduction, sorted probe) route through the Pallas kernels
-in `repro.kernels` when `use_kernels=True` (TPU target; interpret-mode on
-CPU); the default jnp path is the oracle they are tested against.
+Segment reductions run through `udf.JitSegmentOps` and PK probes through
+`scans.search_sorted`: one composed XLA body per operator on every backend.
 """
 
 from __future__ import annotations
@@ -279,14 +278,6 @@ def cardinality_scale(root: Node, bindings: Mapping[str, "MaskedBatch"]) -> floa
     return s
 
 
-def segment_reduce_backend(use_kernels: bool):
-    if not use_kernels:
-        return JitSegmentOps
-    from ..kernels import ops as kops
-
-    return kops.KernelSegmentOps
-
-
 # ---------------------------------------------------------------------------
 # Per-operator execution
 # ---------------------------------------------------------------------------
@@ -311,8 +302,7 @@ def _exec_map(op: MapOp, b: MaskedBatch) -> MaskedBatch:
     return _concat(parts)
 
 
-def _exec_reduce(op: ReduceOp, b: MaskedBatch, use_kernels: bool,
-                 use_order: bool = True,
+def _exec_reduce(op: ReduceOp, b: MaskedBatch, use_order: bool = True,
                  obs: Optional[dict] = None,
                  contiguous: bool = False) -> MaskedBatch:
     """`obs`, when given, receives the traced observed group count under
@@ -341,8 +331,8 @@ def _exec_reduce(op: ReduceOp, b: MaskedBatch, use_kernels: bool,
         sb, seg, is_start = _sort_by_key(b, key)
         base_order = key
     nseg = b.capacity  # worst case: every valid row its own group
-    segcls = segment_reduce_backend(use_kernels)
-    segops = segcls(seg, nseg, record_valid=sb.valid, is_start=is_start)
+    segops = JitSegmentOps(seg, nseg, record_valid=sb.valid,
+                           is_start=is_start)
     col = invoke.run_kat_udf(op.udf, dict(sb.columns), segops, op.key)
     ngroups = jnp.sum(is_start)
     if obs is not None:
@@ -408,22 +398,16 @@ def _match_codes(op: MatchOp, lb: MaskedBatch, rb: MaskedBatch):
     return rank[:nl], rank[nl:]
 
 
-def _probe(rcode, lcode, start, use_kernels: bool):
+def _probe(rcode, lcode, start):
     """The PK probe: `max(searchsorted(rcode, lcode), start)` for the sorted
-    PK codes `rcode`, under the device scope `probe` (`scans.search_sorted`,
-    or the Pallas kernel; a `start` of 0 clamps nothing)."""
-    if not use_kernels:
-        with scope("probe"):
-            return scans.search_sorted(rcode, lcode, start)
-    from ..kernels import ops as kops
-
+    PK codes `rcode`, under the device scope `probe` (a `start` of 0 clamps
+    nothing)."""
     with scope("probe"):
-        pos = kops.sorted_probe(rcode, lcode)
-    return pos if isinstance(start, int) else jnp.maximum(pos, start)
+        return scans.search_sorted(rcode, lcode, start)
 
 
 def _exec_match_pk(op: MatchOp, lb: MaskedBatch, rb: MaskedBatch,
-                   use_kernels: bool, use_order: bool = True,
+                   use_order: bool = True,
                    obs: Optional[dict] = None) -> MaskedBatch:
     """Equi-join where the right side is unique on its key (PK side): each
     left row matches at most one right row — sorted-search probe.  When the
@@ -461,8 +445,7 @@ def _exec_match_pk(op: MatchOp, lb: MaskedBatch, rb: MaskedBatch,
             rcols = {f: v[order] for f, v in rb.columns.items()}
             rvalid = rb.valid[order]
 
-    pos = jnp.clip(_probe(rcode, lcode, first_valid, use_kernels), 0,
-                   rb.capacity - 1)
+    pos = jnp.clip(_probe(rcode, lcode, first_valid), 0, rb.capacity - 1)
     hit = (rcode[pos] == lcode) & lb.valid & rvalid[pos]
     if obs is not None:  # observed probe hits (adaptive join-fanout feedback)
         obs["groups"] = jnp.sum(hit.astype(jnp.int32))
@@ -486,7 +469,7 @@ def _exec_match_pk(op: MatchOp, lb: MaskedBatch, rb: MaskedBatch,
 
 
 def _exec_match_anti(op: MatchOp, lb: MaskedBatch, rb: MaskedBatch,
-                     use_kernels: bool, use_order: bool = True,
+                     use_order: bool = True,
                      obs: Optional[dict] = None) -> MaskedBatch:
     """Left anti join: keep exactly the LEFT rows whose key has NO valid
     partner on the right.  No UDF runs; the output is a slot-aligned mask
@@ -509,8 +492,7 @@ def _exec_match_anti(op: MatchOp, lb: MaskedBatch, rb: MaskedBatch,
             order = jnp.lexsort((~rb.valid, rcode_raw))
             rcode = rcode_raw[order]
             rvalid = rb.valid[order]
-    pos = jnp.clip(_probe(rcode, lcode, first_valid, use_kernels), 0,
-                   rb.capacity - 1)
+    pos = jnp.clip(_probe(rcode, lcode, first_valid), 0, rb.capacity - 1)
     present = (rcode[pos] == lcode) & rvalid[pos]
     keep = lb.valid & ~present
     if obs is not None:  # observed survivors (adaptive selectivity feedback)
@@ -572,7 +554,7 @@ def _exec_cross(op, lb: MaskedBatch, rb: MaskedBatch,
 
 
 def _exec_cogroup(op: CoGroupOp, lb: MaskedBatch, rb: MaskedBatch,
-                  use_kernels: bool, use_order: bool = True,
+                  use_order: bool = True,
                   obs: Optional[dict] = None) -> MaskedBatch:
     """Align both sides on the union key domain with static shapes."""
     nl, nr = lb.capacity, rb.capacity
@@ -620,9 +602,8 @@ def _exec_cogroup(op: CoGroupOp, lb: MaskedBatch, rb: MaskedBatch,
     lseg, rseg = lseg[lord], rseg[rord]
     lvalid, rvalid = lb.valid[lord], rb.valid[rord]
 
-    segcls = segment_reduce_backend(use_kernels)
-    lops = segcls(lseg, nseg, record_valid=lvalid)
-    rops = segcls(rseg, nseg, record_valid=rvalid)
+    lops = JitSegmentOps(lseg, nseg, record_valid=lvalid)
+    rops = JitSegmentOps(rseg, nseg, record_valid=rvalid)
     col = invoke.run_cogroup_udf(op.udf, lcols, lops, rcols, rops,
                                  op.left_key, op.right_key)
     parts = []
@@ -641,7 +622,6 @@ def _exec_cogroup(op: CoGroupOp, lb: MaskedBatch, rb: MaskedBatch,
 # Flow execution
 # ---------------------------------------------------------------------------
 def execute_masked(root: Node, bindings: Mapping[str, MaskedBatch],
-                   use_kernels: bool = False,
                    compact_slack: float = 2.0,
                    compact: bool = True,
                    use_order: bool = True) -> MaskedBatch:
@@ -679,27 +659,27 @@ def execute_masked(root: Node, bindings: Mapping[str, MaskedBatch],
         elif isinstance(node, MapOp):
             out = _exec_map(node, run(node.child))
         elif isinstance(node, ReduceOp):
-            out = _exec_reduce(node, run(node.child), use_kernels, use_order)
+            out = _exec_reduce(node, run(node.child), use_order)
         elif isinstance(node, LimitOp):
             out = _exec_limit(node, run(node.child), use_order)
         elif isinstance(node, MatchOp):
             lb, rb = run(node.left), run(node.right)
             if node.anti:
-                out = _exec_match_anti(node, lb, rb, use_kernels, use_order)
+                out = _exec_match_anti(node, lb, rb, use_order)
             elif node.hints.pk_side == "right":
-                out = _exec_match_pk(node, lb, rb, use_kernels, use_order)
+                out = _exec_match_pk(node, lb, rb, use_order)
             elif node.hints.pk_side == "left":
                 from .reorder import commute as _commute
 
                 flipped = _commute(node)
-                out = _exec_match_pk(flipped, rb, lb, use_kernels, use_order)
+                out = _exec_match_pk(flipped, rb, lb, use_order)
             else:
                 out = _exec_cross(node, lb, rb, node.left_key, node.right_key)
         elif isinstance(node, CrossOp):
             out = _exec_cross(node, run(node.left), run(node.right))
         elif isinstance(node, CoGroupOp):
             out = _exec_cogroup(node, run(node.left), run(node.right),
-                                use_kernels, use_order)
+                                use_order)
         else:
             raise TypeError(type(node).__name__)
         out = maybe_compact(node, out)
@@ -727,7 +707,6 @@ def bucket_capacity(x: float) -> int:
 
 def run_flow_jit(root: Node, bindings: Mapping[str, RecordBatch],
                  capacities: Optional[Mapping[str, int]] = None,
-                 use_kernels: bool = False,
                  use_order: bool = True) -> RecordBatch:
     """Convenience: bind numpy batches, jit-execute, return a RecordBatch."""
     caps = capacities or {}
@@ -736,7 +715,6 @@ def run_flow_jit(root: Node, bindings: Mapping[str, RecordBatch],
 
     @functools.partial(jax.jit, static_argnums=())
     def go(mb):
-        return execute_masked(root, mb, use_kernels=use_kernels,
-                              use_order=use_order)
+        return execute_masked(root, mb, use_order=use_order)
 
     return go(masked).to_record_batch()

@@ -48,9 +48,8 @@ class RankedPlan:
     def order(self) -> str:
         return "->".join(reversed(self.flow.op_names()))
 
-    def compile(self, use_kernels: bool = False, compact_slack: float = 2.0,
-                cache=None, use_order: bool = True, adaptive=None,
-                stats=None):
+    def compile(self, compact_slack: float = 2.0, cache=None,
+                use_order: bool = True, adaptive=None, stats=None):
         """Lower this plan into a ready-to-run `pipeline.CompiledPlan`.
 
         Lowers the PHYSICAL plan, so the shipping strategies and order
@@ -60,10 +59,9 @@ class RankedPlan:
         (`pipeline.AdaptiveConfig`, DESIGN.md §9)."""
         from .pipeline import compile_plan
 
-        return compile_plan(self.plan, use_kernels=use_kernels,
-                            compact_slack=compact_slack, cache=cache,
-                            use_order=use_order, adaptive=adaptive,
-                            stats=stats)
+        return compile_plan(self.plan, compact_slack=compact_slack,
+                            cache=cache, use_order=use_order,
+                            adaptive=adaptive, stats=stats)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -82,15 +80,13 @@ class OptResult:
         the search covered is `num_enumerated`."""
         return self.num_enumerated or len(self.ranked)
 
-    def compile(self, use_kernels: bool = False, compact_slack: float = 2.0,
-                cache=None, use_order: bool = True, adaptive=None,
-                stats=None):
+    def compile(self, compact_slack: float = 2.0, cache=None,
+                use_order: bool = True, adaptive=None, stats=None):
         """Compile the best plan: `optimize(flow).compile().run(bindings)`.
 
         Repeated optimize+compile of equal-shaped flows returns handles that
         share one warm executable through the plan-executable cache."""
-        return self.best.compile(use_kernels=use_kernels,
-                                 compact_slack=compact_slack, cache=cache,
+        return self.best.compile(compact_slack=compact_slack, cache=cache,
                                  use_order=use_order, adaptive=adaptive,
                                  stats=stats)
 

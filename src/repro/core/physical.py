@@ -120,20 +120,10 @@ class CostVec:
 
 @dataclasses.dataclass(frozen=True)
 class Ctx:
-    """Parallel execution context (degree of parallelism + fabric).
-
-    `megakernel` prices the fused whole-stage lowering (DESIGN.md §10):
-    key-based operators fed by a forwarded Map chain gain a `megakernel`
-    local-strategy candidate whose HBM term elides the input re-read — the
-    chain's output never round-trips to HBM but stays VMEM-resident into
-    the aggregate/probe — gated on the per-worker working set fitting VMEM.
-    Off by default so existing plan goldens are unchanged; the compiled
-    pipeline's route planner (kernels.megakernel.plan_routes) makes the
-    actual fusion decision per bound capacity either way."""
+    """Parallel execution context (degree of parallelism + fabric)."""
 
     dop: int = 32
     chip: hw.ChipSpec = hw.CHIP
-    megakernel: bool = False
 
     @property
     def link_bw(self) -> float:
@@ -413,21 +403,6 @@ def _expand(node: Node, ctx: Ctx, stats_memo: dict,
                 out.append(PhysPlan(node=node, inputs=(iplan,), ship=(ship,),
                                     local=local, props=props, node_cost=cost,
                                     ship_keys=(pkeys,)))
-                # fused whole-stage lowering: a forwarded Map chain feeding
-                # the aggregate keeps its output VMEM-resident, eliding the
-                # input re-read from the HBM term (DESIGN.md §10) — only
-                # admissible when the per-worker working set fits VMEM
-                if (ship == "forward" and ctx.megakernel
-                        and isinstance(node.child, MapOp)
-                        and (cin.bytes + st.bytes) / ctx.dop
-                        <= ctx.chip.vmem_bytes):
-                    mcost = CostVec(net=net,
-                                    mem=_t_mem(0.0, st.bytes, ctx),
-                                    cpu=_t_cpu(cpu, ctx))
-                    out.append(PhysPlan(node=node, inputs=(iplan,),
-                                        ship=(ship,), local="megakernel",
-                                        props=props, node_cost=mcost,
-                                        ship_keys=(pkeys,)))
 
     elif isinstance(node, LimitOp):
         # WITH-TIES top-k is a GLOBAL decision: at dop=1 it forwards and
@@ -539,29 +514,12 @@ def _expand(node: Node, ctx: Ctx, stats_memo: dict,
             ship = ("forward", "broadcast") if bc_side == 1 \
                 else ("broadcast", "forward")
             fwd_cands = lcands if bc_side == 1 else rcands
-            fwd_node = node.left if bc_side == 1 else node.right
-            # fused probe: forwarded Map-chain output stays VMEM-resident
-            # into the broadcast probe, eliding its HBM re-read (§10); the
-            # replicated side is fully resident per worker, so it charges
-            # against VMEM undivided
-            mega = (ctx.megakernel and is_match
-                    and isinstance(fwd_node, MapOp)
-                    and (fst.bytes + st.bytes) / ctx.dop + bst.bytes
-                    <= ctx.chip.vmem_bytes)
-            mcost = CostVec(net=net,
-                            mem=_t_mem(bst.bytes * ctx.dop, st.bytes, ctx),
-                            cpu=_t_cpu(cpu, ctx))
             for fprops, fplan in fwd_cands.items():
                 inputs = (fplan, cheap_r) if bc_side == 1 else (cheap_l, fplan)
                 out.append(PhysPlan(
                     node=node, inputs=inputs, ship=ship, local="probe",
                     props=_preserved(fprops, node), node_cost=cost,
                     ship_keys=(None, None)))
-                if mega:
-                    out.append(PhysPlan(
-                        node=node, inputs=inputs, ship=ship,
-                        local="megakernel", props=_preserved(fprops, node),
-                        node_cost=mcost, ship_keys=(None, None)))
 
     elif isinstance(node, CoGroupOp):
         ls = estimate(node.left, stats_memo, ctx.dop)

@@ -12,8 +12,7 @@ pipeline of STAGES and jit-compiles the whole pipeline into one executable
   dispatch and one boundary compaction instead of N of each (boundary
   compaction is a stable linear prefix-sum pack, `MaskedBatch.compact`);
 * Reduce / Match / Cross / CoGroup remain explicit stage boundaries (they
-  re-shape the batch: sorts, probes, segment reductions), routed through the
-  Pallas kernels when `use_kernels` is set;
+  re-shape the batch: sorts, probes, segment reductions);
 * every static capacity is drawn from the geometric `bucket_capacity`
   ladder, so the number of distinct traced shapes stays O(log n);
 * stages carry the ORDER properties the physical layer reasons about
@@ -25,8 +24,8 @@ Executables are cached in a process-wide `ExecutableCache` keyed on a
 commute-invariant SEMANTIC fingerprint of the flow (operator names, UDF
 code objects, keys, hints, source schemas, cardinalities and declared sort
 orders — see `semantic_key`) plus source capacity buckets and runtime
-orders, the lowered stages' order assumptions, `use_kernels`,
-`compact_slack`, `use_order` and input donation.  Commute invariance means
+orders, the lowered stages' order assumptions, `compact_slack`,
+`use_order` and input donation.  Commute invariance means
 two plans that differ only in join argument order — multiset-equal by
 construction — share one warm executable; fingerprinting UDF code by VALUE
 means a rebuilt-from-scratch but identical flow also hits, while two
@@ -55,11 +54,9 @@ Whole-stage megakernels (DESIGN.md §10): runs of single-consumer
 chain/reduce/PK-match stages whose working set fits VMEM are routed through
 `kernels.megakernel` — one fused span body with dead-column pruning at
 interior compactions and contiguity-aware segmentation, inlined into the
-executable's XLA program (an interpret-mode Pallas call under
-`REPRO_MEGAKERNEL_PALLAS=1`, off-TPU only).  Routes are
-planned per source signature and fingerprinted (with the dispatch mode)
-into the executable-cache key; `use_megakernel` joins the semantic
-fingerprint, so fused and composed traces never share an executable.
+executable's XLA program.  Routes are planned per source signature and
+fingerprinted into the executable-cache key; `use_megakernel` joins the
+semantic fingerprint, so fused and composed traces never share an executable.
 Non-fusable shapes (Cross, CoGroup, hint-less Match, shared intermediates,
 non-blockable capacities, VMEM overruns) fall back to the composed walk.
 
@@ -526,7 +523,7 @@ class _Interned:
 # per-shard body of distributed execution)
 # ---------------------------------------------------------------------------
 def execute_stage(stage: Stage, ins: Sequence[M.MaskedBatch],
-                  use_kernels: bool, use_order: bool = True,
+                  use_order: bool = True,
                   obs: Optional[dict] = None,
                   contiguous_in: bool = False) -> M.MaskedBatch:
     """Run one stage's local (per-worker) computation on masked batches.
@@ -545,7 +542,7 @@ def execute_stage(stage: Stage, ins: Sequence[M.MaskedBatch],
         return b
     node = stage.top
     if stage.kind == "reduce":
-        return M._exec_reduce(node, ins[0], use_kernels, use_order, obs,
+        return M._exec_reduce(node, ins[0], use_order, obs,
                               contiguous=contiguous_in)
     if stage.kind == "limit":
         return M._exec_limit(node, ins[0], use_order)
@@ -554,21 +551,18 @@ def execute_stage(stage: Stage, ins: Sequence[M.MaskedBatch],
         if node.anti:
             # checked before pk_side: commute() refuses anti nodes, and the
             # sides must not swap anyway (only left survives)
-            return M._exec_match_anti(node, lb, rb, use_kernels, use_order,
-                                      obs)
+            return M._exec_match_anti(node, lb, rb, use_order, obs)
         if node.hints.pk_side == "right":
-            return M._exec_match_pk(node, lb, rb, use_kernels, use_order, obs)
+            return M._exec_match_pk(node, lb, rb, use_order, obs)
         if node.hints.pk_side == "left":
             from .reorder import commute as _commute
 
-            return M._exec_match_pk(_commute(node), rb, lb, use_kernels,
-                                    use_order, obs)
+            return M._exec_match_pk(_commute(node), rb, lb, use_order, obs)
         return M._exec_cross(node, lb, rb, node.left_key, node.right_key)
     if stage.kind == "cross":
         return M._exec_cross(node, *ins)
     if stage.kind == "cogroup":
-        return M._exec_cogroup(node, *ins, use_kernels, use_order=use_order,
-                               obs=obs)
+        return M._exec_cogroup(node, *ins, use_order=use_order, obs=obs)
     raise TypeError(f"unknown stage kind {stage.kind!r}")
 
 
@@ -587,7 +581,7 @@ def stage_scope(stage: Stage):
 
 
 def run_stages(stages: Sequence[Stage], bindings: Mapping[str, M.MaskedBatch],
-               use_kernels: bool, compact_slack: float,
+               compact_slack: float,
                stats_memo: dict, scale: float = 1.0,
                use_order: bool = True, observe: Optional[list] = None,
                caps: Optional[list] = None,
@@ -647,7 +641,7 @@ def run_stages(stages: Sequence[Stage], bindings: Mapping[str, M.MaskedBatch],
                 orders = st.in_orders or ((),) * len(st.inputs)
                 ins = [resolve(r, o) for r, o in zip(st.inputs, orders)]
                 obs: Optional[dict] = {} if observe is not None else None
-                out = execute_stage(st, ins, use_kernels, use_order, obs)
+                out = execute_stage(st, ins, use_order, obs)
                 last = results[i] = boundary(st, out, obs)
         else:
             from ..kernels import megakernel as MK
@@ -664,7 +658,7 @@ def run_stages(stages: Sequence[Stage], bindings: Mapping[str, M.MaskedBatch],
             planned = [M.planned_capacity(st.top, stats_memo, compact_slack,
                                           scale) for st in span]
             raw, span_obs, applied = MK.run_span(span, ins_per, planned,
-                                                 use_kernels, use_order)
+                                                 use_order)
             if caps is not None:
                 caps.extend(applied)
             if observe is not None:
@@ -765,14 +759,12 @@ class ExecutableCache:
     """Bounded LRU cache of jitted pipeline executables.
 
     Key: `(semantic_key(flow), stage order signature, per-source (name,
-    schema signature, capacity bucket, runtime order), use_kernels,
-    compact_slack, use_order, donate, observe, megakernel routes,
-    dispatch mode)`.  The routes element records which stages execute as
-    whole-stage megakernels (DESIGN.md §10) and the dispatch mode names the
-    backend variant, so toggling `REPRO_MEGAKERNEL`/`REPRO_MEGAKERNEL_PALLAS`
-    coexists with the plain route instead of clobbering it.  `traces`
-    counts actual jit traces (incremented from inside the traced body), so
-    tests can assert warm calls never re-trace.
+    schema signature, capacity bucket, runtime order), compact_slack,
+    use_order, donate, observe, megakernel routes)`.  The routes element
+    records which stages execute as whole-stage megakernels (DESIGN.md §10),
+    so toggling `REPRO_MEGAKERNEL` coexists with the plain route instead of
+    clobbering it.  `traces` counts actual jit traces (incremented from
+    inside the traced body), so tests can assert warm calls never re-trace.
 
     Capacity defaults to `$REPRO_EXEC_CACHE_CAP` (256): adaptive serving
     deliberately multiplies executables (one per calibration regime), so
@@ -947,7 +939,6 @@ class CompiledPlan:
 
     flow: Node
     stages: tuple
-    use_kernels: bool = False
     compact_slack: float = 2.0
     use_order: bool = True
     use_megakernel: bool = dataclasses.field(
@@ -1072,20 +1063,14 @@ class CompiledPlan:
         if observe is None:
             observe = self.adaptive is not None
         routes = self._routes({s[0]: s[2] for s in source_sig})
-        mode = None
-        if routes is not None:
-            from ..kernels import megakernel as MK
-
-            mode = MK.dispatch_mode()
         self._last_routes = routes  # introspection (tests, benchmarks)
-        # routes + dispatch mode join the key: a route change (different
-        # capacities fuse differently) or a dispatch change (pallas vs
-        # inline-xla) traces a different program
-        key = (self._sem, source_sig, self.use_kernels, self.compact_slack,
-               self.use_order, donate, observe, routes, mode)
+        # routes join the key: a route change (different capacities fuse
+        # differently) traces a different program
+        key = (self._sem, source_sig, self.compact_slack, self.use_order,
+               donate, observe, routes)
         fn = self.cache.get(key)
         if fn is None:
-            stages, use_kernels = self.stages, self.use_kernels
+            stages = self.stages
             slack, cache = self.compact_slack, self.cache
             use_order = self.use_order
             # planned per-stage compaction capacities, recorded as a
@@ -1111,11 +1096,10 @@ class CompiledPlan:
                 stats_memo = seed_source_stats(
                     flow, {n: b.capacity for n, b in mb.items()}, {})
                 if not observe:
-                    return run_stages(stages, mb, use_kernels, slack,
-                                      stats_memo, use_order=use_order,
-                                      routes=routes)
+                    return run_stages(stages, mb, slack, stats_memo,
+                                      use_order=use_order, routes=routes)
                 obs_list: list = []
-                out = run_stages(stages, mb, use_kernels, slack, stats_memo,
+                out = run_stages(stages, mb, slack, stats_memo,
                                  use_order=use_order, observe=obs_list,
                                  caps=stage_caps, routes=routes)
                 # one packed int32 vector — [sources (name-sorted), per-stage
@@ -1343,8 +1327,7 @@ class CompiledPlan:
         masked, _ = self._masked_sig(masked_bindings)
         stats_memo = seed_source_stats(
             self.flow, {n: b.capacity for n, b in masked.items()}, {})
-        return run_stages(self.stages, masked, self.use_kernels,
-                          self.compact_slack, stats_memo,
+        return run_stages(self.stages, masked, self.compact_slack, stats_memo,
                           use_order=self.use_order,
                           routes=self._routes(
                               {n: b.capacity for n, b in masked.items()}))
@@ -1353,8 +1336,7 @@ class CompiledPlan:
         return self.cache.stats()
 
 
-def compile_plan(flow_or_plan, use_kernels: bool = False,
-                 compact_slack: float = 2.0,
+def compile_plan(flow_or_plan, compact_slack: float = 2.0,
                  cache: Optional[ExecutableCache] = None,
                  use_order: bool = True,
                  adaptive: Optional[AdaptiveConfig] = None,
@@ -1367,12 +1349,7 @@ def compile_plan(flow_or_plan, use_kernels: bool = False,
     (DESIGN.md §9); `stats` optionally shares a `StatsStore` across handles
     (e.g. seeded from a previous serving session).  `use_megakernel`
     (default on; `REPRO_MEGAKERNEL=0` disables globally) routes fusable
-    stage runs through the whole-stage megakernel (DESIGN.md §10).
-    `use_kernels=True` raises on a TPU backend, whose compiler refuses the
-    dataflow Pallas kernels (`kernels.ops.refuse_on_tpu`)."""
-    from ..kernels.ops import refuse_on_tpu
-
-    refuse_on_tpu(use_kernels)
+    stage runs through the whole-stage megakernel (DESIGN.md §10)."""
     with span("compile"):
         if isinstance(flow_or_plan, PhysPlan):
             flow, stages = flow_or_plan.node, lower_phys(flow_or_plan)
@@ -1381,7 +1358,6 @@ def compile_plan(flow_or_plan, use_kernels: bool = False,
         if use_megakernel is None:
             use_megakernel = _megakernel_default()
         return CompiledPlan(flow=flow, stages=stages,
-                            use_kernels=use_kernels,
                             compact_slack=compact_slack,
                             use_order=use_order,
                             use_megakernel=use_megakernel,

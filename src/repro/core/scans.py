@@ -275,9 +275,7 @@ def segmented_scan(v: jnp.ndarray, flags: jnp.ndarray, op: str
     RESET at `i` (a segment start); the caller pre-fills slots that must not
     contribute (invalid rows) with the op identity.
 
-    Log-depth shift-and-combine within 128-wide rows plus one carry pass —
-    the jnp analogue of `repro.kernels.segmented_scan`, fast on CPU where the
-    Pallas kernel only interprets."""
+    Log-depth shift-and-combine within 128-wide rows plus one carry pass."""
     fn = _OPS[op]
     n = v.shape[0]
     ident = identity_for(op, v.dtype)

@@ -1,8 +1,4 @@
-"""Pallas TPU kernels for the compute hot spots.
-
-Data plane (the paper's local strategies, TPU-adapted — DESIGN.md §3.1):
-  segmented_scan — grouped aggregation (Reduce/CoGroup local strategy)
-  sorted_probe   — sorted-search join probe (Match local strategy)
+"""Pallas TPU kernels for the model plane, and the fused-span executor.
 
 Model plane:
   flash_attention — fused causal/windowed GQA attention
@@ -10,8 +6,13 @@ Model plane:
   linear_scan     — diagonal linear recurrence (RG-LRU)
 
 Each kernel file: pl.pallas_call + explicit BlockSpec VMEM tiling.
-`ops.py` holds the jit'd public wrappers; `ref.py` the pure-jnp oracles.
-Kernels run interpret=True on non-TPU backends (validated in tests);
-compiled mode targets TPU v5e.  The data-plane kernels do not compile for
-it yet (`ops.TPU_KERNEL_REFUSALS`), so `use_kernels=True` raises on a TPU.
+`ops.py` holds the jit'd public wrappers; `ref.py` the pure-jnp oracles
+(including those of the data plane's segmented scan, segment reduction
+and sorted probe, which `core.scans` and `core.udf.JitSegmentOps` are
+tested against).  Kernels run interpret=True on non-TPU backends
+(validated in tests); compiled mode targets TPU v5e.
+
+Data plane: every stage body is composed XLA (`core.masked`, `core.scans`);
+`megakernel.py` fuses runs of stages into one span body, inlined into the
+same XLA program (DESIGN.md §10).
 """

@@ -34,14 +34,10 @@ returns the same per-stage `(valid-count, kat-aux)` observation pairs
 boundary-for-boundary, so `record_batch_obs`, truncation detection and
 `StatsStore` keys all work unchanged.
 
-Dispatch: on every backend the span body inlines into the enclosing jit
-("xla" mode), which XLA compiles for the chip.  `REPRO_MEGAKERNEL_PALLAS=1`
-wraps the whole body in one grid-free, whole-block `pl.pallas_call` in
-interpret mode instead — CPU CI uses it to exercise the lowering; both
-modes trace identical computations, which is what makes megakernel-vs-
-composed bit-identity testable on CPU.  Mosaic refuses that body on a TPU
-(64-bit columns, no `sort`/`cumsum` lowering), so the setting raises there
-(DESIGN.md §10.2).
+Dispatch: on every backend the span body inlines into the enclosing jit,
+which XLA compiles for the chip.  Mosaic could not compile it as one
+Pallas kernel for a TPU: every flow column is 64-bit, and it has no
+lowering for the sort and cumsum the body needs (DESIGN.md §10.2).
 
 Fallback (`plan_routes`): Cross, CoGroup and hint-less Match stages, spans
 shorter than two stages, multi-consumer interior edges, non-8-blockable
@@ -52,45 +48,14 @@ byte-for-byte the pre-megakernel behavior.
 from __future__ import annotations
 
 import collections
-import os
 from typing import Optional, Sequence
 
-import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
-from jax.extend.core import Literal
 
 from .. import hw
 from ..core import masked as M
 from ..core.reorder import eff_reads
-
-# "1": run fused spans through the interpret-mode pallas wrapper (off-TPU only)
-PALLAS_ENV = "REPRO_MEGAKERNEL_PALLAS"
-
-# what Mosaic answers when asked to compile a span body for a TPU
-TPU_PALLAS_REFUSAL = (
-    "Mosaic cannot compile a fused span body for a TPU: every flow column is "
-    "64-bit ('NotImplementedError: 64-bit types are not supported'), and it "
-    "has no lowering for the sort and cumsum the body needs ('Unimplemented "
-    "primitive in Pallas TPU lowering')")
-
-
-def dispatch_mode() -> str:
-    """How a fused span executes: "xla" (the body inlined into the
-    enclosing jit — every backend) or "pallas" (one whole-block
-    `pallas_call` in interpret mode, only under `REPRO_MEGAKERNEL_PALLAS=1`
-    off-TPU).  Part of the executable-cache key — the two modes trace
-    different programs.  Forcing "pallas" on a TPU backend raises: the rule
-    is fixed by what Mosaic can compile, never rescued by a fallback."""
-    if os.environ.get(PALLAS_ENV, "") != "1":
-        return "xla"
-    if jax.default_backend() == "tpu":
-        raise NotImplementedError(
-            f"{PALLAS_ENV}=1 on a TPU backend: {TPU_PALLAS_REFUSAL}; unset "
-            f"it to run fused spans as XLA")
-    return "pallas"
-
 
 # ---------------------------------------------------------------------------
 # Fusability predicate + route planning
@@ -229,106 +194,8 @@ def _live_fields(consumer, fields) -> tuple:
 # ---------------------------------------------------------------------------
 # Span execution
 # ---------------------------------------------------------------------------
-def _span_body(span, ins_per_stage, planned_caps, use_kernels, use_order,
-               caps_acc: list):
-    from ..core import pipeline as PL
-
-    prev: Optional[M.MaskedBatch] = None
-    prev_packed = False
-    counts, auxes = [], []
-    out = None
-    for k, (st, raw_ins) in enumerate(zip(span, ins_per_stage)):
-        with PL.stage_scope(st):
-            ins = [prev if b is None else b for b in raw_ins]
-            obs: dict = {}
-            out = PL.execute_stage(st, ins, use_kernels, use_order, obs,
-                                   contiguous_in=prev_packed)
-            counts.append(jnp.sum(out.valid.astype(jnp.int32)))
-            auxes.append(jnp.asarray(obs.get("groups", jnp.int32(-1)), jnp.int32))
-            if k == len(span) - 1:
-                break
-            # interior boundary: prune dead columns, compact to exactly the
-            # capacity the composed path would, and record packedness for the
-            # consumer's contiguous segmentation
-            nxt = span[k + 1]
-            live = _live_fields(nxt, out.columns.keys())
-            if len(live) < len(out.columns):
-                out = M.MaskedBatch({f: out.columns[f] for f in live}, out.valid,
-                                    M.order_prefix(out.order, live))
-            cap = min(out.capacity, planned_caps[k])
-            caps_acc.append(cap)
-            if cap < out.capacity:
-                out = out.compact(cap)
-                prev_packed = True
-            else:
-                prev_packed = False
-            # attach the lowered order assumption on the in-span edge, exactly
-            # as run_stages does for solo stages
-            orders = nxt.in_orders or ((),) * len(nxt.inputs)
-            for t, b in enumerate(ins_per_stage[k + 1]):
-                if b is None and use_order and orders[t] and not out.order:
-                    out = out.with_order(orders[t])
-                    break
-            prev = out
-    return out, tuple(counts), tuple(auxes)
-
-
-def _pallas_block_call(body, ins):
-    """Run `body` (pytree-in → pytree-out) as ONE grid-free, interpret-mode
-    `pl.pallas_call` with whole-array refs: every leaf is a full block.  It
-    traces the identical computation (bit-identity with "xla" dispatch).
-    Scalar leaves (the obs side-channel) ship as shape-(1,) refs."""
-    flat, treedef = jax.tree_util.tree_flatten(ins)
-    out_sd = jax.eval_shape(body, ins)
-    oflat_sd, otree = jax.tree_util.tree_flatten(out_sd)
-    scal = [s.ndim == 0 for s in oflat_sd]
-    out_shape = [jax.ShapeDtypeStruct((1,) if sc else s.shape, s.dtype)
-                 for s, sc in zip(oflat_sd, scal)]
-
-    def flat_body(*leaves):
-        out = body(jax.tree_util.tree_unflatten(treedef, list(leaves)))
-        return jax.tree_util.tree_flatten(out)[0]
-
-    # pallas kernels may not close over traced constants (iota tables from
-    # arange, sort dispatch tables, ...): trace the body to a jaxpr once and
-    # ship its consts as explicit kernel inputs, re-binding them to the
-    # constvars at eval time.  0-d consts ride as shape-(1,) refs.
-    closed = jax.make_jaxpr(flat_body)(*flat)
-    consts = [jnp.asarray(c) for c in closed.consts]
-    cscal = [c.ndim == 0 for c in consts]
-    args = list(flat) + [c[None] if sc else c
-                         for c, sc in zip(consts, cscal)]
-
-    # outputs that folded to jaxpr literals (e.g. the constant -1 aux of an
-    # aux-free stage) never enter the kernel: a store of a concrete value
-    # would itself be a captured constant.  Reattach them host-side.
-    lit = [v.val if isinstance(v, Literal) else None
-           for v in closed.jaxpr.outvars]
-    keep = [i for i, v in enumerate(lit) if v is None]
-    out_shape = [out_shape[i] for i in keep]
-
-    def kernel(*refs):
-        in_refs = refs[:len(flat)]
-        const_refs = refs[len(flat):len(args)]
-        out_refs = refs[len(args):]
-        cvals = [r[...][0] if sc else r[...]
-                 for r, sc in zip(const_refs, cscal)]
-        oflat = jax.core.eval_jaxpr(closed.jaxpr, cvals,
-                                    *(r[...] for r in in_refs))
-        for r, i in zip(out_refs, keep):
-            r[...] = oflat[i][None] if scal[i] else oflat[i]
-
-    res = pl.pallas_call(kernel, out_shape=out_shape, interpret=True)(*args)
-    merged = [None if v is None else jnp.asarray(v, oflat_sd[i].dtype)
-              for i, v in enumerate(lit)]
-    for r, i in zip(res, keep):
-        merged[i] = r[0] if scal[i] else r
-    return jax.tree_util.tree_unflatten(otree, merged)
-
-
 def run_span(span: Sequence, ins_per_stage: Sequence, planned_caps: Sequence,
-             use_kernels: bool, use_order: bool,
-             dispatch: Optional[str] = None):
+             use_order: bool):
     """Execute a fused span (traceable).
 
     `ins_per_stage[k]` lists stage k's resolved input batches with None
@@ -344,18 +211,44 @@ def run_span(span: Sequence, ins_per_stage: Sequence, planned_caps: Sequence,
     (aux = int32 -1 for aux-free stages), `caps` the interior capacities
     actually applied (static trace-time ints — the truncation-detection
     reference for all but the last span stage)."""
-    mode = dispatch or dispatch_mode()
-    state: dict = {}
+    from ..core import pipeline as PL
 
-    def body(ins):
-        acc: list = []
-        raw, counts, auxes = _span_body(span, ins, planned_caps, use_kernels,
-                                        use_order, acc)
-        state["caps"] = tuple(acc)
-        return raw, counts, auxes
-
-    if mode == "pallas":
-        raw, counts, auxes = _pallas_block_call(body, list(ins_per_stage))
-    else:
-        raw, counts, auxes = body(list(ins_per_stage))
-    return raw, list(zip(counts, auxes)), state["caps"]
+    prev: Optional[M.MaskedBatch] = None
+    prev_packed = False
+    obs_out, caps = [], []
+    out = None
+    for k, (st, raw_ins) in enumerate(zip(span, ins_per_stage)):
+        with PL.stage_scope(st):
+            ins = [prev if b is None else b for b in raw_ins]
+            obs: dict = {}
+            out = PL.execute_stage(st, ins, use_order, obs,
+                                   contiguous_in=prev_packed)
+            obs_out.append((jnp.sum(out.valid.astype(jnp.int32)),
+                            jnp.asarray(obs.get("groups", jnp.int32(-1)),
+                                        jnp.int32)))
+            if k == len(span) - 1:
+                break
+            # interior boundary: prune dead columns, compact to exactly the
+            # capacity the composed path would, and record packedness for the
+            # consumer's contiguous segmentation
+            nxt = span[k + 1]
+            live = _live_fields(nxt, out.columns.keys())
+            if len(live) < len(out.columns):
+                out = M.MaskedBatch({f: out.columns[f] for f in live}, out.valid,
+                                    M.order_prefix(out.order, live))
+            cap = min(out.capacity, planned_caps[k])
+            caps.append(cap)
+            if cap < out.capacity:
+                out = out.compact(cap)
+                prev_packed = True
+            else:
+                prev_packed = False
+            # attach the lowered order assumption on the in-span edge, exactly
+            # as run_stages does for solo stages
+            orders = nxt.in_orders or ((),) * len(nxt.inputs)
+            for t, b in enumerate(ins_per_stage[k + 1]):
+                if b is None and use_order and orders[t] and not out.order:
+                    out = out.with_order(orders[t])
+                    break
+            prev = out
+    return out, obs_out, tuple(caps)

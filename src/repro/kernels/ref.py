@@ -1,7 +1,9 @@
-"""Pure-jnp oracles for every Pallas kernel (the correctness references).
+"""Pure-jnp oracles (the correctness references): one for every Pallas
+kernel, and the data plane's segmented scan, segment reduction and sorted
+probe, which `core.scans` and `core.udf.JitSegmentOps` are tested against.
 
-Each function mirrors one kernel's contract exactly; kernel tests sweep
-shapes/dtypes and assert_allclose against these.
+Each function states one contract plainly; tests sweep shapes/dtypes and
+assert_allclose against these.
 """
 
 from __future__ import annotations

@@ -348,7 +348,6 @@ class ServeConfig:
     prior_weight: float = 0.0
     quant: int = 4
     optimize_max_plans: int = 4000
-    use_kernels: bool = False
     use_order: bool = True
     async_swap: bool = True
     share_subplans: bool = dataclasses.field(
@@ -463,9 +462,6 @@ class DataflowEngine:
 
     def __init__(self, config: ServeConfig = ServeConfig(),
                  cache: Optional[ExecutableCache] = None):
-        from ..kernels.ops import refuse_on_tpu
-
-        refuse_on_tpu(config.use_kernels)
         self.config = config
         self.cache = cache if cache is not None else ExecutableCache()
         self._tenants: dict[str, _Tenant] = {}
@@ -529,14 +525,12 @@ class DataflowEngine:
             sg = self._prefixes.get(key)
         if sg is None:
             plan = compile_plan(self._plan_for(sp.prefix), cache=self.cache,
-                                use_kernels=cfg.use_kernels,
                                 use_order=cfg.use_order)
             sg = _SharedGroup(key=key, plan=plan, source=sp.source,
                               store=StatsStore())
             with self._lock:
                 sg = self._prefixes.setdefault(key, sg)
         suffix = compile_plan(self._plan_for(sp.suffix), cache=self.cache,
-                              use_kernels=cfg.use_kernels,
                               use_order=cfg.use_order)
         with self._lock:
             sg.members.add(t.name)
@@ -572,7 +566,6 @@ class DataflowEngine:
         if g is not None:
             return g
         solo = compile_plan(self._plan_for(flow), cache=self.cache,
-                            use_kernels=cfg.use_kernels,
                             use_order=cfg.use_order)
         coalesced, cf = None, None
         if cfg.max_coalesce > 1:
@@ -580,7 +573,6 @@ class DataflowEngine:
             if cf is not None:
                 coalesced = compile_plan(self._plan_for(cf.root),
                                          cache=self.cache,
-                                         use_kernels=cfg.use_kernels,
                                          use_order=cfg.use_order)
         g = _PlanGroup(key=key, flow=flow, solo=solo, coalesced=coalesced,
                        coalesce_info=cf, store=StatsStore())
@@ -979,7 +971,6 @@ class DataflowEngine:
             return
         g.coalesce_info = cf
         g.coalesced = compile_plan(self._plan_for(cf.root), cache=self.cache,
-                                   use_kernels=self.config.use_kernels,
                                    use_order=self.config.use_order)
         g.trunc_streak = 0
         g.repairs += 1

@@ -1,10 +1,6 @@
-"""Backend rules of the chip path, checked on the CPU.
-
-A fused span dispatches as XLA unless `REPRO_MEGAKERNEL_PALLAS=1` forces the
-interpret-mode Pallas wrapper, which a TPU backend refuses; `use_kernels`
-raises on a TPU backend at every entry point; and the persistent compile
-cache lands where `JAX_COMPILATION_CACHE_DIR` says, else at one fixed path.
-The TPU backend is steered here by patching `jax.default_backend`.
+"""Backend rules of the chip path, checked on the CPU: the persistent
+compile cache lands where `JAX_COMPILATION_CACHE_DIR` says, else at one
+fixed path.
 """
 
 from __future__ import annotations
@@ -15,68 +11,7 @@ import pytest
 
 import jax
 
-from repro.configs import flows
 from repro.core import pipeline as PL
-from repro.core.optimizer import optimize
-from repro.kernels import megakernel as MK
-
-
-@pytest.fixture
-def on_tpu(monkeypatch):
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-
-
-def test_dispatch_mode_is_xla_unless_forced(monkeypatch):
-    monkeypatch.delenv(MK.PALLAS_ENV, raising=False)
-    assert MK.dispatch_mode() == "xla"
-    monkeypatch.setenv(MK.PALLAS_ENV, "0")
-    assert MK.dispatch_mode() == "xla"
-    monkeypatch.setenv(MK.PALLAS_ENV, "1")
-    assert MK.dispatch_mode() == "pallas"
-
-
-def test_dispatch_mode_on_tpu(monkeypatch, on_tpu):
-    monkeypatch.delenv(MK.PALLAS_ENV, raising=False)
-    assert MK.dispatch_mode() == "xla"
-    monkeypatch.setenv(MK.PALLAS_ENV, "1")
-    with pytest.raises(NotImplementedError, match="64-bit types"):
-        MK.dispatch_mode()
-
-
-def _engine(use_kernels):
-    from repro.serve.dataflow import DataflowEngine, ServeConfig
-
-    return DataflowEngine(ServeConfig(use_kernels=use_kernels))
-
-
-def _mesh_plan(use_kernels):
-    from repro.core.distributed import DistributedPlan
-
-    return DistributedPlan(optimize(flows.q15()[0]).best.plan,
-                           use_kernels=use_kernels)
-
-
-@pytest.mark.parametrize("entry", [
-    lambda uk: PL.compile_plan(flows.q15()[0], use_kernels=uk),
-    lambda uk: optimize(flows.q15()[0]).compile(use_kernels=uk),
-    _engine,
-    _mesh_plan,
-], ids=["compile_plan", "RankedPlan.compile", "DataflowEngine",
-        "DistributedPlan"])
-def test_use_kernels_refused_on_tpu(entry, on_tpu):
-    with pytest.raises(NotImplementedError, match="sorted_probe"):
-        entry(True)
-    entry(False)  # the XLA path stays open
-
-
-def test_use_kernels_runs_interpreted_off_tpu():
-    root, make = flows.q15()
-    b = make(512, seed=5)
-    out = PL.compile_plan(root, use_kernels=True,
-                          cache=PL.ExecutableCache()).run(b)
-    from repro.core import executor
-
-    assert out.equivalent(executor.execute(root, b))
 
 
 @pytest.fixture

@@ -32,10 +32,9 @@ def test_all_plans_equivalent_eager(name, flow_data):
 
 
 @pytest.mark.parametrize("name", ["q15", "clickstream"])
-@pytest.mark.parametrize("use_kernels", [False, True])
-def test_masked_jit_equivalent(name, flow_data, use_kernels):
+def test_masked_jit_equivalent(name, flow_data):
     root, b, ref = flow_data[name]
-    got = run_flow_jit(root, b, use_kernels=use_kernels)
+    got = run_flow_jit(root, b)
     assert got.equivalent(ref, atol=1e-4)
 
 

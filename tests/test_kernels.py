@@ -1,55 +1,13 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret mode) vs pure-jnp oracle."""
+"""Per-kernel shape/dtype sweeps of the model-plane Pallas kernels
+(interpret mode) against their pure-jnp oracles."""
 
 import numpy as np
 import jax.numpy as jnp
 import pytest
 
-# optional dependency: skip cleanly (instead of failing collection)
-# in environments without hypothesis
-hypothesis = pytest.importorskip("hypothesis")
-from hypothesis import given, settings, strategies as st
-
 from repro.kernels import ops, ref
 
 RNG = np.random.default_rng(0)
-
-
-@pytest.mark.parametrize("n,c,op", [
-    (64, 3, "add"), (512, 1, "max"), (1000, 2, "min"), (48, 4, "add"),
-    (8, 1, "max"), (4096, 2, "add"),
-])
-def test_segmented_scan(n, c, op):
-    v = jnp.asarray(RNG.normal(size=(n, c)).astype(np.float32))
-    flags = jnp.asarray(RNG.random(n) < 0.2).at[0].set(True)
-    np.testing.assert_allclose(ops.segmented_scan(v, flags, op=op),
-                               ref.segmented_scan(v, flags, op=op),
-                               rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.parametrize("n,nseg,op,frac_valid", [
-    (128, 16, "add", 0.8), (1000, 50, "max", 0.5), (256, 8, "min", 1.0),
-    (64, 64, "add", 0.3),
-])
-def test_segment_reduce(n, nseg, op, frac_valid):
-    sid = np.sort(RNG.integers(0, nseg, n)).astype(np.int32)
-    v = RNG.normal(size=n).astype(np.float32)
-    valid = RNG.random(n) < frac_valid
-    got = ops.segment_reduce(jnp.asarray(v), jnp.asarray(sid), nseg, op=op,
-                             valid=jnp.asarray(valid))
-    want = ref.segment_reduce(jnp.asarray(v), jnp.asarray(sid), nseg, op=op,
-                              valid=jnp.asarray(valid))
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-
-
-@settings(max_examples=20, deadline=None)
-@given(n=st.integers(1, 300), m=st.integers(1, 300),
-       lo=st.integers(-100, 0), hi=st.integers(1, 1000))
-def test_sorted_probe_property(n, m, lo, hi):
-    keys = np.sort(RNG.integers(lo, hi, n)).astype(np.float64)
-    qs = RNG.integers(lo - 5, hi + 5, m).astype(np.float64)
-    got = ops.sorted_probe(jnp.asarray(keys), jnp.asarray(qs))
-    want = ref.sorted_probe(jnp.asarray(keys), jnp.asarray(qs))
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 @pytest.mark.parametrize("shape,causal,window,dt,tol", [

@@ -10,9 +10,8 @@ truncation re-runs).  Beyond identity, these tests pin the contract's
 edges: fallback routing (Cross/CoGroup/shared subtrees/non-blockable
 capacities stay solo), executable-cache key separation (fused and composed
 traces never share an executable), obs side-channel parity (the adaptive
-layer sees identical boundary counts either route), the Pallas whole-block
-dispatch (interpret mode on CPU), and the truncation force-swap staying on
-the megakernel route.
+layer sees identical boundary counts either route), and the truncation
+force-swap staying on the megakernel route.
 """
 
 from __future__ import annotations
@@ -202,18 +201,6 @@ def test_fused_and_composed_never_share_an_executable():
     assert cache.stats().hits == 2
 
 
-def test_dispatch_mode_joins_the_key(monkeypatch):
-    root, mk = flows.FLOWS["q15"]()
-    cache = ExecutableCache()
-    b = mk(1024, seed=3)
-    cp = compile_plan(root, cache=cache, use_megakernel=True)
-    monkeypatch.delenv(MK.PALLAS_ENV, raising=False)
-    cp.run(b)
-    monkeypatch.setenv(MK.PALLAS_ENV, "1")
-    cp.run(b)  # pallas dispatch: must retrace, not reuse the xla trace
-    assert cache.stats().traces == 2
-
-
 # ---------------------------------------------------------------------------
 # Obs side-channel parity
 # ---------------------------------------------------------------------------
@@ -230,9 +217,8 @@ def test_observe_and_caps_parity_between_routes():
 
     def run(route):
         obs, caps = [], []
-        out = PL.run_stages(cp.stages, masked, cp.use_kernels,
-                            cp.compact_slack, stats_memo, observe=obs,
-                            caps=caps, routes=route)
+        out = PL.run_stages(cp.stages, masked, cp.compact_slack, stats_memo,
+                            observe=obs, caps=caps, routes=route)
         return out, obs, caps
 
     out_m, obs_m, caps_m = run(routes)
@@ -244,22 +230,6 @@ def test_observe_and_caps_parity_between_routes():
         assert int(am) == int(ac)
     assert flowgen.canonical_rows(out_m.to_record_batch()) \
         == flowgen.canonical_rows(out_c.to_record_batch())
-
-
-# ---------------------------------------------------------------------------
-# Pallas whole-block dispatch (interpret mode on CPU)
-# ---------------------------------------------------------------------------
-@pytest.mark.parametrize("name", ("q15", "clickstream"))
-def test_pallas_dispatch_bit_identical(name, monkeypatch):
-    monkeypatch.setenv(MK.PALLAS_ENV, "1")
-    assert MK.dispatch_mode() == "pallas"
-    root, mk = flows.FLOWS[name]()
-    b = mk(2048, seed=13)
-    on = compile_plan(root, cache=ExecutableCache(), use_megakernel=True)
-    off = compile_plan(root, cache=ExecutableCache(), use_megakernel=False)
-    assert flowgen.canonical_rows(on.run(b)) \
-        == flowgen.canonical_rows(off.run(b))
-    assert _mega_entries(on._last_routes)
 
 
 # ---------------------------------------------------------------------------
@@ -307,6 +277,6 @@ def test_interior_compaction_capacity_is_route_agnostic():
     routes = cp._routes(caps)
     assert _mega_entries(routes)
     got: list = []
-    PL.run_stages(cp.stages, masked, cp.use_kernels, cp.compact_slack,
-                  stats_memo, caps=got, routes=routes)
+    PL.run_stages(cp.stages, masked, cp.compact_slack, stats_memo,
+                  caps=got, routes=routes)
     assert [min(c, p) for c, p in zip(got, planned)] == got

@@ -36,10 +36,9 @@ def flow_data():
 # Parity: the acceptance bar — every evaluation flow, fused vs eager
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("name", list(flows.FLOWS))
-@pytest.mark.parametrize("use_kernels", [False, True])
-def test_pipeline_parity(name, flow_data, use_kernels):
+def test_pipeline_parity(name, flow_data):
     root, bindings, ref = flow_data[name]
-    cp = compile_plan(root, use_kernels=use_kernels, cache=ExecutableCache())
+    cp = compile_plan(root, cache=ExecutableCache())
     assert cp.run(bindings(N, seed=7)).equivalent(ref, atol=1e-4)
 
 

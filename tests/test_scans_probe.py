@@ -191,7 +191,7 @@ def _probe_hlo(n_keys: int, n_queries: int) -> list:
     """`op_name`s under `/probe/` in the CPU HLO of one PK probe."""
     a = jax.ShapeDtypeStruct((n_keys,), jnp.int64)
     x = jax.ShapeDtypeStruct((n_queries,), jnp.int64)
-    hlo = jax.jit(lambda a, x: masked._probe(a, x, 0, False)).lower(
+    hlo = jax.jit(lambda a, x: masked._probe(a, x, 0)).lower(
         a, x).compile().as_text()
     return [p for p in re.findall(r'op_name="([^"]*)"', hlo)
             if "/probe/" in p]
@@ -258,7 +258,7 @@ def test_match_directory_equals_search(case, anti, monkeypatch):
 
     def both():
         obs_ = {}
-        out = jax.jit(lambda lb, rb: (run(op, lb, rb, False, True, obs_),
+        out = jax.jit(lambda lb, rb: (run(op, lb, rb, True, obs_),
                                       obs_["groups"]))(lb, rb)
         return jax.tree_util.tree_map(np.asarray, out)
 

@@ -234,18 +234,17 @@ def test_pk_probe_elision_minimal_key_after_leading_gap():
                       "y": jnp.asarray([-1, 10, 20, 30])},
                      jnp.asarray([False, True, True, True]),  # leading gap
                      order=("b",))
-    out = _exec_match_pk(root, lb, rb, use_kernels=False, use_order=True)
-    ref = _exec_match_pk(root, lb, rb, use_kernels=False, use_order=False)
+    out = _exec_match_pk(root, lb, rb, use_order=True)
+    ref = _exec_match_pk(root, lb, rb, use_order=False)
     _ident(out.to_record_batch(), ref.to_record_batch())
     got = sorted(np.asarray(out.columns["y"])[np.asarray(out.valid)].tolist())
     assert got == [10, 10, 20], "minimal-key rows must match through the gap"
 
 
-@pytest.mark.parametrize("use_kernels", [False, True])
-def test_cogroup_permuted_order_cover_not_elided(use_kernels):
+def test_cogroup_permuted_order_cover_not_elided():
     """Review regression: a side sorted on a PERMUTATION of the cogroup key
     must not take the valids-first fast path (union segment ids are not
-    monotone over it — the kernel backend's contiguity invariant breaks)."""
+    monotone over it, so per-side segments would not be contiguous)."""
     rng = np.random.default_rng(0)
     n = 16
     a = rng.integers(0, 3, n)
@@ -267,7 +266,5 @@ def test_cogroup_permuted_order_cover_not_elided(use_kernels):
                                "d": rng.integers(0, 3, 8),
                                "w": rng.integers(-9, 9, 8)})}
     ref = executor.execute(root, b)
-    _ident(run_flow_jit(root, b, use_kernels=use_kernels, use_order=True),
-           ref)
-    _ident(run_flow_jit(root, b, use_kernels=use_kernels, use_order=False),
-           ref)
+    _ident(run_flow_jit(root, b, use_order=True), ref)
+    _ident(run_flow_jit(root, b, use_order=False), ref)

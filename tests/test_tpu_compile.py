@@ -4,15 +4,11 @@ No chip is attached: the TPU compiler builds for a `v5e:2x2` topology that
 is only described, so these tests raise whatever the chip's compiler would
 raise, at no chip time.  They cover the executables `chip_smoke.py` runs:
 
-* Q15's fused executable at 4,096 rows under the TPU dispatch rule (the
-  megakernel span inlined as XLA);
+* Q15's fused executable at 4,096 rows (the megakernel span inlined as
+  XLA);
 * Q15's composed executable at 65,536 rows;
 * the combiner flow of `benchmarks/bench_aggregation.py` as one
   `shard_map` program over the 4-device mesh.
-
-The two `use_kernels` Pallas kernels are pinned as strict xfails: Mosaic
-refuses both today (`kernels.ops.TPU_KERNEL_REFUSALS`), and the change that
-makes them compile must flip these tests.
 
 The topology is described inside a module fixture, never while a module is
 imported: only one process may load the TPU library, and each test worker
@@ -28,15 +24,11 @@ import numpy as np
 import pytest
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 from repro.configs import flows
 from repro.core.pipeline import ExecutableCache, compile_plan
-from repro.kernels import megakernel as MK
-from repro.kernels import segmented_scan as SS
-from repro.kernels import sorted_probe as SP
 
 
 @pytest.fixture(scope="module")
@@ -77,9 +69,7 @@ def _q15_executable(rows: int, use_megakernel: bool):
     return cp._executable(sig), masked, cp._last_routes
 
 
-def test_q15_fused_span_compiles_as_xla(topo, one_chip, monkeypatch):
-    monkeypatch.delenv(MK.PALLAS_ENV, raising=False)
-    assert MK.dispatch_mode() == "xla"
+def test_q15_fused_span_compiles_as_xla(topo, one_chip):
     fn, masked, routes = _q15_executable(4096, use_megakernel=True)
     assert any(e[0] == "mega" for e in routes), routes
     compiled = fn.lower(_shapes(masked, one_chip)).compile()
@@ -114,21 +104,3 @@ def test_combiner_flow_compiles_on_four_chip_mesh(topo):
     args = [_shapes(staged[n], shard) for n in sorted(staged)]
     text = fn.lower(*args).compile().as_text()
     assert "all-gather" in text
-
-
-@pytest.mark.xfail(strict=True, reason="Mosaic refuses sorted_probe: "
-                   "64-bit keys, and int64 index maps under x64")
-@pytest.mark.parametrize("dtype", (jnp.float64, jnp.int32))
-def test_sorted_probe_compiles(topo, one_chip, dtype):
-    keys = jax.ShapeDtypeStruct((4096,), dtype, sharding=one_chip)
-    queries = jax.ShapeDtypeStruct((4096,), dtype, sharding=one_chip)
-    SP.sorted_probe.lower(keys, queries, interpret=False).compile()
-
-
-@pytest.mark.xfail(strict=True, reason="Mosaic cannot lower the in-kernel "
-                   "associative_scan of segmented_scan")
-def test_segmented_scan_compiles(topo, one_chip):
-    values = jax.ShapeDtypeStruct((4096, 128), jnp.float32,
-                                  sharding=one_chip)
-    flags = jax.ShapeDtypeStruct((4096,), jnp.bool_, sharding=one_chip)
-    SS.segmented_scan.lower(values, flags, interpret=False).compile()
