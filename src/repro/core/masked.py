@@ -37,7 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import invoke, scans
-from ..obs import scope
+from ..obs import count, scope
 from .cost import estimate
 from .operators import (CoGroupOp, CrossOp, LimitOp, MapOp, MatchOp, Node,
                         ReduceOp, Source)
@@ -398,12 +398,33 @@ def _match_codes(op: MatchOp, lb: MaskedBatch, rb: MaskedBatch):
     return rank[:nl], rank[nl:]
 
 
-def _probe(rcode, lcode, start):
-    """The PK probe: `max(searchsorted(rcode, lcode), start)` for the sorted
-    PK codes `rcode`, under the device scope `probe` (a `start` of 0 clamps
-    nothing)."""
+def _probe(rcode, rvalid, lcode, lvalid, start, runs_valid: bool):
+    """The PK probe: `(pos, hit)` for the sorted PK codes `rcode` and their
+    validity `rvalid`.  `pos` is `max(searchsorted(rcode, lcode), start)`
+    (a `start` of 0 clamps nothing), clamped to the PK side; the search
+    runs under the device scope `probe`.  `hit` is whether `pos` holds a
+    valid row of the code `lcode`, and the left row is valid (`lvalid`,
+    None for every row).
+
+    Where the search took the directory, its `eq` decides the code with no
+    gather of `rcode[pos]`, and each trace counts `probe.hit_in_search`.
+    With `runs_valid` (the cummax fill from the first valid slot `start`)
+    every run start the directory can return is a valid row, so neither is
+    `rvalid[pos]` gathered; one scalar guards a PK side with no valid row,
+    whose fill codes would otherwise match."""
     with scope("probe"):
-        return scans.search_sorted(rcode, lcode, start)
+        pos, eq = scans.search_sorted(rcode, lcode, start)
+    pos = jnp.clip(pos, 0, rcode.shape[0] - 1)
+    if eq is None:
+        hit = rcode[pos] == lcode
+    else:
+        count("probe.hit_in_search", 1)
+        hit = eq
+    if lvalid is not None:
+        hit = hit & lvalid
+    if eq is not None and runs_valid:
+        return pos, hit & jnp.any(rvalid)
+    return pos, hit & rvalid[pos]
 
 
 def _exec_match_pk(op: MatchOp, lb: MaskedBatch, rb: MaskedBatch,
@@ -433,9 +454,9 @@ def _exec_match_pk(op: MatchOp, lb: MaskedBatch, rb: MaskedBatch,
         rcode = scans.cummax(
             jnp.where(rb.valid, rcode_raw, jnp.asarray(lo, rcode_raw.dtype)))
         first_valid = jnp.argmax(rb.valid).astype(jnp.int32)
-        rcols, rvalid = rb.columns, rb.valid
+        rcols, rvalid, runs_valid = rb.columns, rb.valid, True
     else:
-        first_valid = 0
+        first_valid, runs_valid = 0, False
         # sort by (code, valid-first): equal-code invalid rows land AFTER the
         # valid ones, so no sentinel arithmetic is needed and a left search
         # still finds the valid row first
@@ -445,8 +466,8 @@ def _exec_match_pk(op: MatchOp, lb: MaskedBatch, rb: MaskedBatch,
             rcols = {f: v[order] for f, v in rb.columns.items()}
             rvalid = rb.valid[order]
 
-    pos = jnp.clip(_probe(rcode, lcode, first_valid), 0, rb.capacity - 1)
-    hit = (rcode[pos] == lcode) & lb.valid & rvalid[pos]
+    pos, hit = _probe(rcode, rvalid, lcode, lb.valid, first_valid,
+                      runs_valid)
     if obs is not None:  # observed probe hits (adaptive join-fanout feedback)
         obs["groups"] = jnp.sum(hit.astype(jnp.int32))
 
@@ -485,15 +506,14 @@ def _exec_match_anti(op: MatchOp, lb: MaskedBatch, rb: MaskedBatch,
         rcode = scans.cummax(
             jnp.where(rb.valid, rcode_raw, jnp.asarray(lo, rcode_raw.dtype)))
         first_valid = jnp.argmax(rb.valid).astype(jnp.int32)
-        rvalid = rb.valid
+        rvalid, runs_valid = rb.valid, True
     else:
-        first_valid = 0
+        first_valid, runs_valid = 0, False
         with scope("sort"):
             order = jnp.lexsort((~rb.valid, rcode_raw))
             rcode = rcode_raw[order]
             rvalid = rb.valid[order]
-    pos = jnp.clip(_probe(rcode, lcode, first_valid), 0, rb.capacity - 1)
-    present = (rcode[pos] == lcode) & rvalid[pos]
+    _, present = _probe(rcode, rvalid, lcode, None, first_valid, runs_valid)
     keep = lb.valid & ~present
     if obs is not None:  # observed survivors (adaptive selectivity feedback)
         obs["groups"] = jnp.sum(keep.astype(jnp.int32))

@@ -21,7 +21,8 @@ aggregates.
 compaction's pack and the sorted segments' boundaries — with one gather
 and no loop once the mask is long (see `select`).  `search_sorted` is the
 PK probe's sorted search: a bucket directory over the distinct keys, then a
-binary search only as deep as the widest bucket (see `search_sorted`).
+binary search only as deep as the widest bucket, whose compares also tell
+whether each query is a key (see `search_sorted`).
 """
 
 from __future__ import annotations
@@ -123,10 +124,13 @@ def _select_blocked(mask, k):
     return jnp.where(blk < B, blk * W + at, n)
 
 
-def search_sorted(a: jnp.ndarray, x: jnp.ndarray, start=0) -> jnp.ndarray:
-    """Left insertion point of each `x` in the nondecreasing `a`, clamped
-    to at least `start` (0 <= start <= len(a)): exactly
-    `max(jnp.searchsorted(a, x, side='left'), start)`, as int32.
+def search_sorted(a: jnp.ndarray, x: jnp.ndarray, start=0):
+    """`(pos, eq)`: `pos` is the left insertion point of each `x` in the
+    nondecreasing `a`, clamped to at least `start` (0 <= start <= len(a)):
+    exactly `max(jnp.searchsorted(a, x, side='left'), start)`, as int32.
+    `eq` is whether `x` occurs in `a[start:]`, that is `pos < len(a)` and
+    `a[pos] == x`, decided by the search with no gather of `a[pos]`; it is
+    None where the plain search ran.
 
     Integer codes probed by at least `_PROBE_MIN` queries take a bucket
     directory (`_search_directory`): its in-bucket binary search is as deep
@@ -142,8 +146,8 @@ def search_sorted(a: jnp.ndarray, x: jnp.ndarray, start=0) -> jnp.ndarray:
     obs.count("probe.search", 1)
     pos = jnp.searchsorted(a, x)
     if isinstance(start, int) and start == 0:
-        return pos
-    return jnp.maximum(pos, start)
+        return pos, None
+    return jnp.maximum(pos, start), None
 
 
 def _search_directory(a, x, start):
@@ -159,41 +163,50 @@ def _search_directory(a, x, start):
     compare offsets from `lo`, and 64-bit codes compare their low 32 bits
     while the shift is at most 32: a bucket's offsets then differ in those
     bits alone, and a 32-bit gather costs a third of a 64-bit one on a TPU
-    v5e (PERF.md §6)."""
+    v5e (PERF.md §6).
+
+    The last step that moves `rgt` moves it to the answer, so its compare
+    gives `eq`; where no step moves it, the answer is the first code of a
+    later bucket (or none), greater than `x`.  `x < lo` has offset 0, as
+    `lo` has, and is no hit."""
     if a.dtype.itemsize < 4:
         a, x = a.astype(jnp.int32), x.astype(jnp.int32)
     n = a.shape[0]
-    U, d, ku, offset, shift, dir_, steps = _directory(a, start)
+    U, d, ku, lo, offset, shift, dir_, steps = _directory(a, start)
     B = dir_.shape[0] - 1
     bx = jnp.minimum(offset(x) >> shift, B).astype(jnp.int32)
-    lr = (dir_[bx], dir_[jnp.minimum(bx + 1, B)])
+    init = (dir_[bx], dir_[jnp.minimum(bx + 1, B)], jnp.zeros(x.shape, bool))
 
     def search(narrow: bool):
         keys, kx = ku, offset(x)
         if narrow:
             keys, kx = keys.astype(jnp.uint32), kx.astype(jnp.uint32)
 
-        def step(_, lr):
-            lft, rgt = lr
+        def step(_, c):
+            lft, rgt, eq = c
             mid = (lft + rgt) >> 1
-            less = keys[jnp.minimum(mid, n - 1)] < kx
+            key = keys[jnp.minimum(mid, n - 1)]
+            less = key < kx
             live = lft < rgt
+            moves = live & ~less
             return (jnp.where(live & less, mid + 1, lft),
-                    jnp.where(live & ~less, mid, rgt))
+                    jnp.where(moves, mid, rgt),
+                    jnp.where(moves, key == kx, eq))
 
-        return lax.fori_loop(0, steps, step, lr)[0]
+        j, _, eq = lax.fori_loop(0, steps, step, init)
+        return j, eq
 
     if a.dtype.itemsize == 4:
-        j = search(False)
+        j, eq = search(False)
     else:
-        j = lax.cond(shift <= 32, lambda: search(True),
-                     lambda: search(False))
-    return jnp.where(j < d, U[jnp.minimum(j, n - 1)], n)
+        j, eq = lax.cond(shift <= 32, lambda: search(True),
+                         lambda: search(False))
+    return jnp.where(j < d, U[jnp.minimum(j, n - 1)], n), eq & (x >= lo)
 
 
 def _directory(a, start):
     """The directory over the distinct codes of `a[start:]` (int32 or
-    wider): `(U, d, ku, offset, shift, dir, steps)`.
+    wider): `(U, d, ku, lo, offset, shift, dir, steps)`.
 
     `U` holds the slots of the `d` run starts of `a[start:]` (as `select`
     gives them: `n` past them) and `ku` their codes' offsets: a cummax fill
@@ -232,7 +245,7 @@ def _directory(a, start):
         1, indices_are_sorted=True)
     dir_ = jnp.concatenate([jnp.zeros((1,), jnp.int32), cumsum(hist[:B])])
     steps = 32 - lax.clz(jnp.max(hist[:B]))
-    return U, d, ku, offset, shift, dir_, steps
+    return U, d, ku, lo, offset, shift, dir_, steps
 
 
 def cumsum(v: jnp.ndarray) -> jnp.ndarray:

@@ -84,13 +84,16 @@ def test_segment_reduce(n, nseg, op, frac_valid):
 def test_sorted_probe_property(n, m, lo, hi):
     """Float codes take `jnp.searchsorted`; integer codes take the bucket
     directory once the probe side reaches `_PROBE_MIN` (patched down to
-    one query here)."""
+    one query here), whose `eq` is whether the query is a key."""
     keys = np.sort(RNG.integers(lo, hi, n))
     qs = RNG.integers(lo - 5, hi + 5, m)
     with mock.patch.object(scans, "_PROBE_MIN", 1):
         for dtype in (np.float64, np.int64):
             k = jnp.asarray(keys.astype(dtype))
             q = jnp.asarray(qs.astype(dtype))
+            pos, eq = jax.jit(scans.search_sorted)(k, q)
             np.testing.assert_array_equal(
-                np.asarray(jax.jit(scans.search_sorted)(k, q)),
-                np.asarray(ref.sorted_probe(k, q)))
+                np.asarray(pos), np.asarray(ref.sorted_probe(k, q)))
+            if eq is not None:
+                np.testing.assert_array_equal(np.asarray(eq),
+                                              np.isin(qs, keys))
