@@ -230,7 +230,8 @@ def _sort_by_key(b: MaskedBatch, key: Sequence[str]):
         cols = {f: v[order] for f, v in b.columns.items()}
         valid = b.valid[order]
     segments = _segments_gappy if len(key) == 1 else _segments_contiguous
-    seg, is_start = segments(cols, key, valid)
+    with scope("segment"):
+        seg, is_start = segments(cols, key, valid)
     return MaskedBatch(cols, valid, tuple(key)), seg, is_start
 
 
@@ -321,19 +322,21 @@ def _exec_reduce(op: ReduceOp, b: MaskedBatch, use_order: bool = True,
     if use_order and order_covers(b.order, key):
         # input already groups equal keys contiguously: segment directly over
         # the (possibly gappy) slots, no sort, no repack
+        count("reduce.sorted", 1)
         sb = b
-        if contiguous:
-            seg, is_start = _segments_contiguous(b.columns, key, b.valid)
-        else:
-            seg, is_start = _segments_gappy(b.columns, key, b.valid)
+        segments = _segments_contiguous if contiguous else _segments_gappy
+        with scope("segment"):
+            seg, is_start = segments(b.columns, key, b.valid)
         base_order = b.order
     else:
+        count("reduce.sort", 1)
         sb, seg, is_start = _sort_by_key(b, key)
         base_order = key
     nseg = b.capacity  # worst case: every valid row its own group
     segops = JitSegmentOps(seg, nseg, record_valid=sb.valid,
                            is_start=is_start)
-    col = invoke.run_kat_udf(op.udf, dict(sb.columns), segops, op.key)
+    with scope("segment"):
+        col = invoke.run_kat_udf(op.udf, dict(sb.columns), segops, op.key)
     ngroups = jnp.sum(is_start)
     if obs is not None:
         obs["groups"] = ngroups.astype(jnp.int32)
@@ -347,8 +350,11 @@ def _exec_reduce(op: ReduceOp, b: MaskedBatch, use_order: bool = True,
                     else dict(sb.columns))
             valid = sb.valid
             if em.group_where is not None:
+                # the group filter: each row takes its group's verdict
+                count("reduce.group_filter", 1)
                 gw = jnp.asarray(em.group_where).astype(bool)
-                valid = valid & gw[seg]
+                with scope("segment"):
+                    valid = valid & gw[seg]
             parts.append(MaskedBatch(
                 _project(cols, op.out_schema, b.capacity), valid,
                 order_prefix(base_order, op.out_schema.fields, w)))

@@ -439,6 +439,7 @@ class JitSegmentOps(SegmentOps):
     _SCAN_MIN_ROWS = 2048
 
     def _seg_reduce(self, vm, op):
+        from ..obs import count
         from . import scans
 
         if vm.shape[0] < self._SCAN_MIN_ROWS:
@@ -447,6 +448,7 @@ class JitSegmentOps(SegmentOps):
                       "min": self._jax.ops.segment_min}[op]
             return seg_fn(vm, self.segment_ids, self.num_segments)
         _, ends, _ = self._starts_ends()
+        count("segment.scan", 1)
         return scans.segmented_scan(vm, self.is_start, op)[ends]
 
     # -- aggregates ----------------------------------------------------------

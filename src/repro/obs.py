@@ -19,7 +19,11 @@ clears both.  Records accumulate until then.
 
 Scope names used by the program: `stage.<kind>.<top operator>` around each
 lowered stage (the operator names `pipeline.stage_key` uses), and inside
-them `compact`, `sort`, `probe` and `wire`.  Span names: `optimize`
+them `compact`, `sort`, `probe`, `segment` (a Reduce's segmentation, group
+aggregates and group-mask broadcast) and `wire`.  A Reduce's trace counts
+`reduce.sorted` (its input order covers the key) or `reduce.sort`, and
+`reduce.group_filter` per group-masked passthrough emission; each traced
+log-depth segmented scan counts `segment.scan`.  Span names: `optimize`
 (counters `optimize.groups`, the logical groups of the plan-space memo
 built, and `optimize.priced`, the physical alternatives the cost model
 priced), `compile`, `bind_device` (children `prepare`, `transfer`;
