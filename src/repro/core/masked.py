@@ -37,13 +37,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import invoke, scans
-from ..obs import count, scope
+from ..obs import count, muted, scope
 from .cost import estimate
 from .operators import (CoGroupOp, CrossOp, LimitOp, MapOp, MatchOp, Node,
                         ReduceOp, Source)
 from .record import RecordBatch
 from .reorder import eff_writes
-from .udf import JitSegmentOps
+from .udf import JitSegmentOps, KatEmit
 
 
 # ---------------------------------------------------------------------------
@@ -254,6 +254,26 @@ def planned_capacity(node: Node, stats_memo: dict, slack: float,
     return int(max(bucket_capacity(rows), 8))
 
 
+# Reduce emissions whose every output row is an input row: the segment count
+# sizes only the per-group arrays inside the stage, never its output
+_ROW_EMITS = (KatEmit.PASSTHROUGH, KatEmit.PASSTHROUGH_FILTER)
+
+
+def segment_capacity(node: Node, stats_memo: dict,
+                     scale: float = 1.0) -> Optional[int]:
+    """Static segment count for a row-aligned Reduce (emit kind
+    `PASSTHROUGH` or `PASSTHROUGH_FILTER`): the bucketed group estimate
+    under `planned_capacity`'s variance guard, `est + 4·sqrt(est)`, floored
+    at 8.  None for every other node: a per-group Reduce emits one slot per
+    segment, so its segment count is its output capacity.  No multiplicative
+    slack: `_exec_reduce` falls back to the full count when more groups
+    arrive, so the estimate buys speed, never exactness."""
+    if not isinstance(node, ReduceOp) or node.props.kat_emit not in _ROW_EMITS:
+        return None
+    est = estimate(node, stats_memo).distinct * scale
+    return int(bucket_capacity(max(est + 4.0 * np.sqrt(est), 8.0)))
+
+
 def compact_to_estimate(b: "MaskedBatch", node: Node, stats_memo: dict,
                         slack: float, scale: float = 1.0,
                         shards: int = 1) -> "MaskedBatch":
@@ -305,7 +325,8 @@ def _exec_map(op: MapOp, b: MaskedBatch) -> MaskedBatch:
 
 def _exec_reduce(op: ReduceOp, b: MaskedBatch, use_order: bool = True,
                  obs: Optional[dict] = None,
-                 contiguous: bool = False) -> MaskedBatch:
+                 contiguous: bool = False,
+                 segment_cap: Optional[int] = None) -> MaskedBatch:
     """`obs`, when given, receives the traced observed group count under
     key "groups" — the stage-boundary statistic the adaptive feedback loop
     calibrates `distinct_keys` from (DESIGN.md §9).  It costs one reduction
@@ -317,7 +338,17 @@ def _exec_reduce(op: ReduceOp, b: MaskedBatch, use_order: bool = True,
     instead of the gap-tolerant cummax walk.  On a valids-first batch the
     two produce identical `(seg, is_start)` arrays — the previous valid row
     IS the adjacent slot — so results are bit-identical, minus the cummax
-    and the gather it feeds."""
+    and the gather it feeds.
+
+    `segment_cap` (`segment_capacity`, a static int) sizes the group arrays
+    of a row-aligned Reduce below its input capacity: the UDF runs over
+    `segment_cap` segments when the live group count fits, and over
+    `b.capacity` (every valid row its own group) when it does not — a
+    `lax.cond` on the group count, so the answer is exact for any data and
+    any estimate, and a wrong estimate costs only the full count's time.
+    Each trace that takes the pair counts `reduce.segments_sized`; the
+    fallback branch traces with counters muted, so every other counter
+    still counts once per Reduce."""
     key = tuple(op.key)
     if use_order and order_covers(b.order, key):
         # input already groups equal keys contiguously: segment directly over
@@ -332,14 +363,39 @@ def _exec_reduce(op: ReduceOp, b: MaskedBatch, use_order: bool = True,
         count("reduce.sort", 1)
         sb, seg, is_start = _sort_by_key(b, key)
         base_order = key
-    nseg = b.capacity  # worst case: every valid row its own group
+    ngroups = jnp.sum(is_start)
+    if obs is not None:
+        obs["groups"] = ngroups.astype(jnp.int32)
+
+    def body(nseg: int, sb, seg, is_start):
+        return _reduce_groups(op, sb, seg, is_start, ngroups, nseg,
+                              base_order)
+
+    if segment_cap is None or segment_cap >= b.capacity \
+            or op.props.kat_emit not in _ROW_EMITS:
+        # worst case: every valid row its own group
+        return body(b.capacity, sb, seg, is_start)
+    count("reduce.segments_sized", 1)
+
+    def full(*args):
+        with muted():
+            return body(b.capacity, *args)
+
+    return jax.lax.cond(ngroups <= segment_cap,
+                        functools.partial(body, segment_cap), full,
+                        sb, seg, is_start)
+
+
+def _reduce_groups(op: ReduceOp, sb: MaskedBatch, seg, is_start, ngroups,
+                   nseg: int, base_order: tuple) -> MaskedBatch:
+    """The Reduce's UDF over `nseg` static segments of the segmented batch
+    `sb` (`nseg` >= the live group count `ngroups`), and its emissions:
+    row-aligned ones at `sb.capacity` slots, per-group ones at `nseg`."""
+    key = tuple(op.key)
     segops = JitSegmentOps(seg, nseg, record_valid=sb.valid,
                            is_start=is_start)
     with scope("segment"):
         col = invoke.run_kat_udf(op.udf, dict(sb.columns), segops, op.key)
-    ngroups = jnp.sum(is_start)
-    if obs is not None:
-        obs["groups"] = ngroups.astype(jnp.int32)
     group_valid = jnp.arange(nseg) < ngroups
     w = eff_writes(op)
 
@@ -356,7 +412,7 @@ def _exec_reduce(op: ReduceOp, b: MaskedBatch, use_order: bool = True,
                 with scope("segment"):
                     valid = valid & gw[seg]
             parts.append(MaskedBatch(
-                _project(cols, op.out_schema, b.capacity), valid,
+                _project(cols, op.out_schema, sb.capacity), valid,
                 order_prefix(base_order, op.out_schema.fields, w)))
         else:
             cols = em.builder.columns()

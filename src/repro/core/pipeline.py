@@ -299,7 +299,6 @@ _KIND = {ReduceOp: "reduce", MatchOp: "match", CrossOp: "cross",
 # emission classes whose masked execution yields a single slot-aligned part
 _SINGLE_RAT = (Card.ONE, Card.AT_MOST_ONE)
 _GROUP_EMITS = (KatEmit.PER_GROUP, KatEmit.PER_GROUP_FILTER)
-_RECORD_EMITS = (KatEmit.PASSTHROUGH, KatEmit.PASSTHROUGH_FILTER)
 
 
 def _chain_out_order(ops: Sequence[Node], in_order: tuple) -> tuple:
@@ -328,7 +327,7 @@ def _stage_out_order(kind: str, node: Node, in_orders: tuple,
         base = in_orders[0] if M.order_covers(in_orders[0], key) else key
         if emit in _GROUP_EMITS:
             base = tuple(base)[:len(key)]
-        elif emit not in _RECORD_EMITS:
+        elif emit not in M._ROW_EMITS:
             return ()
         return M.order_prefix(base, node.out_schema.fields, eff_writes(node))
     if kind == "limit":
@@ -525,7 +524,8 @@ class _Interned:
 def execute_stage(stage: Stage, ins: Sequence[M.MaskedBatch],
                   use_order: bool = True,
                   obs: Optional[dict] = None,
-                  contiguous_in: bool = False) -> M.MaskedBatch:
+                  contiguous_in: bool = False,
+                  segment_cap: Optional[int] = None) -> M.MaskedBatch:
     """Run one stage's local (per-worker) computation on masked batches.
 
     Order elision keys off the input batches' `order` metadata; callers
@@ -534,7 +534,8 @@ def execute_stage(stage: Stage, ins: Sequence[M.MaskedBatch],
     (observed groups / probe hits) for the adaptive feedback loop.
     `contiguous_in` asserts the first input was just prefix-packed (a
     megakernel interior boundary): a Reduce then segments with adjacent
-    compares instead of the gap-tolerant cummax walk, bit-identically."""
+    compares instead of the gap-tolerant cummax walk, bit-identically.
+    `segment_cap` is a Reduce stage's `masked.segment_capacity`."""
     if stage.kind == "chain":
         b = ins[0]
         for op in stage.ops:
@@ -543,7 +544,8 @@ def execute_stage(stage: Stage, ins: Sequence[M.MaskedBatch],
     node = stage.top
     if stage.kind == "reduce":
         return M._exec_reduce(node, ins[0], use_order, obs,
-                              contiguous=contiguous_in)
+                              contiguous=contiguous_in,
+                              segment_cap=segment_cap)
     if stage.kind == "limit":
         return M._exec_limit(node, ins[0], use_order)
     if stage.kind == "match":
@@ -641,7 +643,9 @@ def run_stages(stages: Sequence[Stage], bindings: Mapping[str, M.MaskedBatch],
                 orders = st.in_orders or ((),) * len(st.inputs)
                 ins = [resolve(r, o) for r, o in zip(st.inputs, orders)]
                 obs: Optional[dict] = {} if observe is not None else None
-                out = execute_stage(st, ins, use_order, obs)
+                out = execute_stage(
+                    st, ins, use_order, obs,
+                    segment_cap=M.segment_capacity(st.top, stats_memo, scale))
                 last = results[i] = boundary(st, out, obs)
         else:
             from ..kernels import megakernel as MK
@@ -657,8 +661,10 @@ def run_stages(stages: Sequence[Stage], bindings: Mapping[str, M.MaskedBatch],
                     for r, o in zip(st.inputs, orders)])
             planned = [M.planned_capacity(st.top, stats_memo, compact_slack,
                                           scale) for st in span]
+            segment_caps = [M.segment_capacity(st.top, stats_memo, scale)
+                            for st in span]
             raw, span_obs, applied = MK.run_span(span, ins_per, planned,
-                                                 use_order)
+                                                 use_order, segment_caps)
             if caps is not None:
                 caps.extend(applied)
             if observe is not None:

@@ -369,7 +369,10 @@ class DomainSegmentOps(SegmentOps):
 
 
 class JitSegmentOps(SegmentOps):
-    """Segment reductions with a static segment count.
+    """Segment reductions with a static segment count: at least the live
+    group count, from the input capacity (every valid row its own group)
+    down to a group estimate (`masked.segment_capacity`), which the masked
+    Reduce guards with a fallback to the full count.
 
     Two regimes:
 

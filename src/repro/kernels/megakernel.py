@@ -195,13 +195,14 @@ def _live_fields(consumer, fields) -> tuple:
 # Span execution
 # ---------------------------------------------------------------------------
 def run_span(span: Sequence, ins_per_stage: Sequence, planned_caps: Sequence,
-             use_order: bool):
+             use_order: bool, segment_caps: Optional[Sequence] = None):
     """Execute a fused span (traceable).
 
     `ins_per_stage[k]` lists stage k's resolved input batches with None
     marking the in-span edge (the previous stage's output, substituted
     internally); `planned_caps[k]` is stage k's planned compaction capacity
-    (`masked.planned_capacity`).  Interior boundaries compact inside the
+    (`masked.planned_capacity`), and `segment_caps[k]`, when given, its
+    `masked.segment_capacity`.  Interior boundaries compact inside the
     span (pruned to live columns); the LAST stage's output returns RAW for
     the caller's usual boundary compaction, keeping the solo/mega caps and
     observation protocols aligned.
@@ -221,8 +222,9 @@ def run_span(span: Sequence, ins_per_stage: Sequence, planned_caps: Sequence,
         with PL.stage_scope(st):
             ins = [prev if b is None else b for b in raw_ins]
             obs: dict = {}
-            out = PL.execute_stage(st, ins, use_order, obs,
-                                   contiguous_in=prev_packed)
+            out = PL.execute_stage(
+                st, ins, use_order, obs, contiguous_in=prev_packed,
+                segment_cap=segment_caps[k] if segment_caps else None)
             obs_out.append((jnp.sum(out.valid.astype(jnp.int32)),
                             jnp.asarray(obs.get("groups", jnp.int32(-1)),
                                         jnp.int32)))

@@ -22,8 +22,10 @@ lowered stage (the operator names `pipeline.stage_key` uses), and inside
 them `compact`, `sort`, `probe`, `segment` (a Reduce's segmentation, group
 aggregates and group-mask broadcast) and `wire`.  A Reduce's trace counts
 `reduce.sorted` (its input order covers the key) or `reduce.sort`, and
-`reduce.group_filter` per group-masked passthrough emission; each traced
-log-depth segmented scan counts `segment.scan`.  Span names: `optimize`
+`reduce.group_filter` per group-masked passthrough emission, and
+`reduce.segments_sized` when its group arrays are sized from the group
+estimate (with the full count as fallback); each traced log-depth segmented
+scan counts `segment.scan`.  Span names: `optimize`
 (counters `optimize.groups`, the logical groups of the plan-space memo
 built, and `optimize.priced`, the physical alternatives the cost model
 priced), `compile`, `bind_device` (children `prepare`, `transfer`;
@@ -129,9 +131,24 @@ def span(name: str):
 
 
 def count(name: str, n) -> None:
-    """Add `n` to counter `name`; a no-op until `enable()`."""
-    if _REC.on:
+    """Add `n` to counter `name`; a no-op until `enable()`, and inside
+    `muted()`."""
+    if _REC.on and not getattr(_REC._local, "muted", False):
         _REC.count(name, n)
+
+
+@contextlib.contextmanager
+def muted():
+    """Drop the counts this thread adds inside the block: for code traced a
+    second time that must count once (the fallback branch of a `lax.cond`
+    whose other branch counts)."""
+    local = _REC._local
+    prev = getattr(local, "muted", False)
+    local.muted = True
+    try:
+        yield
+    finally:
+        local.muted = prev
 
 
 def enable() -> None:
